@@ -7,20 +7,6 @@ benchmarked program — divergence between the two was a review finding.
 from __future__ import annotations
 
 
-def enable_jax_cache(repo_root: str) -> None:
-    """Persistent compilation cache (same knobs as bench.py)."""
-    import os
-
-    import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(repo_root, ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:  # older jax without the persistent cache knobs
-        pass
-
-
 def build_gpt_train_step(family="gpt", impl="pallas", layers=12, heads=12,
                          kv_heads=None, head_dim=64, seq=1024, batch=8,
                          vocab=50304, sp=1, attention=None,

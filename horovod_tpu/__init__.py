@@ -22,7 +22,6 @@ Public API mirrors `import horovod.torch as hvd`:
 from .analysis import witness as _witness                      # noqa: F401
 _witness.maybe_install()
 
-from . import _compat                                          # noqa: F401
 from .core.types import (                                      # noqa: F401
     ReduceOp, Average, Sum, Adasum, Min, Max, Product,
     Status, StatusType, HorovodInternalError, HostsUpdatedInterrupt,

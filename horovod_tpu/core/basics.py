@@ -84,14 +84,11 @@ def _maybe_init_distributed(cfg: Config) -> None:
                 "Multi-process init needs HOROVOD_NUM_PROCESSES/"
                 "HOROVOD_PROCESS_ID (or HOROVOD_CROSS_SIZE/HOROVOD_CROSS_RANK)"
                 " alongside HOROVOD_COORDINATOR_ADDR")
-        try:
-            # CPU backend: cross-process collectives need an explicit
-            # implementation (the reference's Gloo CPU data plane,
-            # ops/gloo_operations.cc — jax ships the same gloo transport).
-            # No-op for TPU, where collectives ride ICI/DCN natively.
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 - older jaxlib without the option
-            pass
+        # CPU backend: cross-process collectives need an explicit
+        # implementation (the reference's Gloo CPU data plane,
+        # ops/gloo_operations.cc — jax ships the same gloo transport).
+        # No-op for TPU, where collectives ride ICI/DCN natively.
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         try:
             jax.distributed.initialize(
                 coordinator_address=coord,

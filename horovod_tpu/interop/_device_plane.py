@@ -113,12 +113,9 @@ def maybe_init(rank: int, size: int) -> bool:
         logger.warning("%s; staying on the host plane", msg)
         return False
     import jax
-    try:
-        # CPU backend: cross-process collectives need gloo (no-op on TPU,
-        # where collectives ride ICI/DCN natively)
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — older jaxlib without the option
-        pass
+    # CPU backend: cross-process collectives need gloo (no-op on TPU,
+    # where collectives ride ICI/DCN natively)
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     if not jax.distributed.is_initialized():
         jax.distributed.initialize(coordinator_address=coord,
                                    num_processes=size, process_id=rank)

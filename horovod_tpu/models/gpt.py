@@ -190,10 +190,13 @@ class Attention(nn.Module):
                 check_vma=vma,
             )(q, k, v)
         else:
-            # fused pallas kernel on TPU, dense reference elsewhere
+            # fused pallas kernel on TPU (per shard of the mesh, if
+            # any), dense reference elsewhere
             from ..ops.pallas_attention import fused_attention
             o = fused_attention(q, k, v, causal=self.causal,
-                                force=cfg.attention_impl)
+                                force=cfg.attention_impl, mesh=cfg.mesh,
+                                batch_axis=cfg.dp_axis,
+                                head_axis=cfg.tp_axis)
 
         o = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.embed_dim)
         return nn.Dense(cfg.embed_dim, dtype=cfg.dtype,

@@ -276,7 +276,9 @@ class LlamaAttention(nn.Module):
             # GQA-aware (the reference fallback expands internally)
             from ..ops.pallas_attention import fused_attention
             o = fused_attention(q, k, v, causal=True,
-                                force=cfg.attention_impl)
+                                force=cfg.attention_impl, mesh=cfg.mesh,
+                                batch_axis=cfg.dp_axis,
+                                head_axis=cfg.tp_axis)
 
         o = o.transpose(0, 2, 1, 3).reshape(B, S, H * D)
         return dense(cfg.embed_dim, name="wo")(o)

@@ -194,6 +194,12 @@ def lib():
             raise _lib_error from e
 
 
+def loaded() -> bool:
+    """True once :func:`lib` has loaded the shared library in this
+    process. Unlike :func:`available` this never triggers a build."""
+    return _lib is not None
+
+
 def available() -> bool:
     try:
         lib()

@@ -34,19 +34,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
 
 def _sds(ref_array, shape, dtype):
     """ShapeDtypeStruct carrying the reference array's varying-mesh-axes
-    annotation, so the kernels also work inside shard_map (check_vma).
-    Pre-vma jax (0.4.x) has neither jax.typeof nor the vma kwarg — there
-    the plain struct is the correct (and only) spelling."""
-    if hasattr(jax, "typeof"):
-        return jax.ShapeDtypeStruct(shape, dtype,
-                                    vma=jax.typeof(ref_array).vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    annotation, so the kernels also work inside shard_map (check_vma)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(ref_array).vma)
 
 
 def _pos_mask(qi_base, kb_base, bq, bk, *, causal: bool,
@@ -461,11 +457,20 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: Optional[float] = None,
-                    force: Optional[str] = None) -> jax.Array:
+                    force: Optional[str] = None, mesh=None,
+                    batch_axis: str = "dp",
+                    head_axis: str = "tp") -> jax.Array:
     """Dispatch: pallas kernel on TPU, dense reference elsewhere.
 
     force: "pallas" | "reference" | "interpret" overrides the platform
     check (tests use "interpret" to run the kernel on CPU).
+
+    mesh: the mesh the caller's jit is partitioned over, if any. GSPMD
+    cannot split a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"),
+    so with a mesh the kernel runs per shard — batch over `batch_axis`,
+    heads over `head_axis`, whichever of the two the mesh has. The
+    reference path is plain XLA ops and stays with the partitioner.
     """
     if q.shape[1] % k.shape[1]:
         raise ValueError(f"q heads {q.shape[1]} must be a multiple of "
@@ -474,11 +479,19 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if mode is None:
         mode = "pallas" if jax.devices()[0].platform == "tpu" \
             else "reference"
-    if mode == "pallas":
-        return flash_attention(q, k, v, causal=causal, scale=scale)
-    if mode == "interpret":
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               interpret=True)
-    from ..parallel.sp import attention_reference, expand_kv_heads
-    k, v = expand_kv_heads(k, v, q.shape[1] // k.shape[1])
-    return attention_reference(q, k, v, causal=causal, scale=scale)
+    if mode not in ("pallas", "interpret"):
+        from ..parallel.sp import attention_reference, expand_kv_heads
+        k, v = expand_kv_heads(k, v, q.shape[1] // k.shape[1])
+        return attention_reference(q, k, v, causal=causal, scale=scale)
+    kernel = functools.partial(flash_attention, causal=causal, scale=scale,
+                               interpret=mode == "interpret")
+    if mesh is None:
+        return kernel(q, k, v)
+    axes = mesh.axis_names
+    spec = P(batch_axis if batch_axis in axes else None,
+             head_axis if head_axis in axes else None, None, None)
+    # interpret mode: jax's HLO interpreter cannot propagate vma through
+    # pallas calls yet (same rule as parallel/sp.sp_impl_for)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec,
+                         check_vma=mode != "interpret")(q, k, v)

@@ -7,20 +7,32 @@ full-pool masking on EVERY decode step; this module replaces that hot
 path with one block-table-aware Pallas kernel that reads KV blocks *in
 place* from the pool:
 
-* grid ``(B, H_kv)`` — each program owns one (row, kv-head) pair, so
-  GQA query groups share one K/V fetch and the speculative verify's
-  ``spec_k + 1`` draft positions share one block-table walk (the
-  "fused verify" is the same kernel at ``T = spec_k + 1``).
-* the block table rides in SMEM; assigned blocks are DMA'd from the
-  HBM pool into a VMEM scratch, unassigned (``-1``) entries are
+* grid ``(B, blocks_per_seq)`` — the block table and positions are
+  scalar-prefetched into SMEM, and the K/V ``BlockSpec`` index maps read
+  the table, so the Pallas pipeline itself DMAs each row's assigned
+  ``[block_size, H_kv, D]`` blocks out of the HBM pool, double-buffered.
+  Whole blocks only: this Mosaic refuses a DMA slice whose two minor
+  dims are not tile-aligned ("Slice shape along dimension 2 must be
+  aligned to tiling (8)"), which rules out lifting one head's
+  ``[block_size, D]`` slab out of a token-major pool by hand.
+* each step splits its block by kv head into a per-row
+  ``[H_kv, L, D]`` VMEM assembly; unassigned (``-1``) entries are
   skipped by predication (their slice is zeroed so stale VMEM bytes —
-  NaN bit patterns included — can never poison the masked matmul).
-* the in-kernel math mirrors `serve.kv_cache.masked_attention`
-  operation-for-operation (f32 scores, divide-after-dot scale, the
-  same ``-1e30`` additive mask, `jax.nn.softmax`), which is what makes
-  the kernel BIT-EXACT against the XLA oracle in interpret mode — the
-  tier-1 parity contract (tests/test_serve_kernels.py) that lets CPU
-  CI guard a TPU kernel.
+  NaN bit patterns included — can never poison the masked matmul) and
+  cost no fetch beyond the first, because the pipeline re-fetches only
+  on an index change.
+* the last step runs the attention, one kv head at a time, so GQA query
+  groups share one K/V fetch and the speculative verify's ``spec_k + 1``
+  draft positions share one block-table walk (the "fused verify" is the
+  same kernel at ``T = spec_k + 1``). The math mirrors
+  `serve.kv_cache.masked_attention` operation-for-operation (f32
+  scores, divide-after-dot scale, the same ``-1e30`` additive mask,
+  `jax.nn.softmax`), which is what makes the kernel BIT-EXACT against
+  the XLA oracle in interpret mode — the tier-1 parity contract
+  (tests/test_serve_kernels.py). That contract guards the arithmetic;
+  whether the TPU compiler accepts the kernel is guarded by
+  tests/test_tpu_lowering.py (Pallas lowering) and `chip_smoke.py`
+  (Mosaic + a tolerance check against the oracle on the chip).
 
 Selection is the strict-parsed ``HOROVOD_SERVE_KERNEL`` knob
 (``pallas | xla | auto``), resolved ONCE at executor build
@@ -91,63 +103,86 @@ def resolve_kernel(explicit: Optional[str] = None, *,
 # paged decode / fused-verify attention kernel
 # ---------------------------------------------------------------------------
 
-def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref,
-                       k_scr, v_scr, sem, *, T: int, G: int, BS: int,
-                       nblk: int, D: int):
-    """One (row, kv-head) program: assemble the row's KV from its block
-    table into VMEM, then run the oracle's masked-attention math over
-    the assembled ``[nblk * BS, D]`` view for all ``T * G`` queries
-    (T positions x G grouped query heads) at once."""
+def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, kb_ref, vb_ref, o_ref,
+                       k_scr, v_scr, *, G: int, BS: int, nblk: int):
+    """One (row, table entry) grid step. The pipeline has already
+    fetched pool block ``max(table[b, j], 0)`` into ``kb_ref``/``vb_ref``
+    (``[1, BS, KV, D]``); an assigned block is split by kv head into the
+    row's ``[KV, nblk * BS, D]`` VMEM assembly, an unassigned (``-1``)
+    entry zero-fills its slice. The last entry's step runs the oracle's
+    masked-attention math over the assembled view, one kv head at a
+    time, for all ``T * G`` queries of that head (T positions x G
+    grouped query heads) at once."""
     b = pl.program_id(0)
-    kvh = pl.program_id(1)
+    j = pl.program_id(1)
+    KV, L, D = k_scr.shape
+    dst = pl.ds(pl.multiple_of(j * BS, BS), BS)
+    blk = tbl_ref[b, j]
 
-    def fetch(j, carry):
-        blk = tbl_ref[b, j]
+    @pl.when(blk >= 0)
+    def _():
+        for h in range(KV):
+            k_scr[h, dst, :] = kb_ref[0, :, h, :]
+            v_scr[h, dst, :] = vb_ref[0, :, h, :]
 
-        @pl.when(blk >= 0)
-        def _():
-            ck = pltpu.make_async_copy(
-                kp_ref.at[blk, :, kvh], k_scr.at[pl.ds(j * BS, BS)],
-                sem.at[0])
-            cv = pltpu.make_async_copy(
-                vp_ref.at[blk, :, kvh], v_scr.at[pl.ds(j * BS, BS)],
-                sem.at[1])
-            ck.start()
-            cv.start()
-            ck.wait()
-            cv.wait()
+    @pl.when(blk < 0)
+    def _():
+        # unassigned entry: zero the slice so stale scratch bytes (NaN
+        # bit patterns included) can never poison the 0-probability
+        # value matmul (0 * NaN)
+        k_scr[:, dst, :] = jnp.zeros((KV, BS, D), k_scr.dtype)
+        v_scr[:, dst, :] = jnp.zeros((KV, BS, D), v_scr.dtype)
 
-        @pl.when(blk < 0)
-        def _():
-            # unassigned entry, skipped by predication: zero the slice
-            # so stale scratch bytes (NaN bit patterns included) can
-            # never poison the 0-probability value matmul (0 * NaN)
-            k_scr[pl.ds(j * BS, BS)] = jnp.zeros((BS, D), k_scr.dtype)
-            v_scr[pl.ds(j * BS, BS)] = jnp.zeros((BS, D), v_scr.dtype)
+    @pl.when(j == nblk - 1)
+    def _():
+        pos = pos_ref[b]
+        TG = q_ref.shape[2]
+        # query row r = t * G + g may see key j iff j <= pos + t, i.e.
+        # (j - pos) * G <= r — the oracle's mask without a vector
+        # integer division
+        r_of = jax.lax.broadcasted_iota(jnp.int32, (TG, L), 0)
+        j_of = jax.lax.broadcasted_iota(jnp.int32, (TG, L), 1)
+        valid = (j_of - pos) * G <= r_of
 
-        return carry
+        def head(h, carry):
+            q = q_ref[0, h].astype(jnp.float32)              # [T*G, D]
+            kf = k_scr[h].astype(jnp.float32)                # [L, D]
+            vf = v_scr[h].astype(jnp.float32)
+            # divide-after-dot, exactly like the oracle's einsum / sqrt(D)
+            s = jax.lax.dot_general(
+                q, kf, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) / np.sqrt(D)
+            s = jnp.where(valid, s, MASK_VALUE)
+            p = jax.nn.softmax(s, axis=-1)
+            o = jax.lax.dot_general(p, vf, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            o_ref[0, h] = o.astype(o_ref.dtype)
+            return carry
 
-    jax.lax.fori_loop(0, nblk, fetch, 0)
+        jax.lax.fori_loop(0, KV, head, 0)
 
-    pos = pos_ref[b]
+
+def _vmem_limit_bytes(TG: int, KV: int, D: int, BS: int, nblk: int,
+                      itemsize: int) -> int:
+    """Scoped-VMEM request for one program, from the shapes. Mosaic pads
+    the two minor dims of every buffer to its (sublane, 128-lane) tile,
+    so D=64 costs as much as D=128 and a ``[BS, KV, D]`` bf16 block with
+    KV=12 occupies a full 16 x 128 tile per token. Never below the
+    compiler's 16 MiB default; a request beyond the chip's VMEM is the
+    compiler's error to raise."""
+    def up(x, m):
+        return pl.cdiv(x, m) * m
+
+    sub = 8 * (4 // itemsize)
+    lanes = up(D, 128)
     L = nblk * BS
-    # [T, G, D] -> [T*G, D]: one matmul for the whole GQA group across
-    # every verify position — the fetch above is shared by all of them
-    q = q_ref[0].reshape(T * G, D).astype(jnp.float32)
-    kf = k_scr[...].astype(jnp.float32)
-    vf = v_scr[...].astype(jnp.float32)
-    # divide-after-dot, exactly like the oracle's einsum / sqrt(D)
-    s = jax.lax.dot_general(
-        q, kf, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) / np.sqrt(D)
-    t_of = jax.lax.broadcasted_iota(jnp.int32, (T * G, L), 0) // G
-    j_of = jax.lax.broadcasted_iota(jnp.int32, (T * G, L), 1)
-    valid = j_of <= pos + t_of
-    s = jnp.where(valid, s, MASK_VALUE)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jax.lax.dot_general(p, vf, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    o_ref[0] = o.reshape(T, G, D).astype(o_ref.dtype)
+    assembly = 2 * KV * up(L, sub) * lanes * itemsize    # k_scr, v_scr
+    windows = 2 * 2 * BS * up(KV, sub) * lanes * itemsize
+    windows += 2 * 2 * KV * up(TG, sub) * lanes * itemsize
+    scores = 6 * up(TG, 8) * L * 4            # [T*G, L] f32 temporaries
+    head = 2 * L * lanes * 4                  # one head's f32 K and V
+    need = assembly + windows + scores + head
+    return max(16 << 20, need + need // 4)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -157,27 +192,48 @@ def _paged_attention_call(q, pool_k, pool_v, block_tables, positions,
     _NB, BS, KV, _ = pool_k.shape
     nblk = block_tables.shape[1]
     G = H // KV
-    kern = functools.partial(_paged_attn_kernel, T=T, G=G, BS=BS,
-                             nblk=nblk, D=D)
-    return pl.pallas_call(
+    # [B, T, H, D] -> [B, KV, T*G, D] (row order t*G + g, the oracle's
+    # flattening): every block's two minor dims then span the whole
+    # array, which is what the TPU lowering requires of a block
+    qf = q.reshape(B, T, KV, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        B, KV, T * G, D)
+
+    def row_map(b, j, tbl, pos):
+        return b, 0, 0, 0
+
+    def blk_map(b, j, tbl, pos):
+        # -1 entries fetch block 0 and the kernel ignores what arrives;
+        # the pipeline re-fetches only when the index changes, so a
+        # row's unassigned tail costs one block, not nblk
+        return jnp.maximum(tbl[b, j], 0), 0, 0, 0
+
+    kern = functools.partial(_paged_attn_kernel, G=G, BS=BS, nblk=nblk)
+    out = pl.pallas_call(
         kern,
-        grid=(B, KV),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),    # tables [B, nblk]
-            pl.BlockSpec(memory_space=pltpu.SMEM),    # positions [B]
-            pl.BlockSpec((1, T, G, D), lambda b, h: (b, 0, h, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # pool_k (in place)
-            pl.BlockSpec(memory_space=pltpu.ANY),     # pool_v (in place)
-        ],
-        out_specs=pl.BlockSpec((1, T, G, D), lambda b, h: (b, 0, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, T, H, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((nblk * BS, D), pool_k.dtype),
-            pltpu.VMEM((nblk * BS, D), pool_v.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,            # block tables, positions
+            grid=(B, nblk),
+            in_specs=[
+                pl.BlockSpec((1, KV, T * G, D), row_map),
+                pl.BlockSpec((1, BS, KV, D), blk_map),   # pool_k block
+                pl.BlockSpec((1, BS, KV, D), blk_map),   # pool_v block
+            ],
+            out_specs=pl.BlockSpec((1, KV, T * G, D), row_map),
+            scratch_shapes=[
+                pltpu.VMEM((KV, nblk * BS, D), pool_k.dtype),
+                pltpu.VMEM((KV, nblk * BS, D), pool_v.dtype),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, KV, T * G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # rows are independent; a row's table walk fills the
+            # assembly in order and the output block is written last
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit_bytes(
+                T * G, KV, D, BS, nblk, pool_k.dtype.itemsize)),
         interpret=interpret,
-    )(block_tables, positions, q, pool_k, pool_v)
+    )(block_tables, positions, qf, pool_k, pool_v)
+    return out.reshape(B, KV, T, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        B, T, H, D)
 
 
 def paged_attention_fused(q: jax.Array, pool_k: jax.Array,

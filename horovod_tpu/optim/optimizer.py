@@ -335,19 +335,12 @@ def distributed_grad(fun, argnums=0, *, has_aux: bool = False,
 
 
 def _to_varying(leaf, axis_name):
-    """unvarying -> device-varying cast; pcast on current jax, pvary on
-    older releases (pvary is deprecated in favor of pcast). Identity when
-    the leaf is already device-varying over `axis_name` (a sharded input:
-    pcast varying->varying raises) — and on pre-vma jax (0.4.x), where
-    shard_map has no varying/unvarying distinction to reconcile."""
-    vma = getattr(getattr(leaf, "aval", None), "vma", None)
-    if vma and axis_name in vma:
+    """unvarying -> device-varying cast. Identity when the leaf is
+    already device-varying over `axis_name` (a sharded input: pcast
+    varying->varying raises)."""
+    if axis_name in jax.typeof(leaf).vma:
         return leaf
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(leaf, axis_name, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(leaf, axis_name)
-    return leaf
+    return jax.lax.pcast(leaf, axis_name, to="varying")
 
 
 #: TF-flavored alias (scripts ported from hvd.DistributedGradientTape)

@@ -23,16 +23,11 @@ from jax import lax
 
 
 def _pvary(x, axes):
-    """Mark `x` device-varying over `axes` (pcast on new jax, pvary on
-    old, identity on pre-vma 0.4.x where there is no varying/unvarying
-    distinction) — the one copy of the compatibility shim."""
+    """Mark `x` device-varying over `axes`."""
     if isinstance(axes, str):
         axes = (axes,)
     for ax in axes:
-        if hasattr(lax, "pcast"):
-            x = lax.pcast(x, ax, to="varying")
-        elif hasattr(lax, "pvary"):
-            x = lax.pvary(x, ax)
+        x = lax.pcast(x, ax, to="varying")
     return x
 
 

@@ -495,18 +495,24 @@ class ShardedExecutor:
         in each cache leaf (leaf order) — what the per-slot crc ledger
         (SlotKVCache.crc_update/crc_check) streams over. Decode reads
         one position; the verify-on-read pass re-reads the whole valid
-        prefix once per retiring request."""
-        return [np.asarray(l[slot, start:stop]).tobytes()
-                for l in self._cache_leaves()]
+        prefix once per retiring request. Reads under the step lock:
+        off CPU the step DONATES the cache, so a reader on another
+        thread (the migration endpoint) must never hold leaves across
+        a step."""
+        with self._swap_lock:
+            return [np.asarray(l[slot, start:stop]).tobytes()
+                    for l in self._cache_leaves()]
 
     def kv_block_bytes(self, block: int, start: int,
                        stop: int) -> list:
         """Paged sibling of :meth:`kv_slot_bytes`: host bytes of
         positions ``[start, stop)`` of pool block ``block`` in each
         cache leaf — what the per-BLOCK crc ledger
-        (BlockPool.crc_stream/crc_check) runs over."""
-        return [np.asarray(l[block, start:stop]).tobytes()
-                for l in self._cache_leaves()]
+        (BlockPool.crc_stream/crc_check) runs over. Under the step
+        lock, like :meth:`kv_slot_bytes`."""
+        with self._swap_lock:
+            return [np.asarray(l[block, start:stop]).tobytes()
+                    for l in self._cache_leaves()]
 
     def copy_kv_block(self, src: int, dst: int) -> None:
         """Device-side copy of pool block ``src`` onto ``dst`` in every
@@ -634,12 +640,25 @@ class ShardedExecutor:
             return None
         return float(np.median(self.step_latencies_ms))
 
+    def lowered_decode_text(self) -> str:
+        """The decode step ([max_batch, 1]) lowered for this backend,
+        as text — what a caller greps to see which attention path the
+        resolved ``kernel`` actually put in the program (the fused
+        kernel is a ``tpu_custom_call`` on TPU). Lowering only: nothing
+        is compiled, run or donated."""
+        B = self.max_batch
+        zi = jnp.zeros((B,), jnp.int32)
+        s = self._default_sample()
+        args = [self.params, self.cache, jnp.zeros((B, 1), jnp.int32), zi,
+                jnp.zeros((B,), bool), zi,
+                jnp.asarray(s["temperature"]), jnp.asarray(s["top_p"]),
+                jnp.asarray(s["seed"]), jnp.asarray(s["ctr"])]
+        if self.paged:
+            args.append(jnp.full((B, self.blocks_per_seq), -1, jnp.int32))
+        return self._fwd_token.lower(*args).as_text()
+
     def jit_cache_size(self) -> int:
-        """Compiled-program count across the step functions (falls back
-        to the executed-signature count on jax versions without the
-        introspection hook) — the churn tests assert this is flat."""
-        try:
-            return int(self._fwd_token._cache_size()
-                       + self._fwd_verify._cache_size())
-        except Exception:  # noqa: BLE001 — private API across jax versions
-            return len(self.signatures)
+        """Compiled-program count across the step functions — the churn
+        tests assert this is flat."""
+        return int(self._fwd_token._cache_size()
+                   + self._fwd_verify._cache_size())

@@ -329,15 +329,18 @@ class TestVGGAndInception:
         assert last_e["ConvBN_0"]["Conv_0"]["kernel"].shape[-2] == 2048
 
 
-def test_bench_model_registries_in_sync():
-    """bench.py keeps a literal mirror of bench_zoo.BENCH_MODELS (so its
-    parent process never imports jax); this pins the two together."""
+def test_bench_rejects_unknown_model_before_device_work(monkeypatch, capsys):
+    """bench.py validates HVD_BENCH_MODEL against bench_zoo.BENCH_MODELS
+    (the one registry) before it touches a device."""
     import importlib.util
+    import json
     import os
     spec = importlib.util.spec_from_file_location(
         "bench_main", os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "bench.py"))
     bench_main = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench_main)
-    from horovod_tpu.models.bench_zoo import BENCH_MODELS
-    assert tuple(bench_main._BENCH_MODELS) == tuple(BENCH_MODELS)
+    monkeypatch.setenv("HVD_BENCH_MODEL", "resnet5000")
+    assert bench_main.main() == 1
+    line = json.loads(capsys.readouterr().out.strip())
+    assert line["value"] is None and "resnet5000" in line["error"]
