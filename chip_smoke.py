@@ -41,6 +41,7 @@ from __future__ import annotations
 import importlib.metadata
 import json
 import os
+import statistics
 import sys
 import time
 
@@ -60,6 +61,7 @@ from horovod_tpu.parallel.mesh_utils import make_mesh
 from horovod_tpu.parallel.tp import gpt_partition_rules, shard_params
 from horovod_tpu.serve import (AdmissionQueue, ContinuousBatcher,
                                ShardedExecutor, kv_cache, pool_blocks_for)
+from horovod_tpu.trace import get_recorder
 from horovod_tpu.training import make_gspmd_train_step
 
 #: the model of the builders' captures, at full width and depth
@@ -307,6 +309,7 @@ def serve_phase(log: CompileLog, widths: dict, params, shape: dict, *,
     """One replica on the first device (`mesh=None`). `decode_kernel=
     None` leaves HOROVOD_SERVE_KERNEL / the platform in charge."""
     t0, mark = time.perf_counter(), log.mark()
+    t_rec = get_recorder().now()    # this phase's spans start here
     max_len, vocab = widths["max_seq_len"], widths["vocab_size"]
     B, block, new = shape["max_batch"], shape["kv_block"], shape["new_tokens"]
     cfg = GPTConfig(**widths, decode=True, kv_block_size=block,
@@ -361,7 +364,9 @@ def serve_phase(log: CompileLog, widths: dict, params, shape: dict, *,
             "steps": sorted(f"{k}:{t}" for k, t in ex.signatures),
             "info_warmup_s": round(warm_s, 3),
             "info_first_run_s": round(run_s, 3),
-            "info_step_ms_p50": round(ex.p50_step_ms(), 3),
+            "info_step_ms_p50": round(statistics.median(
+                s.duration_ms for s in get_recorder().between(t_rec, 1e18)
+                if s.name == "exec_step"), 3),
             "devices": {"params": device_ids(ex.params),
                         "kv_pool": device_ids(ex.cache)},
             "local_devices": jax.local_device_count(),

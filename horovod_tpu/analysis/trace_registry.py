@@ -1,8 +1,9 @@
 """Span/leg-registry lint (pass ``trace-registry``).
 
 The tracing plane's equivalent of the knob and metric registries: the
-span names processes record (``SpanRecorder.record`` /
-``record_process``, ``TraceAssembler.span``) and the leg labels the
+span names processes record (``SpanRecorder.record_local`` /
+``record_process`` / ``span``, ``TraceAssembler.span``) and the
+leg labels the
 router's ``hvd_trace_leg_ms{leg,pool}`` histograms carry are declared
 ONCE, in ``trace/spans.py``'s :data:`~horovod_tpu.trace.spans.
 SPAN_LEGS` table (legs: the :data:`~horovod_tpu.trace.spans.LEGS`
@@ -46,10 +47,12 @@ DESCRIPTION = ("span names recorded anywhere must be declared in "
 _SPANS_PATH = "horovod_tpu/trace/spans.py"
 _DOCS = "docs/tracing.md"
 
-#: recording-call shapes: dotted-name suffix -> index of the span-name
-#: argument. ``record``/``span`` take (ctx, name, ...);
-#: ``record_process`` takes (name, ...).
-_RECORD_CALLS = {"record": 1, "span": 1, "record_process": 0}
+#: recording-call shapes: dotted-name suffix -> the positions the
+#: span-name argument may take. ``TraceAssembler.span`` takes
+#: (ctx, name, ...); ``record_process``, ``record_local`` and
+#: ``SpanRecorder.span`` take (name, ...).
+_RECORD_CALLS = {"span": (0, 1), "record_process": (0,),
+                 "record_local": (0,)}
 
 
 def _declared(sf: SourceFile) -> Tuple[Dict[str, Optional[str]],
@@ -95,10 +98,10 @@ def _recorded_names(sf: SourceFile) -> List[Tuple[str, int, int]]:
         cn = call_name(node)
         if cn is None:
             continue
-        idx = _RECORD_CALLS.get(cn.rsplit(".", 1)[-1])
-        if idx is None or len(node.args) <= idx:
-            continue
-        name = str_const(node.args[idx])
+        name = next(
+            (n for n in (str_const(node.args[i]) for i in
+                         _RECORD_CALLS.get(cn.rsplit(".", 1)[-1], ())
+                         if i < len(node.args)) if n is not None), None)
         if name is not None:
             out.append((name, node.lineno,
                         getattr(node, "end_lineno", node.lineno)))

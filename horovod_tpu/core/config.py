@@ -397,7 +397,8 @@ class Config:
     trace_sample: float = 0.0
     # Per-process span-ring capacity, total spans (HOROVOD_TRACE_RING):
     # a worker whose router never collects evicts oldest-trace-first
-    # past this bound.
+    # past this bound. The always-on local ring (trace/spans.py) holds
+    # eight times as many and evicts its oldest span.
     trace_ring: int = 4096
     # Retained-trace ring on the router (HOROVOD_TRACE_RETAIN): the
     # last N tail-sampled traces kept for the flight recorder.

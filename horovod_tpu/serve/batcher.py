@@ -93,10 +93,17 @@ class _Active:
     #: and migration must fence the packet OUT, not relabel stale KV
     #: as current.
     params_version: Optional[int] = None
+    #: monotonic stamp when this sequence's admission wave started
+    #: its prefill (install time for a migrated sequence): the queue
+    #: wait ends here
+    t_admit: Optional[float] = None
     #: monotonic stamp of the first generated token (prefill-step end,
-    #: or install time for a migrated sequence) — the traced decode
-    #: span's start (docs/tracing.md)
+    #: or install time for a migrated sequence) — the decode span's
+    #: start (docs/tracing.md)
     t_first: Optional[float] = None
+    #: one monotonic stamp per entry of `out`: the end of the step that
+    #: emitted it, shared by the rows of that step
+    token_times: List[float] = field(default_factory=list)
     #: monotonic stamp when the sequence parked for migration — the
     #: traced park span's start (serve/kv_migrate.py records its end
     #: at pack time)
@@ -367,10 +374,10 @@ class ContinuousBatcher:
                 got = self._weights.poll()
                 if got is not None:
                     version, tree = got
-                    t_sw = time.time()
+                    t_sw = time.monotonic()
                     self.executor.swap_params(tree, version=version)
                     _trace_recorder().record_process(
-                        "weight_fence", t_sw, time.time(),
+                        "weight_fence", t_sw, time.monotonic(),
                         version=version)
             except Exception as e:  # noqa: BLE001 — serve on stale
                 import logging
@@ -495,53 +502,69 @@ class ContinuousBatcher:
         if hb is not None:
             hb()
         self._fire_step_chaos()
-        self._maybe_swap_weights()
-        # stale-weight KV must never serve a new version: any adopted
-        # swap (or router-requested flush) invalidates the prefix cache
-        # BEFORE this iteration can match against it
-        self._maybe_flush_prefix()
-        # expired-but-still-queued requests get their structured
-        # deadline completion NOW, even when every slot is busy —
-        # within one iteration, not at slot-drain time
-        self.queue.reap_expired()
-        # migration plumbing (single-writer: all pool/row bookkeeping
-        # happens HERE, on the scheduler thread — the endpoint only
-        # enqueues): free rows the endpoint released, reap abandoned
-        # parked rows, install migrated sequences BEFORE admission so
-        # a mid-stream arrival is never starved by local newcomers
-        self._drain_parked_release()
-        self._install_migrated()
-        self._retire()
-        # KV tier (serve/kvtier/): install router-pulled runs, then
-        # promote ladder-held prefixes of waiting prompts BEFORE the
-        # admission wave matches — a promoted block is indistinguishable
-        # from a locally cached one by the time _plan walks the tree
-        if self.kvtier is not None:
-            if self.kvtier.has_grafts():
-                self.kvtier.install_grafts()
-            if not self.kvtier.empty():
-                for p in self.queue.peek_prompts(
-                        self.executor.max_batch):
-                    self.kvtier.promote_for(p)
-        admitted = self._admit()
-        if admitted:
-            self._prefill(admitted)
-            self._retire()  # a 1-token request finishes at prefill
-        if self._active:
-            self._decode()
-        # evaluated EVERY iteration, busy or idle: the iteration counter
-        # below ticks regardless, so an exact-'at' corrupt landing while
-        # the replica is idle must still be captured (and deferred to
-        # the next written slot) — inside the busy branch the counter
-        # would walk past the address without fire() ever seeing it
-        self._fire_kv_chaos()
-        if self._active:
-            self._retire()
+        rec = _trace_recorder()
+        with rec.span("sched_iteration"):
+            self._iterate(rec)
         self.iterations += 1
         return bool(self._active) or bool(self._reprefill) \
             or self.queue.depth() > 0 or bool(self._migrate_in) \
             or bool(self._parked_release) \
             or (self.kvtier is not None and self.kvtier.has_grafts())
+
+    def _iterate(self, rec) -> None:
+        """The body of one iteration, inside its ``sched_iteration``
+        span; each phase is a child span in the process's span ring
+        (docs/tracing.md, "The local flight recorder")."""
+        self._maybe_swap_weights()
+        # stale-weight KV must never serve a new version: any adopted
+        # swap (or router-requested flush) invalidates the prefix cache
+        # BEFORE this iteration can match against it
+        self._maybe_flush_prefix()
+        with rec.span("sched_retire"):
+            # expired-but-still-queued requests get their structured
+            # deadline completion NOW, even when every slot is busy —
+            # within one iteration, not at slot-drain time
+            self.queue.reap_expired()
+            # migration plumbing (single-writer: all pool/row
+            # bookkeeping happens HERE, on the scheduler thread — the
+            # endpoint only enqueues): free rows the endpoint released,
+            # reap abandoned parked rows, install migrated sequences
+            # BEFORE admission so a mid-stream arrival is never starved
+            # by local newcomers
+            self._drain_parked_release()
+            self._install_migrated()
+            self._retire()
+        with rec.span("sched_admit"):
+            # KV tier (serve/kvtier/): install router-pulled runs, then
+            # promote ladder-held prefixes of waiting prompts BEFORE
+            # the admission wave matches — a promoted block is
+            # indistinguishable from a locally cached one by the time
+            # _plan walks the tree
+            if self.kvtier is not None:
+                if self.kvtier.has_grafts():
+                    self.kvtier.install_grafts()
+                if not self.kvtier.empty():
+                    for p in self.queue.peek_prompts(
+                            self.executor.max_batch):
+                        self.kvtier.promote_for(p)
+            admitted = self._admit()
+        if admitted:
+            with rec.span("sched_prefill"):
+                self._prefill(admitted)
+            with rec.span("sched_retire"):
+                self._retire()  # a 1-token request finishes at prefill
+        if self._active:
+            with rec.span("sched_decode"):
+                self._decode()
+        # evaluated EVERY iteration, busy or idle: the iteration counter
+        # ticks regardless, so an exact-'at' corrupt landing while
+        # the replica is idle must still be captured (and deferred to
+        # the next written slot) — inside the busy branch the counter
+        # would walk past the address without fire() ever seeing it
+        self._fire_kv_chaos()
+        if self._active:
+            with rec.span("sched_retire"):
+                self._retire()
 
     def run(self, max_iterations: Optional[int] = None) -> None:
         """Drive until drained (loopback/bench mode)."""
@@ -795,24 +818,43 @@ class ContinuousBatcher:
             top_p=float(meta.get("top_p", 1.0)),
             seed=int(meta.get("seed", 0)),
             trace=meta.get("trace"))
+        # the handle was made before its request: stamps start here
+        # (the tokens the sender emitted all carry the install time)
+        req.handle._request, req.handle.t_submit = req, now
         seq = _Active(req=req, slot=row, out=out,
                       cache_len=cache_len,
                       rng_ctr=int(meta.get("rng_ctr", 1)),
-                      t_first=now)
+                      t_admit=now, t_first=now,
+                      token_times=[now] * len(out))
         self.kv.lengths[row] = cache_len
         self._active[row] = seq
         self.migrations_in += 1
         return ("installed", None)
 
     # -- internals -----------------------------------------------------------
-    def _stats(self) -> dict:
+    def _stats(self) -> Optional[dict]:
+        """Refresh the occupancy gauges; the SERVE timeline row's
+        fields only where the executor has a timeline to write to."""
         occ = self.kv.occupancy()
         self._m_occupancy.set(occ)
         if self.paged:
             self._m_blocks.set(self.kv.pool.in_use())
+        if self.executor.timeline is None:
+            return None
         return {"queue_depth": self.queue.depth(),
                 "occupancy": round(occ, 3),
                 "shed": self.queue.shed_count}
+
+    def _resolve(self, seq: _Active, status: str, ms: float, *,
+                 error: Optional[str] = None) -> None:
+        """Hand the sequence's stamps to its handle and resolve it
+        (an errored request delivers no tokens, so no token stamps)."""
+        h = seq.req.handle
+        h.t_admit, h.t_first = seq.t_admit, seq.t_first
+        keep = error is None
+        h.token_times = list(seq.token_times) if keep else []
+        h._resolve(seq.out if keep else [], status, latency_ms=ms,
+                   error=error)
 
     # -- on-device sampling row data -----------------------------------------
     def _sample_args(self, rows, ctr_offset: int = 0) -> dict:
@@ -949,19 +991,18 @@ class ContinuousBatcher:
                     self.kv_reprefills += 1
                     self._reprefill.append(req)
                 else:
-                    req.handle._resolve(
-                        [], "error", latency_ms=ms, error="kv_corrupt")
+                    self._resolve(seq, "error", ms, error="kv_corrupt")
                 continue
-            if req.trace is not None and seq.t_first is not None \
+            if seq.t_first is not None \
                     and not (req.hold_kv and self.paged):
-                base = time.time() - time.monotonic()
-                _trace_recorder().record(
-                    req.trace, "decode",
-                    seq.t_first + base, now + base,
-                    rid=req.rid, tokens=len(seq.out))
+                trace, root = req.trace_ids()
+                _trace_recorder().record_local(
+                    "decode", seq.t_first, now, trace=trace,
+                    parent=root, ship=req.trace, rid=req.rid,
+                    tokens=len(seq.out), token_times=seq.token_times)
             if expired and not done_ok:
                 self.queue.expired_count += 1
-                req.handle._resolve(seq.out, "expired", latency_ms=ms)
+                self._resolve(seq, "expired", ms)
             elif req.hold_kv and self.paged:
                 # disaggregated prefill: PARK the verified sequence —
                 # row and blocks stay allocated so the endpoint can
@@ -973,11 +1014,11 @@ class ContinuousBatcher:
                 with self._parked_lock:
                     self.parked[req.rid] = seq
                 del self._active[slot]
-                req.handle._resolve(seq.out, "ok", latency_ms=ms)
+                self._resolve(seq, "ok", ms)
                 self.queue.note_service_ms(ms)
                 continue
             else:
-                req.handle._resolve(seq.out, "ok", latency_ms=ms)
+                self._resolve(seq, "ok", ms)
                 self.queue.note_service_ms(ms)
             self._free_seq(slot)
             del self._active[slot]
@@ -1173,21 +1214,20 @@ class ContinuousBatcher:
                 del self._active[a.slot]
                 self._reprefill.append(a.req)
             admitted = [a for a in admitted if a not in hit_rows]
+        # the wave's first tokens exist: one stamp for all its rows
         t_first = time.monotonic()
-        # spans are wall-clock (cross-process merge); map the
-        # scheduler's monotonic stamps through one base per batch
-        base = time.time() - time.monotonic()
         rec = _trace_recorder()
         for a in admitted:
             self._m_ttft.observe(
                 (t_first - a.req.submitted_at) * 1000.0)
-            a.t_first = t_first
-            if a.req.trace is not None:
-                rec.record(a.req.trace, "queue_wait",
-                           a.req.submitted_at + base, t_p0 + base)
-                rec.record(a.req.trace, "prefill",
-                           t_p0 + base, t_first + base,
-                           rid=a.req.rid)
+            a.t_admit, a.t_first = t_p0, t_first
+            a.token_times.append(t_first)
+            trace, root = a.req.trace_ids()
+            rec.record_local("queue_wait", a.req.submitted_at, t_p0,
+                             trace=trace, parent=root, ship=a.req.trace)
+            rec.record_local("prefill", t_p0, t_first, trace=trace,
+                             parent=root, ship=a.req.trace,
+                             rid=a.req.rid)
             n = len(a.req.prompt)
             a.cache_len = n
             a.params_version = self.executor.last_step_version
@@ -1272,6 +1312,7 @@ class ContinuousBatcher:
             tokens, positions, mask, last_idx, kind="decode",
             stats=self._stats(), sample=self._sample_args(rows),
             block_tables=self.kv.table() if self.paged else None)
+        t_tok = time.monotonic()   # one stamp for the step's rows
         self.gen_steps += len(rows)
         for slot in rows:
             seq = self._active[slot]
@@ -1280,6 +1321,7 @@ class ContinuousBatcher:
             seq.cache_len += 1
             self.kv.lengths[slot] = seq.cache_len
             seq.out.append(int(nxt[slot]))
+            seq.token_times.append(t_tok)
             seq.rng_ctr += 1
             self.gen_tokens += 1
 
@@ -1381,6 +1423,7 @@ class ContinuousBatcher:
             stats=self._stats(), sample=self._sample_args(rows),
             draft_probs=dprobs, n_draft=n_draft,
             block_tables=self.kv.table() if self.paged else None)
+        t_tok = time.monotonic()   # one stamp for all the step emitted
         self.gen_steps += len(rows)
         for slot in rows:
             seq = self._active[slot]
@@ -1398,6 +1441,7 @@ class ContinuousBatcher:
             # recomputes those blocks' ledgers
             self._crc_write(slot, seq.cache_len, seq.cache_len + k + 1)
             seq.out.extend(emitted)
+            seq.token_times.extend([t_tok] * len(emitted))
             seq.cache_len += len(emitted)
             self.kv.lengths[slot] = seq.cache_len
             self.gen_tokens += len(emitted)

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import metrics as obs_metrics
+from ..trace.spans import get_recorder as _trace_recorder
 
 logger = logging.getLogger("horovod_tpu")
 
@@ -136,7 +137,6 @@ class ShardedExecutor:
         # -- metrics --
         self.steps = 0
         self.tokens_out = 0
-        self.step_latencies_ms: "deque[float]" = deque(maxlen=1024)
         self._tok_window: "deque[Tuple[float, int]]" = deque(maxlen=1024)
         #: distinct (kind, T) entry points actually executed — the
         #: jit-signature ledger the no-recompile tests assert on
@@ -342,56 +342,59 @@ class ShardedExecutor:
           per-row real proposal counts ``n_draft``.
         """
         t0 = time.perf_counter()
-        self.signatures.add((kind, int(tokens.shape[1])))
-        if self.paged:
-            if block_tables is None:
-                raise ValueError("a paged executor step needs "
-                                 "block_tables")
-            extra = (jnp.asarray(block_tables, jnp.int32),)
-        else:
-            extra = ()
-        s = sample if sample is not None else self._default_sample()
-        sargs = (jnp.asarray(s["temperature"], jnp.float32),
-                 jnp.asarray(s["top_p"], jnp.float32),
-                 jnp.asarray(s["seed"], jnp.uint32),
-                 jnp.asarray(s["ctr"], jnp.int32))
+        T = int(tokens.shape[1])
+        self.signatures.add((kind, T))
+        if self.paged and block_tables is None:
+            raise ValueError("a paged executor step needs block_tables")
+        n_tok = int(np.sum(mask))
+        rec = _trace_recorder()
         probs = None
-        with self._swap_lock:   # the weight-swap version fence
-            self.last_step_version = self.params_version
-            if kind == "verify":
-                B, T = self.max_batch, int(tokens.shape[1])
-                if draft_probs is None:
-                    draft_probs = jnp.zeros((B, T - 1, self.vocab_size),
-                                            jnp.float32)
-                nd = jnp.asarray(
-                    n_draft if n_draft is not None
-                    else np.zeros(B, np.int32), jnp.int32)
-                emitted, n_acc, self.cache = self._fwd_verify(
-                    self.params, self.cache,
-                    jnp.asarray(tokens, jnp.int32),
-                    jnp.asarray(positions, jnp.int32),
-                    jnp.asarray(mask, bool), *sargs, draft_probs, nd,
-                    *extra)
+        # the step and its three legs land in the process's span ring
+        # (docs/tracing.md): which of them the device waits for is what
+        # the serve cell's idle metrics read
+        with rec.span("exec_step", kind=kind, rows=n_tok):
+            with rec.span("exec_upload"):
+                s = sample if sample is not None \
+                    else self._default_sample()
+                args = [jnp.asarray(tokens, jnp.int32),
+                        jnp.asarray(positions, jnp.int32),
+                        jnp.asarray(mask, bool)]
+                if kind == "verify":
+                    B = self.max_batch
+                    if draft_probs is None:
+                        draft_probs = jnp.zeros(
+                            (B, T - 1, self.vocab_size), jnp.float32)
+                    tail = [draft_probs, jnp.asarray(
+                        n_draft if n_draft is not None
+                        else np.zeros(B, np.int32), jnp.int32)]
+                else:
+                    args.append(jnp.asarray(last_idx, jnp.int32))
+                    tail = []
+                args += [jnp.asarray(s["temperature"], jnp.float32),
+                         jnp.asarray(s["top_p"], jnp.float32),
+                         jnp.asarray(s["seed"], jnp.uint32),
+                         jnp.asarray(s["ctr"], jnp.int32)] + tail
+                if self.paged:
+                    args.append(jnp.asarray(block_tables, jnp.int32))
+            with self._swap_lock:   # the weight-swap version fence
+                self.last_step_version = self.params_version
+                fwd = self._fwd_verify if kind == "verify" \
+                    else self._fwd_token
+                with rec.span("exec_dispatch"):
+                    *out, self.cache = fwd(self.params, self.cache,
+                                           *args)
                 # host readback doubles as completion fence — inside
                 # the lock so a swap never lands mid-step
-                nxt = (np.asarray(emitted), np.asarray(n_acc))
-            else:
-                out = self._fwd_token(
-                    self.params, self.cache,
-                    jnp.asarray(tokens, jnp.int32),
-                    jnp.asarray(positions, jnp.int32),
-                    jnp.asarray(mask, bool),
-                    jnp.asarray(last_idx, jnp.int32), *sargs, *extra)
-                if self.role == "draft":
-                    tok, probs, self.cache = out
-                else:
-                    tok, self.cache = out
-                nxt = np.asarray(tok)
+                with rec.span("exec_readback"):
+                    if kind == "verify":
+                        nxt = (np.asarray(out[0]), np.asarray(out[1]))
+                    else:
+                        nxt = np.asarray(out[0])
+                        if self.role == "draft":
+                            probs = out[1]
         dt_ms = (time.perf_counter() - t0) * 1000.0
         self.steps += 1
-        self.step_latencies_ms.append(dt_ms)
         self._m_step_ms.get(kind, self._m_step_ms["decode"]).observe(dt_ms)
-        n_tok = int(np.sum(mask))
         self.tokens_out += n_tok
         self._m_tokens.inc(n_tok)
         self._tok_window.append((time.perf_counter(), n_tok))
@@ -634,11 +637,6 @@ class ShardedExecutor:
             return 0.0
         toks = sum(n for _, n in self._tok_window) - self._tok_window[0][1]
         return toks / (t_last - t_first)
-
-    def p50_step_ms(self) -> Optional[float]:
-        if not self.step_latencies_ms:
-            return None
-        return float(np.median(self.step_latencies_ms))
 
     def lowered_decode_text(self) -> str:
         """The decode step ([max_batch, 1]) lowered for this backend,

@@ -732,7 +732,7 @@ class FleetRouter:
         if src is None or src.batcher is None or dst_tier is None \
                 or src.batcher.kvtier is None:
             return
-        t0 = time.time()
+        t0 = time.monotonic()
         packed = src.batcher.kvtier.export_run(
             prompt, rep.executor.params_version)
         if packed is None:
@@ -750,7 +750,7 @@ class FleetRouter:
         dst_tier.submit_graft(header, blocks)
         self._m_kvtier_pulls.inc()
         _trace_recorder().record_process(
-            "kvtier_pull", t0, time.time(), blocks=len(blocks),
+            "kvtier_pull", t0, time.monotonic(), blocks=len(blocks),
             src=best_rid, dst=rep.id)
 
     def _make_on_resolve(self, tr: _Tracked, rid: int):

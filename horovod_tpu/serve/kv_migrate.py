@@ -152,10 +152,9 @@ def pack_parked(batcher, rid: int, *, fid: str,
             # covers parked-in-_retire -> packed-here
             header["trace"] = req.trace
             if seq.parked_at is not None:
-                base = time.time() - time.monotonic()
-                _trace_recorder().record(
-                    req.trace, "park",
-                    seq.parked_at + base, time.time(), rid=int(rid))
+                _trace_recorder().record_local(
+                    "park", seq.parked_at, time.monotonic(),
+                    ship=req.trace, rid=int(rid))
         return header, payload
     finally:
         batcher.unpin_parked(rid)

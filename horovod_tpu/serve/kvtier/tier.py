@@ -529,7 +529,7 @@ class ReplicaKVTier:
         if n_blocks < 1:
             return 0
         have = self.executor.params_version
-        t0 = time.time()
+        t0 = time.monotonic()
         staged = self._stage_runs(toks, n_blocks, have)
         promoted = self._install_staged(staged, have) if staged else 0
         if promoted:
@@ -539,7 +539,8 @@ class ReplicaKVTier:
             # trace: exempt (process-level span, leg None — see
             # SPAN_LEGS; recorded once per promotion burst)
             _trace_recorder().record_process(
-                "kvtier_promote", t0, time.time(), blocks=promoted)
+                "kvtier_promote", t0, time.monotonic(),
+                blocks=promoted)
             self._gauge_refresh()
         return promoted
 

@@ -60,6 +60,7 @@ from ..chaos.detector import AccrualTracker
 from ..native import resilience
 from ..obs import metrics as obs_metrics
 from ..trace import collect as _tr_collect
+from ..trace.spans import to_wall as _to_wall
 from . import wire
 from .fleet import (FAILOVER_MS_HELP, FAILOVERS_HELP,
                     FLEET_REJECTED_HELP, FleetHandle, REPLICA_UP_HELP,
@@ -704,10 +705,9 @@ class ProcessFleetRouter:
                         (acked[0] - t_d0) * 1000.0)
                     if self.tracer is not None \
                             and tr.trace is not None:
-                        base = time.time() - time.monotonic()
                         self.tracer.span(
-                            tr.trace, "dispatch", t_d0 + base,
-                            acked[0] + base, replica=rep.id)
+                            tr.trace, "dispatch", _to_wall(t_d0),
+                            _to_wall(acked[0]), replica=rep.id)
                 self._on_reply(tr, rep.id, payload)
                 return None
             # control ack: the worker's queue door spoke

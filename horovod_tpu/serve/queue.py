@@ -23,10 +23,11 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence, Tuple
 
 from ..chaos import inject as _chaos
 from ..obs import metrics as obs_metrics
+from ..trace.spans import get_recorder as _trace_recorder
 
 
 class Rejected(Exception):
@@ -88,6 +89,16 @@ class ServeRequest:
     def expired(self, now: Optional[float] = None) -> bool:
         return (now if now is not None else time.monotonic()) > self.deadline
 
+    def trace_ids(self) -> Tuple[str, str]:
+        """``(trace id, id of the request's root span)`` its spans are
+        recorded under: the router's, where it minted a context; else
+        local ones made from ``rid`` (every request is recorded in the
+        process's span ring, traced by a router or not)."""
+        t = self.trace
+        if isinstance(t, dict) and t.get("trace") and t.get("span"):
+            return str(t["trace"]), str(t["span"])
+        return f"rid{self.rid}", f"rid{self.rid}"
+
 
 class ServeHandle:
     """Caller-side completion handle; resolved exactly once by the
@@ -97,17 +108,39 @@ class ServeHandle:
     once with the handle AFTER resolution — the fleet router's
     completion hook. It runs on the resolving thread and must never be
     called while a queue/batcher lock is held (lock-order discipline
-    with the router's own lock)."""
+    with the router's own lock).
+
+    **Stamps** (``time.monotonic()`` seconds, the clock of deadlines;
+    the scheduler writes them where the thing happens, a caller reads
+    them once the handle is done — docs/serving.md): ``t_submit``;
+    ``t_admit`` (its admission wave starts prefill — the queue wait
+    ends); ``t_first`` (the first token exists: ``t_first - t_submit``
+    is the time to first token); ``token_times``, one stamp per entry
+    of ``tokens`` (the end of the step that emitted it, so
+    ``token_times[0] == t_first`` and the differences are the token
+    gaps); ``t_done`` (resolution). What never happened stays None /
+    empty (a request that expired in the queue has no ``t_admit``)."""
 
     def __init__(self, rid: int,
                  on_resolve: Optional[Callable[["ServeHandle"],
-                                               None]] = None):
+                                               None]] = None,
+                 request: Optional[ServeRequest] = None):
         self.rid = rid
         self.status = "pending"
         self.tokens: List[int] = []
         self.error: Optional[str] = None
         self.latency_ms: Optional[float] = None
         self.on_resolve = on_resolve
+        self.t_submit: Optional[float] = (
+            request.submitted_at if request is not None else None)
+        self.t_admit: Optional[float] = None
+        self.t_first: Optional[float] = None
+        self.token_times: List[float] = []
+        self.t_done: Optional[float] = None
+        #: the scheduler-side request this handle answers, until it
+        #: resolves (None for a router's outer handle): what `_resolve`
+        #: records the ``request`` span from
+        self._request = request
         self._event = threading.Event()
         self._rlock = threading.Lock()
 
@@ -127,6 +160,16 @@ class ServeHandle:
             self.status = status
             self.error = error
             self.latency_ms = latency_ms
+            self.t_done = time.monotonic()
+            req, self._request = self._request, None
+            if req is not None:
+                # the request's root span, submit -> resolved, whoever
+                # resolved it (the batcher, the queue's expiry paths);
+                # in the ring before a waiter can wake and read it
+                trace, root = req.trace_ids()
+                _trace_recorder().record_local(
+                    "request", self.t_submit, self.t_done, trace=trace,
+                    span=root, rid=req.rid, status=status)
             self._event.set()
         cb = self.on_resolve
         if cb is not None:
@@ -282,7 +325,8 @@ class AdmissionQueue:
                                temperature=temperature, top_p=top_p,
                                seed=seed, hold_kv=bool(hold_kv),
                                trace=trace)
-            req.handle = ServeHandle(rid, on_resolve=on_resolve)
+            req.handle = ServeHandle(rid, on_resolve=on_resolve,
+                                     request=req)
             self._dq.append(req)
             self._m_admitted.inc()
             self._m_depth.set(len(self._dq))

@@ -389,9 +389,8 @@ class ReplicaEndpoint:
                 "dedupe": bool(ack.get("dedupe", False))}
             tr = header.get("trace")
             if isinstance(tr, dict) and tr.get("trace"):
-                base = time.time() - time.monotonic()
-                _trace_recorder().record(
-                    tr, "migrate_push", t0 + base, time.time(),
+                _trace_recorder().record_local(
+                    "migrate_push", t0, time.monotonic(), ship=tr,
                     fid=str(msg.get("dfid")), bytes=len(payload))
                 spans = _trace_recorder().drain(str(tr["trace"]))
                 if spans:
@@ -438,7 +437,7 @@ class ReplicaEndpoint:
             wire.send_msg(sock, {"ack": "installed", "dedupe": True})
             return
         if mine:
-            t_i0 = time.time()
+            t_i0 = time.monotonic()
             try:
                 blocks = kv_migrate.unpack_blocks(msg, payload)
             except kv_migrate.MigrateCorrupt as e:
@@ -454,9 +453,9 @@ class ReplicaEndpoint:
                     if isinstance(tr, dict) and tr.get("trace"):
                         # decode-side receive span; drained later with
                         # the result op's reply
-                        _trace_recorder().record(
-                            tr, "migrate_install", t_i0, time.time(),
-                            fid=fid, outcome=str(out[0]))
+                        _trace_recorder().record_local(
+                            "migrate_install", t_i0, time.monotonic(),
+                            ship=tr, fid=fid, outcome=str(out[0]))
                     self._finalize_install(
                         fid, ent, out,
                         pending["handle"] if out[0] == "installed"

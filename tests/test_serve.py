@@ -30,6 +30,7 @@ from horovod_tpu.models.gpt import GPT, GPTConfig
 from horovod_tpu.models.llama import Llama, LlamaConfig
 from horovod_tpu.serve import (AdmissionQueue, ContinuousBatcher, Rejected,
                                ShardedExecutor, SlotKVCache)
+from horovod_tpu.trace import get_recorder
 
 _KW = dict(vocab_size=64, num_layers=2, num_heads=2, head_dim=8,
            max_seq_len=48, dtype=jnp.float32, attention_impl="reference")
@@ -298,11 +299,15 @@ class TestObservability:
                     "occupancy", "shed"} <= set(e["args"])
 
     def test_executor_metrics(self, gpt):
+        t_rec = get_recorder().now()
         ex, q, b = _stack(gpt, max_batch=2, warmup=False)
         q.submit(list(range(4)), max_new_tokens=4)
         b.run()
         assert ex.steps >= 4
-        assert ex.p50_step_ms() is not None and ex.p50_step_ms() > 0
+        steps = [s for s in get_recorder().between(t_rec, 1e18)
+                 if s.name == "exec_step"]
+        assert len(steps) == ex.steps
+        assert all(s.duration_ms > 0 for s in steps)
         assert ex.tokens_out >= 4
 
 

@@ -40,6 +40,9 @@ from horovod_tpu.models.llama import Llama, LlamaConfig
 from horovod_tpu.serve import (AdmissionQueue, BlockPool, ContinuousBatcher,
                                PagedKVCache, RadixPrefixCache, Rejected,
                                ShardedExecutor)
+from horovod_tpu.trace import SpanRecorder, TraceContext, get_recorder
+from horovod_tpu.trace import spans as spans_mod
+from horovod_tpu.trace.spans import to_wall
 
 _KW = dict(vocab_size=64, num_layers=2, num_heads=2, head_dim=8,
            max_seq_len=48, dtype=jnp.float32, attention_impl="reference")
@@ -854,3 +857,160 @@ class TestPagedConfigKnobs:
             ContinuousBatcher(ex, q, buckets=(8,),
                               draft_executor=mismatched, spec_k=2,
                               prefix_cache=False)
+
+
+# ---------------------------------------------------------------------------
+# the local flight recorder: spans and token stamps where they happen
+# ---------------------------------------------------------------------------
+
+def _span_scenario(gpt, scenario):
+    """(executor, queue, batcher, draft executor or None, submit kwargs)
+    for one way of generating tokens."""
+    draft, kw, sub = None, {}, {}
+    if scenario == "speculative":
+        draft = _draft_ex(gpt, gpt.params)
+        kw = dict(draft=draft, spec_k=3, prefix=False)
+    elif scenario == "speculative_rejecting":
+        draft = _draft_ex(gpt, gpt.draft_params)
+        kw = dict(draft=draft, spec_k=3, kv_crc=True)
+    elif scenario == "reprefill_after_kv_corruption":
+        inject.install(ChaosPlan.from_dict({"faults": [
+            {"rank": 0, "site": "serve.kv", "kind": "corrupt",
+             "at": 3}]}), rank=0)
+        kw = dict(max_batch=2, kv_crc=True)
+    elif scenario == "router_traced":
+        sub = {"trace": True}
+    ex, q, b = _stack(gpt, **kw)
+    return ex, q, b, draft, sub
+
+
+def _span_run(gpt, scenario, n=5, new=7):
+    """Serve `n` requests one way; the handles and every span the
+    process recorded meanwhile (warm-up included), in a recorder of the
+    run's own so that no other test's spans are among them."""
+    rec, old = SpanRecorder(), spans_mod._recorder
+    spans_mod._recorder = rec
+    try:
+        ex, q, b, draft, sub = _span_scenario(gpt, scenario)
+        rng = np.random.RandomState(21)
+        prompts = [list(rng.randint(0, 64, rng.randint(2, 9)))
+                   for _ in range(n)]
+        handles = [q.submit(
+            p, max_new_tokens=new,
+            trace=TraceContext.mint().to_wire() if sub else None)
+            for p in prompts]
+        b.run()
+    finally:
+        inject.uninstall()
+        spans_mod._recorder = old
+    assert get_recorder() is not rec
+    for p, h in zip(prompts, handles):
+        assert h.status == "ok" and h.tokens == gpt.oracle(p, new)
+    return SimpleNamespace(
+        ex=ex, q=q, b=b, draft=draft, handles=handles, scenario=scenario,
+        rec=rec, spans=rec.between(0.0, float("inf")))
+
+
+class TestLocalSpans:
+    @pytest.fixture(scope="class", params=[
+        "plain", "speculative", "speculative_rejecting",
+        "reprefill_after_kv_corruption", "router_traced"])
+    def r(self, request, gpt):
+        return _span_run(gpt, request.param)
+
+    def test_every_finished_request_has_ordered_stamps(self, r):
+        if r.scenario == "reprefill_after_kv_corruption":
+            assert r.b.kv_reprefills >= 1
+        roots = {s.extra["rid"]: s for s in r.spans
+                 if s.name == "request"}
+        for h in r.handles:
+            assert len(h.token_times) == len(h.tokens)
+            stamps = [h.t_submit, h.t_admit, h.t_first,
+                      *h.token_times, h.t_done]
+            assert all(a <= b for a, b in zip(stamps, stamps[1:]))
+            assert h.t_first == h.token_times[0]
+            root = roots[h.rid]
+            assert (root.t0, root.t1) == (h.t_submit, h.t_done)
+            # each stamp once: the root carries none of its legs'
+            assert root.extra == {"rid": h.rid, "status": "ok"}
+            assert h._request is None       # no cycle left behind
+            mine = [s for s in r.spans if s.trace == root.trace]
+            decode = [s for s in mine if s.name == "decode"][-1]
+            assert decode.extra["token_times"] == h.token_times
+            assert (decode.t0, decode.extra["tokens"]) == \
+                (h.t_first, len(h.tokens))
+            wait = [s for s in mine if s.name == "queue_wait"][-1]
+            assert (wait.t0, wait.t1) == (h.t_submit, h.t_admit)
+            pre = [s for s in mine if s.name == "prefill"][-1]
+            assert (pre.t0, pre.t1) == (h.t_admit, h.t_first)
+
+    def test_exec_steps_are_all_recorded(self, r):
+        """One `exec_step` span per executor step (warm-up included),
+        whose rows add up to the tokens the executor counted."""
+        steps = [s for s in r.spans if s.name == "exec_step"]
+        executors = [ex for ex in (r.ex, r.draft) if ex is not None]
+        assert len(steps) == sum(ex.steps for ex in executors)
+        assert sum(s.extra["rows"] for s in steps) == \
+            sum(ex.tokens_out for ex in executors)
+        legs = {"exec_upload", "exec_dispatch", "exec_readback"}
+        by_parent = {}
+        for s in r.spans:
+            if s.name in legs:
+                by_parent.setdefault(s.parent, set()).add(s.name)
+        assert all(by_parent[s.span] == legs for s in steps)
+        iterations = [s for s in r.spans if s.name == "sched_iteration"]
+        assert len(iterations) == r.b.iterations
+
+    def test_children_lie_inside_their_parents(self, r):
+        by_id = {s.span: s for s in r.spans}
+        children = [s for s in r.spans if s.parent in by_id]
+        assert len(children) > len(r.spans) // 2
+        for s in children:
+            up = by_id[s.parent]
+            assert up.t0 <= s.t0 <= s.t1 <= up.t1, (s.name, up.name)
+        # the scheduler's phases hang off its iteration, the executor's
+        # step off the phase that launched it
+        for s in r.spans:
+            if s.name in ("sched_retire", "sched_admit", "sched_prefill",
+                          "sched_decode"):
+                assert by_id[s.parent].name == "sched_iteration"
+            if s.name == "exec_step" and s.parent is not None:
+                assert by_id[s.parent].name in ("sched_prefill",
+                                                "sched_decode")
+
+    def test_router_traced_request_ships_what_it_shipped(self, gpt):
+        """`drain` hands the router queue_wait / prefill / decode on
+        the WALL clock, without the per-token stamps and without any
+        scheduler or executor span."""
+        r = _span_run(gpt, "router_traced", n=2)
+        roots = {s.extra["rid"]: s for s in r.spans
+                 if s.name == "request"}
+        for h in r.handles:
+            root = roots[h.rid]     # under the router's ids
+            assert not root.trace.startswith("rid")
+            wire = r.rec.drain(root.trace)
+            assert [s["name"] for s in wire] == ["queue_wait", "prefill",
+                                                 "decode"]
+            assert all(s["parent"] == root.span for s in wire)
+            assert wire[2]["extra"] == {"rid": h.rid,
+                                        "tokens": len(h.tokens)}
+            assert wire[1]["extra"] == {"rid": h.rid}
+            assert wire[0]["t0"] == pytest.approx(
+                to_wall(h.t_submit), abs=0.05)
+            assert abs(wire[2]["t1"] - time.time()) < 60.0
+            # one base for the drain: the spans still tile exactly
+            assert wire[0]["t1"] == wire[1]["t0"]
+            assert wire[1]["t1"] == wire[2]["t0"]
+
+    def test_a_request_that_expires_in_the_queue_is_recorded(self, gpt):
+        rec = get_recorder()
+        t_rec = rec.now()
+        ex, q, b = _stack(gpt, max_batch=2)
+        h = q.submit([1, 2, 3], max_new_tokens=4, deadline_ms=1e-3)
+        b.run()
+        assert h.status == "expired"
+        assert (h.t_admit, h.t_first, h.token_times) == (None, None, [])
+        assert h.t_submit <= h.t_done
+        root = [s for s in rec.between(t_rec, float("inf"))
+                if s.name == "request"]
+        assert len(root) == 1 and root[0].extra["status"] == "expired"

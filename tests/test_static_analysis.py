@@ -96,7 +96,8 @@ class TestFixtures:
 
     def test_trace_bad_flagged(self):
         f = _run_pass(trace_registry)
-        assert _codes(f, "bad_trace") == ["undeclared-span"]
+        # the wire-clock call, the thread span, the local record
+        assert _codes(f, "bad_trace") == ["undeclared-span"] * 3
         reg = _codes(f, "trace/spans")
         # declaration <-> docs drift, both directions, plus the
         # unregistered leg label

@@ -35,6 +35,7 @@ import threading
 import time
 import urllib.request
 from types import SimpleNamespace
+from unittest import mock
 
 import pytest
 
@@ -44,6 +45,7 @@ from horovod_tpu.trace.clock import ClockOffsets
 from horovod_tpu.trace.collect import (TraceAssembler, assembler_from_env,
                                        clock_key, leg_decompose)
 from horovod_tpu.trace.context import TraceContext
+from horovod_tpu.trace import spans as spans_mod
 from horovod_tpu.trace.spans import (LEGS, SPAN_LEGS, SPAN_NAMES,
                                      SpanRecorder)
 from horovod_tpu.trace.writer import (ROUTER_PID, ChromeTraceWriter,
@@ -107,8 +109,9 @@ class TestSpanRecorder:
     def test_record_and_drain_pops_whole_trace(self):
         rec = SpanRecorder(64, pool="prefill", replica=3, gen=2)
         ctx = TraceContext.mint()
-        rec.record(ctx, "queue_wait", 1.0, 2.0)
-        rec.record(ctx.to_wire(), "prefill", 2.0, 3.0, tokens=8)
+        rec.record_local("queue_wait", 1.0, 2.0, ship=ctx)
+        rec.record_local("prefill", 2.0, 3.0, ship=ctx.to_wire(),
+                         tokens=8)
         assert rec.pending() == 2
         spans = rec.drain(ctx.trace_id)
         assert [s["name"] for s in spans] == ["queue_wait", "prefill"]
@@ -121,17 +124,19 @@ class TestSpanRecorder:
 
     def test_untraced_and_garbage_are_single_branch_noops(self):
         rec = SpanRecorder(8)
-        assert rec.record(None, "prefill", 0.0, 1.0) is None
-        assert rec.record({"bogus": 1}, "prefill", 0.0, 1.0) is None
+        # nothing to ship under: the span stays in the local ring
+        rec.record_local("prefill", 0.0, 1.0, ship=None)
+        rec.record_local("prefill", 0.0, 1.0, ship={"bogus": 1})
         assert rec.pending() == 0
+        assert rec.drain(None) == []
 
     def test_overflow_evicts_oldest_trace_whole(self):
         rec = SpanRecorder(4)
         a, b = TraceContext.mint(), TraceContext.mint()
         for i in range(3):
-            rec.record(a, "decode", i, i + 1)
+            rec.record_local("decode", i, i + 1, ship=a)
         for i in range(3):   # 6 > 4: trace a evicted WHOLE
-            rec.record(b, "decode", i, i + 1)
+            rec.record_local("decode", i, i + 1, ship=b)
         assert rec.dropped == 3
         assert rec.drain(a.trace_id) == []
         assert len(rec.drain(b.trace_id)) == 3
@@ -140,7 +145,7 @@ class TestSpanRecorder:
         rec = SpanRecorder(16)
         rec.record_process("weight_fence", 5.0, 6.0, gen=2)
         ctx = TraceContext.mint()
-        rec.record(ctx, "decode", 0.0, 1.0)
+        rec.record_local("decode", 0.0, 1.0, ship=ctx)
         names = [s["name"] for s in rec.drain(ctx.trace_id)]
         assert names == ["decode", "weight_fence"]
         # drained exactly once
@@ -151,7 +156,7 @@ class TestSpanRecorder:
         rec = SpanRecorder(8)
         rec.configure(pool="decode", replica=1, gen=4)
         ctx = TraceContext.mint()
-        rec.record(ctx, "decode", 0.0, 1.0)
+        rec.record_local("decode", 0.0, 1.0, ship=ctx)
         sp = rec.drain(ctx.trace_id)[0]
         assert (sp["pool"], sp["replica"], sp["gen"]) == \
             ("decode", 1, 4)
@@ -253,16 +258,20 @@ def _mk_asm(**kw):
 
 def _worker_spans(ctx, base, *, skew=0.0, replica=0, migrate=False):
     """A plausible worker-side span set, stamped ``skew`` seconds off
-    the router clock."""
+    the router clock. The recorder's stamps are monotonic and `drain`
+    adds the wall base: this worker's is 0, so the stamps below are
+    its wall clock too."""
     rec = SpanRecorder(64, pool="prefill", replica=replica)
     b = base + skew
-    rec.record(ctx, "queue_wait", b + 0.01, b + 0.10)
-    rec.record(ctx, "prefill", b + 0.10, b + 0.30)
+    rec.record_local("queue_wait", b + 0.01, b + 0.10, ship=ctx)
+    rec.record_local("prefill", b + 0.10, b + 0.30, ship=ctx)
     if migrate:
-        rec.record(ctx, "park", b + 0.30, b + 0.35)
-        rec.record(ctx, "migrate_push", b + 0.35, b + 0.45)
-    rec.record(ctx, "decode", b + (0.45 if migrate else 0.30), b + 0.9)
-    return rec.drain(ctx.trace_id)
+        rec.record_local("park", b + 0.30, b + 0.35, ship=ctx)
+        rec.record_local("migrate_push", b + 0.35, b + 0.45, ship=ctx)
+    rec.record_local("decode", b + (0.45 if migrate else 0.30), b + 0.9,
+                     ship=ctx)
+    with mock.patch.object(spans_mod, "wall_base", return_value=0.0):
+        return rec.drain(ctx.trace_id)
 
 
 class TestTraceAssembler:
