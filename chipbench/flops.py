@@ -5,6 +5,13 @@ these by a measured time. They count the work of the algorithm, not of
 an implementation: causal attention is the lower triangle, nothing is
 counted twice for recomputation, and a cache read is the logical bytes
 of K and V, not the tile-padded ones. A multiply-add is two operations.
+
+The functions in the first half take sizes and are checked against
+hand-worked values; the second half is what the per-layer readers ask
+for through ``chipbench/families/gpt2.py``: the same counts summed over
+the model's layers and over the steps a runner recorded. Every GPT-2
+layer does the same work, so the sum is a product here; a family whose
+layers differ (a window on some) sums what each of them reads.
 """
 from __future__ import annotations
 
@@ -71,13 +78,6 @@ def paged_decode_work(shape: Shape, context_tokens: int,
             "bytes": kv_bytes}
 
 
-def roofline_seconds(work: dict, peak: dict) -> float:
-    """The least time the chip could take for `work`: the larger of
-    operations over peak FLOP/s and bytes over peak bytes/s."""
-    return max(work["flops"] / peak["bf16_flops_per_s"],
-               work["bytes"] / peak["hbm_bytes_per_s"])
-
-
 def param_count(shape: Shape) -> int:
     """Parameters of the model as the program builds it (untied head,
     padded vocabulary)."""
@@ -86,3 +86,46 @@ def param_count(shape: Shape) -> int:
                  + 4 * d)
     return (2 * shape.padded_vocab * d + shape.positions * d
             + per_layer * shape.layers + 2 * d)
+
+
+# ---------------------------------------------------------------------------
+# what the readers ask a family for: whole model, recorded steps
+# ---------------------------------------------------------------------------
+
+def _all_layers(work: dict, shape: Shape) -> dict:
+    return {k: v * shape.layers for k, v in work.items()}
+
+
+def serve_steps_flops(shape: Shape, steps) -> float:
+    """Required forward operations of the executor steps a serving
+    runner recorded (``prompt_tokens``, ``prompt_context``,
+    ``decode_tokens``, ``decode_context``, ``emitted``, ``kind`` each).
+    A prefill emits one token per row too: its head product."""
+    need = serve_flops(
+        shape,
+        prompt_tokens=sum(x["prompt_tokens"] for x in steps),
+        prompt_context=sum(x["prompt_context"] for x in steps),
+        decode_tokens=sum(x["decode_tokens"] for x in steps),
+        decode_context=sum(x["decode_context"] for x in steps))
+    return need + 2 * shape.d * shape.padded_vocab * sum(
+        x["emitted"] for x in steps if x["kind"] == "prefill")
+
+
+def attention_work(shape: Shape, batch: int, seq: int) -> dict:
+    """`flash_attention_work` of every layer of one train step."""
+    return _all_layers(flash_attention_work(shape, batch, seq), shape)
+
+
+def decode_attention_work(shape: Shape, steps) -> dict:
+    """`paged_decode_work` of every layer over the recorded decode
+    steps: each layer reads the K and V of every cached token."""
+    context = sum(x["decode_context"] for x in steps)
+    return _all_layers(paged_decode_work(shape, context), shape)
+
+
+def decode_query_pattern(shape: Shape, rows: int) -> str:
+    """A regular expression for the decode kernel's query operand in the
+    trace event's text, one query per row, ``[rows, heads, 1,
+    head_dim]``: what tells a decode call of the kernel from a prefill
+    call of it."""
+    return rf"\[{rows},{shape.heads},1,{shape.head_dim}\]"

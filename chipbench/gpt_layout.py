@@ -2,8 +2,9 @@
 
 The plain reference holds its weights stacked over layers under its own
 names; `models/gpt.py` holds a flax tree. These functions carry the same
-values across, and read per-leaf norms back in the reference's names.
-The reference itself knows none of this.
+values across, read per-leaf norms back in the reference's names, and
+build the model object a serving executor or a train step gets from a
+configuration. The reference itself knows none of this.
 """
 from __future__ import annotations
 
@@ -28,6 +29,40 @@ def flax_tree(stacked: dict, shape: ref.Shape) -> dict:
             "mlp": {"up": {"kernel": w["fc_w"], "bias": w["fc_b"]},
                     "down": {"kernel": w["out_w"], "bias": w["out_b"]}}}
     return tree
+
+
+def program_params(shape: ref.Shape, key) -> dict:
+    """The seed's weights as the program's parameter tree. Traceable:
+    call under `jit`, so they are made on the device in one call."""
+    return flax_tree(ref.make_weights(shape, key), shape)
+
+
+def _model(shape: ref.Shape, config: dict, **kw):
+    import jax.numpy as jnp
+    from horovod_tpu.models.gpt import GPT, GPTConfig
+    assumed = config.get("assumed", {})
+    return GPT(GPTConfig(
+        vocab_size=shape.padded_vocab, num_layers=shape.layers,
+        num_heads=shape.heads, head_dim=shape.head_dim,
+        max_seq_len=shape.positions,
+        dtype=jnp.dtype(assumed.get("compute_dtype", "bfloat16")),
+        logits_dtype=jnp.dtype(assumed.get("logits_dtype", "float32")),
+        **kw))
+
+
+def serve_model(shape: ref.Shape, config: dict, *, kv_block: int,
+                kv_pool_blocks: int, decode_kernel):
+    """The model object a `ShardedExecutor` gets: decode mode over a
+    paged KV pool of `kv_pool_blocks` blocks of `kv_block` tokens."""
+    return _model(shape, config, decode=True, kv_block_size=kv_block,
+                  kv_pool_blocks=kv_pool_blocks, decode_kernel=decode_kernel)
+
+
+def train_model(shape: ref.Shape, config: dict, *, kernels):
+    """The model whose `apply` a train step gets; `kernels` is the
+    attention implementation (None: the platform's; ``"interpret"``
+    off the TPU)."""
+    return _model(shape, config, attention_impl=kernels)
 
 
 def _qkv_parts(name: str, x):

@@ -3,9 +3,11 @@
     python -m chipbench --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The harness knows the order of a run and the shape of its last line; it
-knows no cell, configuration or metric by name. The cell's traffic file
-names a runner (``chipbench/runners/<name>.py``) which drives the system
-under test; with ``--trace 1`` each per-layer metric of the cell is read
+knows no cell, configuration, architecture or metric by name. The
+configuration's file names its family (``chipbench/families/<name>.py``:
+shape, reference, work counts, model objects, rehearsal widths); the
+cell's traffic file names a runner (``chipbench/runners/<name>.py``)
+which drives the system under test; with ``--trace 1`` each per-layer metric of the cell is read
 by its own file (``chipbench/layer_metrics/<metric>.py``).
 
 Order of a run: set-up (import, `hvd.init`, weights from the seed on the
@@ -29,13 +31,6 @@ from typing import Any, Dict, List, Optional
 
 from . import manifest as mf
 from . import xplane
-
-#: `--rehearse` widths: every configuration shrinks to these (CPU,
-#: interpret-mode kernels); nothing a rehearsal prints is a measurement
-REHEARSE_CONFIG = {"n_embd": 64, "n_layer": 2, "n_head": 4,
-                   "n_positions": 64, "n_ctx": 64, "vocab_size": 500,
-                   "assumed": {"padded_vocab_size": 512}}
-
 
 class Refused(Exception):
     """The run cannot be a measurement (no TPU, too few chips, unknown
@@ -111,16 +106,23 @@ class Run:
         self.rehearse = rehearse
         self.config = cell.config
         self.traffic = cell.traffic
+        #: whatever depends on the architecture is asked of this module
+        self.family = cell.family()
         if rehearse:
-            self.config = _merge(cell.config, REHEARSE_CONFIG)
+            self.config = _merge(cell.config, self.family.REHEARSE_CONFIG)
             self.traffic = _merge(cell.traffic,
                                   cell.traffic.get("rehearse", {}))
+        #: the family's reading of the configuration's sizes
+        self.shape = self.family.Shape(self.config)
         self.chips = cell.chips
         self.tracer = Tracer(trace)
         self.devices: list = []
         self.peak: Dict[str, float] = {}
-        #: filled by the runner
+        #: filled by the runner: the window's length, and where a reader
+        #: lays the program's own spans against it, its opening on
+        #: `time.perf_counter()`
         self.window_s: float = 0.0
+        self.window_open: Optional[float] = None
         self.attempted = 0
         self.failed = 0
         self.end_to_end: Dict[str, float] = {}
@@ -240,8 +242,8 @@ def compare(compared: List[dict]) -> bool:
     return ok
 
 
-def main(argv: Optional[List[str]] = None,
-         t0: Optional[float] = None) -> int:
+def main(argv: Optional[List[str]] = None, t0: Optional[float] = None,
+         root: str = mf.ROOT) -> int:
     t0 = time.perf_counter() if t0 is None else t0
     ap = argparse.ArgumentParser(prog="python -m chipbench")
     ap.add_argument("--workload", required=True)
@@ -257,8 +259,8 @@ def main(argv: Optional[List[str]] = None,
         ap.error("--seed is a non-negative whole number")
 
     try:
-        manifest = mf.load()
-        cell = mf.Cell(manifest, args.workload)
+        manifest = mf.load(root)
+        cell = mf.Cell(manifest, args.workload, root)
         run = Run(cell, args.seed, args.seconds, bool(args.trace),
                   args.rehearse)
         device = device_gate(run)

@@ -5,12 +5,14 @@ products, over the device time of the decode-attention kernel events.
 
 The kernel is found by NAME (Mosaic custom calls named after
 `paged_attention`; the trace carries no flax module path) and told from
-the prefill calls of the same kernel by its query operand: one query per
-row, ``[rows, heads, 1, head_dim]``. Nothing found, nothing printed."""
+the prefill calls of the same kernel by its query operand, which the
+run's family gives as a pattern (one query per row). The family also
+says what all its layers together had to read and do over the traced
+steps, so one whose layers differ (a window on some) can say so.
+Nothing found, nothing printed."""
 import re
 
-from chipbench import flops, xplane
-from chipbench.reference import Shape
+from chipbench import roofline, xplane
 
 PATTERN = r"paged_attention"
 
@@ -19,17 +21,14 @@ def read(run):
     steps = (run.traced or {}).get("steps")
     if run.trace is None or not steps:
         return None
-    shape = Shape(run.config)
     rows = int(run.traffic["server"]["max_batch"])
-    one_query = re.compile(
-        rf"\[{rows},{shape.heads},1,{shape.head_dim}\]")
+    one_query = re.compile(run.family.decode_query_pattern(run.shape, rows))
     events = xplane.kernel_events(run.trace, PATTERN,
                                   keep=lambda text: bool(
                                       one_query.search(text)))
     seconds = [sum(b - a for _, a, b in ev) for ev in events.values() if ev]
     if not seconds:
         return None
-    context = sum(s["decode_context"] for s in steps)
-    work = flops.paged_decode_work(shape, context)
-    least = flops.roofline_seconds(work, run.peak) * shape.layers
+    work = run.family.decode_attention_work(run.shape, steps)
+    least = roofline.roofline_seconds(work, run.peak)
     return 100.0 * least / (sum(seconds) / len(seconds))

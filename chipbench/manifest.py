@@ -4,7 +4,11 @@ The harness holds no table of cells, configurations or metrics: a name
 in the manifest leads to a file by the rules below, so a later PR adds a
 cell by adding files and manifest entries and edits nothing here.
 
-    configs[].file                      the configuration, as it is run
+    configs[].file                      the configuration, as it is run;
+                                        its ``family`` names the
+                                        architecture
+    chipbench/families/<family>.py      shape, reference, work counts,
+                                        model objects, rehearsal widths
     chipbench/traffic/<traffic>.json    the traffic mix and the deployment
                                         it is offered to (`runner`,
                                         `generator`, `chips`, sizes)
@@ -135,6 +139,14 @@ def validate(manifest: dict, root: str = ROOT) -> List[str]:
         files.add(f)
         if not os.path.isfile(os.path.join(root, f)):
             errors.append(f"config {c['name']}: file {f!r} missing")
+        else:
+            try:
+                family = family_file(load_json(f, root), c["name"])
+                if not os.path.isfile(os.path.join(root, family)):
+                    errors.append(f"config {c['name']}: no family module "
+                                  f"{family}")
+            except ManifestError as e:
+                errors.append(str(e))
         red = c.get("reduced", [])
         if not (isinstance(red, list) and len(red) <= 16):
             errors.append(f"config {c['name']}: reduced has at most 16 keys")
@@ -243,6 +255,16 @@ def traffic_file(traffic: str) -> str:
     return f"chipbench/traffic/{traffic}.json"
 
 
+def family_file(config: dict, config_name: str) -> str:
+    """The family module a configuration file's ``family`` names."""
+    name = config.get("family")
+    if not (isinstance(name, str) and NAME.match(name)):
+        raise ManifestError(
+            f"configuration {config_name}: its file names no family "
+            f"(\"family\": \"<name>\" -> chipbench/families/<name>.py)")
+    return f"chipbench/families/{name}.py"
+
+
 def layer_metric_file(metric: str) -> str:
     return f"chipbench/layer_metrics/{metric}.py"
 
@@ -309,6 +331,12 @@ class Cell:
         if rehearse:
             out.update(data.get("rehearse", {}))
         return out
+
+    def family(self):
+        """The module that holds whatever depends on the configuration's
+        architecture (``"family"`` in the configuration file)."""
+        return load_module(family_file(self.config, self.config_name),
+                           self.root)
 
     def runner(self):
         return load_module(f"chipbench/runners/{self.traffic['runner']}.py",

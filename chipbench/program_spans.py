@@ -31,10 +31,8 @@ one ``serve.request_p90_ms`` takes, as nearly as the program's spans
 tell it: requests that resolved ``ok``, were submitted after the first
 ``sched_iteration`` that began after the profiler stopped (its stop
 stalls the loop for seconds), and resolved before the window closed
-(`window_s` after it opened; it opened `min(2, seconds/4)` before the
-runner went to start the profiler, which is the end of the last
-``sched_iteration`` before `t_start`, so the close is placed late by
-less than one loop pass).
+(`run.window_s` after `run.window_open`, which the runner stamps on the
+harness's clock).
 
 **Idle side** (`a.idle`), from the device trace and the spans: the time
 inside the traced window in which no operation ran on the device, split
@@ -123,17 +121,12 @@ def _lost(rec, since: float) -> bool:
 # ---------------------------------------------------------------------------
 
 def _host_side(run, rec, delta: float) -> Optional[dict]:
-    tr = run.tracer
-    lead = min(2.0, run.seconds / 4)    # runners/serve.py::window's own
-    t_start, t_stop = tr.t_start + delta, tr.t_stop + delta
-    if _lost(rec, t_start - lead):
+    if run.window_open is None:
         return None
-    # the runner starts the profiler between two iterations, `lead`
-    # seconds into the window, and stamps `t_start` once it is up: the
-    # end of the last iteration before it is nearer to that moment
-    before = [s.t1 for s in rec.between(t_start - 10.0, t_start)
-              if s.name == "sched_iteration" and s.t1 <= t_start]
-    t_open = max(before, default=t_start) - lead
+    t_stop = run.tracer.t_stop + delta
+    t_open = run.window_open + delta
+    if _lost(rec, t_open):
+        return None
     t_close = t_open + run.window_s
     spans = rec.between(t_open, t_close)
     after = [s.t0 for s in spans

@@ -171,6 +171,14 @@ def logits(w, shape: Shape, tokens, precision: str = "float32",
                precision)
 
 
+def logits_at(w, shape: Shape, tokens, where, precision: str = "float32"):
+    """[n, padded_vocab] logits of ONE sequence (tokens [1, S]) at the
+    positions `where` [n]: what a serving check reads, without the head
+    product at the positions nobody asked for."""
+    return _mm(hidden(w, shape, tokens, precision)[0][where], w["head_w"],
+               precision)
+
+
 def loss(w, shape: Shape, tokens, labels, precision: str = "float32"):
     """Mean token cross entropy over the padded vocabulary (the padded
     rows are real rows of the model that runs)."""

@@ -24,6 +24,10 @@ pass over each prompt with its served tokens, and at every served
 position the gap by which the served token's reference logit lies below
 the reference's best. Greedy decoding in exact arithmetic gives 0; the
 widest gap is what `check` compares.
+
+No model is named here. The configuration's family (`run.family`,
+``chipbench/families/<family>.py``) gives the model object and the
+parameters the executor gets, and the reference's weights and logits.
 """
 from __future__ import annotations
 
@@ -34,8 +38,10 @@ from typing import List
 
 import numpy as np
 
-from chipbench import reference as ref
-from chipbench.gpt_layout import flax_tree
+
+#: what this runner asks of the configuration's family
+FAMILY_NEEDS = ("seed_key", "program_params", "serve_model",
+                "reference_weights", "logits_at", "CONTROL")
 
 
 class _Caller:
@@ -56,7 +62,7 @@ def percentile(values: List[float], q: float) -> float:
 class Runner:
     def __init__(self, run):
         self.run = run
-        self.shape = ref.Shape(run.config)
+        self.shape = run.shape
         self.executor = self.batcher = self.queue = None
         self.finished: List[dict] = []
         self.emitted = (0, 0)
@@ -66,39 +72,32 @@ class Runner:
     # -- set-up -----------------------------------------------------------
     def setup(self) -> None:
         import jax
-        import jax.numpy as jnp
 
         import horovod_tpu as hvd
-        from horovod_tpu.models.gpt import GPT, GPTConfig
         from horovod_tpu.serve import (AdmissionQueue, ContinuousBatcher,
                                        ShardedExecutor, pool_blocks_for)
 
         run, shape, tr = self.run, self.shape, self.run.traffic
-        sv = tr["server"]
+        family, sv = run.family, tr["server"]
         with run.phase("init"):
             hvd.init()
         B, max_len, block = sv["max_batch"], sv["max_len"], sv["kv_block"]
         kernel = sv.get("decode_kernel")
         if run.rehearse and kernel is None:
             kernel = "pallas"           # interpret mode off the TPU
-        assumed = run.config.get("assumed", {})
-        cfg = GPTConfig(
-            vocab_size=shape.padded_vocab, num_layers=shape.layers,
-            num_heads=shape.heads, head_dim=shape.head_dim,
-            max_seq_len=shape.positions,
-            dtype=jnp.dtype(assumed.get("compute_dtype", "bfloat16")),
-            logits_dtype=jnp.dtype(assumed.get("logits_dtype", "float32")),
-            decode=True, kv_block_size=block,
+        model = family.serve_model(
+            shape, run.config, kv_block=block,
             kv_pool_blocks=pool_blocks_for(B, max_len, block),
             decode_kernel=kernel)
         # one replica on the default (first) device, as a caller of the
         # serving API gets it; no `default_device` scope here: it is part
         # of jit's cache key, and the window runs outside any
         with run.phase("weights"):
-            params = jax.block_until_ready(jax.jit(lambda k: flax_tree(
-                ref.make_weights(shape, k), shape))(ref.seed_key(run.seed)))
+            params = jax.block_until_ready(jax.jit(
+                lambda k: family.program_params(shape, k))(
+                    family.seed_key(run.seed)))
         with run.phase("executor"):
-            self.executor = ShardedExecutor(GPT(cfg), params, max_batch=B,
+            self.executor = ShardedExecutor(model, params, max_batch=B,
                                             max_len=max_len)
         del params
         self.queue = AdmissionQueue(
@@ -117,7 +116,10 @@ class Runner:
 
     def _wrap_executor(self) -> None:
         """Time every `executor.step` from outside and note the work it
-        was handed: rows, tokens and the keys each token attends to."""
+        was handed: rows, tokens and the keys each token attends to (the
+        sums, and each row's own cached length ``rows_start`` and, in a
+        prefill, ``rows_tokens``; a decode step adds one token a row: for
+        a family whose layers do not all read the whole context)."""
         ex, inner, run = self.executor, self.executor.step, self.run
 
         def step(tokens, positions, mask, last_idx, *, kind="decode", **kw):
@@ -133,11 +135,13 @@ class Runner:
                 rec = {"prompt_tokens": int(n.sum()),
                        "prompt_context": int((n * start
                                               + n * (n + 1) // 2).sum()),
-                       "decode_tokens": 0, "decode_context": 0}
+                       "decode_tokens": 0, "decode_context": 0,
+                       "rows_start": start, "rows_tokens": n}
             else:
                 rec = {"prompt_tokens": 0, "prompt_context": 0,
                        "decode_tokens": int(rows.size),
-                       "decode_context": int((start + 1).sum())}
+                       "decode_context": int((start + 1).sum()),
+                       "rows_start": start}
             rec.update(kind=kind, t0=t0, t1=t1, emitted=int(rows.size),
                        width=int(np.asarray(tokens).shape[1]))
             self.steps.append(rec)
@@ -161,7 +165,7 @@ class Runner:
         run, tr = self.run, self.run.traffic
         trace_seconds = float(tr.get("trace_seconds", 4.0))
         callers = [_Caller() for _ in range(int(tr["callers"]))]
-        t_start = time.perf_counter()
+        t_start = run.window_open = time.perf_counter()
         t_end = t_start + run.seconds
         t_trace = t_start + min(2.0, run.seconds / 4)
         t_undisturbed = t_start    # requests submitted before it are
@@ -296,7 +300,7 @@ class Runner:
                         - sum(len(f["tokens"]) for f in self.finished))
         self._drop_program()
         limits = run.cell.limits(run.rehearse)
-        gaps = served_gaps(shape, run.seed, picked,
+        gaps = served_gaps(run.family, shape, run.seed, picked,
                            pad_to=int(run.traffic["server"]["max_len"]),
                            device=run.devices[0])
         widest = max((g["served"].max() for g in gaps), default=float("nan"))
@@ -315,6 +319,7 @@ class Runner:
         first at each of the same positions (the control)."""
         run = self.run
         rows = []
+        family, control = run.family, run.family.CONTROL
         first = True
         for seed in seeds:
             if not first:
@@ -325,9 +330,9 @@ class Runner:
             self.window()
             picked = self.sample()
             self._drop_program()
-            precisions = ("fp8",) if seed in control_seeds else ()
+            precisions = (control,) if seed in control_seeds else ()
             gaps = served_gaps(
-                self.shape, seed, picked,
+                family, self.shape, seed, picked,
                 pad_to=int(run.traffic["server"]["max_len"]),
                 device=run.devices[0], controls=precisions)
             row = {"seed": seed, "finished": len(self.finished),
@@ -346,15 +351,15 @@ class Runner:
         self._drop_program()
 
 
-def served_gaps(shape: ref.Shape, seed: int, picked: List[dict], *,
+def served_gaps(family, shape, seed: int, picked: List[dict], *,
                 pad_to: int, device=None, controls=()) -> List[dict]:
     """For each picked request: ``served`` [n] the gap, at every served
     position, between the reference's best logit and its logit of the
     served token; ``margin`` [n] the reference's own top-1 to top-2
     margin there; and per control precision the gap of the token that
-    precision puts first. The reference makes its own weights from the
-    seed and reads one request at a time, padded to `pad_to` (causal, so
-    the padding is never seen)."""
+    precision puts first. The reference is the family's: it makes its own
+    weights from the seed and reads one request at a time, padded to
+    `pad_to` (causal, so the padding is never seen)."""
     import jax
     import jax.numpy as jnp
 
@@ -363,14 +368,13 @@ def served_gaps(shape: ref.Shape, seed: int, picked: List[dict], *,
     n_max = max(len(f["tokens"]) for f in picked)
 
     def read(w, tokens, where, served):
-        h = ref.hidden(w, shape, tokens)[0][where]          # [n_max, d]
-        lg = jnp.matmul(h, w["head_w"], precision=ref.HIGHEST)
+        lg = family.logits_at(w, shape, tokens, where)      # [n_max, V]
         top2 = jax.lax.top_k(lg, 2)[0]
         mine = jnp.take_along_axis(lg, served[:, None], axis=1)[:, 0]
         out = {"served": top2[:, 0] - mine, "margin": top2[:, 0] - top2[:, 1]}
         for p in controls:
-            hp = ref.hidden(w, shape, tokens, precision=p)[0][where]
-            first = jnp.argmax(ref._mm(hp, w["head_w"], p), axis=-1)
+            first = jnp.argmax(family.logits_at(w, shape, tokens, where,
+                                                precision=p), axis=-1)
             out[p] = top2[:, 0] - jnp.take_along_axis(
                 lg, first[:, None], axis=1)[:, 0]
         return out
@@ -378,7 +382,8 @@ def served_gaps(shape: ref.Shape, seed: int, picked: List[dict], *,
     ctx = jax.default_device(device) if device is not None \
         else contextlib.nullcontext()
     with ctx:
-        w = jax.jit(lambda k: ref.make_weights(shape, k))(ref.seed_key(seed))
+        w = jax.jit(lambda k: family.reference_weights(shape, k))(
+            family.seed_key(seed))
         read = jax.jit(read)
         out = []
         for f in picked:

@@ -10,22 +10,31 @@ Set-up builds ONE step object with its state, drives it through the
 `checked_steps` first steps on the window's own call and feed (that is
 also the warm-up: the first call compiles), and hands the same object to
 the window. After the window the state is freed and the plain reference
-(`chipbench/reference.py`) follows the same first steps from the same
-seed; `check` compares each step's loss, the first gradient's norm as
-the optimizer got it (Adam's first moment after one step is
+follows the same first steps from the same seed; `check` compares each
+step's loss, the first gradient's norm as the optimizer got it (Adam's first moment after one step is
 ``(1 - b1) * g``) and the norm of the parameters' change, by the worst
 leaf. See PERF.md section 2 for the readings behind each limit.
+
+No model is named here. The configuration's family (`run.family`,
+``chipbench/families/<family>.py``) gives the model whose `apply` the
+step gets, the parameters from the seed and their per-leaf norms, and the
+reference's trainer and norms.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import sys
 import time
 from typing import Dict, List
 
 import numpy as np
 
-from chipbench import reference as ref
-from chipbench.gpt_layout import flax_tree, leaf_norms
+
+#: what this runner asks of the configuration's family
+FAMILY_NEEDS = ("seed_key", "program_params", "train_model",
+                "program_leaf_norms", "Trainer", "reference_leaf_norms",
+                "CONTROL")
 
 
 def _first_moment(opt_state):
@@ -73,38 +82,34 @@ def compare_steps(prog: dict, want: dict, limits: dict) -> List[dict]:
 
 
 class Runner:
+    #: never more steps in flight than this, whatever the traffic asks:
+    #: the wait at the window's close is that many steps long
+    MAX_AHEAD = 64
+
     def __init__(self, run):
         self.run = run
-        self.shape = ref.Shape(run.config)
+        self.shape = run.shape
         self.state = None
         self.readings: dict = {}
 
     # -- set-up -----------------------------------------------------------
     def setup(self) -> None:
         import jax
-        import jax.numpy as jnp
         import optax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         import horovod_tpu as hvd
-        from horovod_tpu.models.gpt import GPT, GPTConfig
         from horovod_tpu.ops.pallas_ce import fused_cross_entropy
         from horovod_tpu.parallel.mesh_utils import make_mesh
         from horovod_tpu.training import make_train_step
 
         run, shape, tr = self.run, self.shape, self.run.traffic
+        family = run.family
         with run.phase("init"):
             hvd.init()
             self.mesh = make_mesh(dp=run.chips, devices=run.devices)
         kernels = "interpret" if run.rehearse else None
-        assumed = run.config.get("assumed", {})
-        model = GPT(GPTConfig(
-            vocab_size=shape.padded_vocab, num_layers=shape.layers,
-            num_heads=shape.heads, head_dim=shape.head_dim,
-            max_seq_len=shape.positions,
-            dtype=jnp.dtype(assumed.get("compute_dtype", "bfloat16")),
-            logits_dtype=jnp.dtype(assumed.get("logits_dtype", "float32")),
-            attention_impl=kernels))
+        model = family.train_model(shape, run.config, kernels=kernels)
 
         def loss_fn(logits, labels):
             # the fused CE kernel on this chip's flattened logits
@@ -122,16 +127,15 @@ class Runner:
                                     axis_name="dp", loss_fn=loss_fn)
         repl = NamedSharding(self.mesh, P())
         self._batch_sh = NamedSharding(self.mesh, P("dp"))
-        self._init = jax.jit(
-            lambda k: flax_tree(ref.make_weights(shape, k), shape),
-            out_shardings=repl)
+        self._init = jax.jit(lambda k: family.program_params(shape, k),
+                             out_shardings=repl)
         self._init_opt = jax.jit(self.step.init_opt_state,
                                  out_shardings=repl)
 
         self.tokens_per_step = (int(tr["rows_per_chip"]) * run.chips
                                 * int(tr["seq_len"]))
-        self._norms = jax.jit(lambda t: leaf_norms(t, shape))
-        self._change = jax.jit(lambda p, k: leaf_norms(
+        self._norms = jax.jit(lambda t: family.program_leaf_norms(t, shape))
+        self._change = jax.jit(lambda p, k: family.program_leaf_norms(
             jax.tree.map(lambda a, b: a - b, p, self._init(k)), shape))
         self.readings = self.first_steps(run.seed)
 
@@ -141,7 +145,7 @@ class Runner:
         leaves `self.state` and `self._feed` ready for the window."""
         import jax
         tr, shape = self.run.traffic, self.shape
-        key = ref.seed_key(seed)
+        key = self.run.family.seed_key(seed)
         self.state = None
         phase = self.run.phase
         with phase("weights"):
@@ -150,12 +154,14 @@ class Runner:
                 self._init_opt(params)), {})
         self._feed = self.run.cell.generator().batches(tr, shape.vocab, seed)
         self._checked = []
-        losses, grad = [], None
+        losses, grad, took = [], None, []
         for i in range(int(tr["checked_steps"])):
             with phase("first_step" if i == 0 else "next_steps"):
+                t = time.perf_counter()
                 host = next(self._feed)
                 self._checked.append(host)
                 losses.append(float(self._dispatch(self._put(host))))
+                took.append(time.perf_counter() - t)
             if i == 0:
                 with phase("norms"):
                     b1 = tr["optimizer"]["b1"]
@@ -165,6 +171,9 @@ class Runner:
         with phase("norms"):
             moved = {k: float(v) for k, v in jax.device_get(
                 self._change(self.state[0], key)).items()}
+        # a fenced step after the first, which compiles or fetches: what
+        # the window sizes its dispatch-ahead by
+        self._step_s = min(took[1:] or took)
         return {"loss": losses, "grad": grad, "change": moved}
 
     def _put(self, host):
@@ -182,47 +191,69 @@ class Runner:
 
     # -- the measured window ----------------------------------------------
     def window(self) -> None:
+        """Steps are dispatched `dispatch_ahead_s` seconds ahead of the one
+        the host waits for, so the chip stays fed while the host stands
+        still. When the time is up nothing more is sent, every step that
+        was sent is waited for, and the clock is read after that wait:
+        all of that work counts, over all of that time."""
         import jax
         run, tr = self.run, self.run.traffic
         trace_steps = int(tr.get("trace_steps", 8))
+        ahead = max(1, min(self.MAX_AHEAD, int(np.ceil(
+            float(tr["dispatch_ahead_s"]) / self._step_s))))
+        pending: collections.deque = collections.deque()
+        done_at: List[float] = []
+
+        def wait(n_left: int) -> None:
+            # the fence: a step counts once its loss is ready
+            with run.tracer.span("fence"):
+                while len(pending) > n_left:
+                    jax.block_until_ready(pending.popleft())
+                    done_at.append(time.perf_counter())
+
         nxt = self._put(next(self._feed))
         steps = 0
         traced_from = None
-        step_s: List[float] = []
         t_start = time.perf_counter()
         t_end = t_start + run.seconds
-        t_prev = t_start
-        while True:
-            now = time.perf_counter()
-            if now >= t_end:
-                break
-            if steps == 2 and run.tracer.enabled:
+        while time.perf_counter() < t_end:
+            if steps == 2 and run.tracer.enabled and traced_from is None:
+                wait(0)         # the slice starts and ends on an idle chip
                 run.tracer.start()
                 traced_from = steps
             with run.tracer.span("step"):
                 loss = self._dispatch(nxt)
-                # the next batch is made and uploaded while this step runs
+                pending.append(loss)
+                # the next batch is made and uploaded while the chip works
                 with run.tracer.span("feed"):
                     nxt = self._put(next(self._feed))
-                # the fence: a step counts once its loss is ready
-                with run.tracer.span("fence"):
-                    jax.block_until_ready(loss)
-            t_now = time.perf_counter()
-            step_s.append(t_now - t_prev)
-            t_prev = t_now
             steps += 1
             if run.tracer.active and steps - traced_from >= trace_steps:
+                wait(0)
                 run.tracer.stop()
                 run.traced = {"steps": steps - traced_from,
                               "tokens": (steps - traced_from)
                               * self.tokens_per_step,
                               "seconds": run.tracer.t_stop
                               - run.tracer.t_start}
-        run.tracer.stop()
+            wait(ahead)
+        t_sent = time.perf_counter()
+        wait(0)
         run.window_s = time.perf_counter() - t_start
+        run.tracer.stop()
         run.attempted = steps
         run.failed = 0 if np.isfinite(float(loss)) else steps
-        run.spans["step"] = step_s
+        gaps = np.diff([t_start] + done_at)
+        run.spans["step"] = [float(g) for g in gaps]
+        # a stall shows as one long gap between two completions; the chip
+        # ran dry only if the gap was longer than the steps sent ahead
+        med = float(np.median(gaps))
+        print(f"info window {steps} steps, {ahead} sent ahead: median gap "
+              f"between completions {1e3 * med:.2f} ms, longest "
+              f"{1e3 * float(gaps.max()):.2f} ms, "
+              f"{int((gaps > 1.5 * med).sum())} over 1.5 x the median; "
+              f"{run.window_s - (t_sent - t_start):.2f} s waited after the "
+              f"last was sent", file=sys.stderr)
         run.end_to_end["train_tokens_per_s"] = (
             steps * self.tokens_per_step / run.window_s)
 
@@ -232,7 +263,7 @@ class Runner:
         run, shape, tr = self.run, self.shape, self.run.traffic
         self.state = None            # free the program's state first
         want = reference_steps(
-            shape, tr["optimizer"], run.seed, self._checked,
+            run.family, shape, tr["optimizer"], run.seed, self._checked,
             rows_per_block=int(tr.get("reference_rows_per_block", 1)),
             device=run.devices[0])
         return compare_steps(self.readings, want,
@@ -241,10 +272,12 @@ class Runner:
     def calibrate(self, seeds, control_seeds) -> List[dict]:
         """Readings for setting limits, in one process: the program
         against the reference on every seed; on `control_seeds` also the
-        reference in fp8 put in the program's place (the control) and
+        reference in the family's control precision (fp8 below bfloat16)
+        put in the program's place (the control) and
         the reference with half of the batch, or all but the first
         chip's rows, left out (the faults a training cell can have)."""
         run, shape, tr = self.run, self.shape, self.run.traffic
+        family, control = run.family, run.family.CONTROL
         prog = {}
         for seed in seeds:
             prog[seed] = (self.first_steps(seed), self._checked)
@@ -256,19 +289,19 @@ class Runner:
         rows = []
         for seed in seeds:
             readings, batches = prog[seed]
-            want = reference_steps(shape, tr["optimizer"], seed, batches,
-                                   **kw)
+            want = reference_steps(family, shape, tr["optimizer"], seed,
+                                   batches, **kw)
             sides = {"program": readings}
             if seed in control_seeds:
-                sides["control_fp8"] = reference_steps(
-                    shape, tr["optimizer"], seed, batches,
-                    precision="fp8", **kw)
+                sides[f"control_{control}"] = reference_steps(
+                    family, shape, tr["optimizer"], seed, batches,
+                    precision=control, **kw)
                 sides["fault_half_batch"] = reference_steps(
-                    shape, tr["optimizer"], seed, batches,
+                    family, shape, tr["optimizer"], seed, batches,
                     rows=slice(0, n // 2), **kw)
                 if run.chips > 1:
                     sides["fault_no_exchange"] = reference_steps(
-                        shape, tr["optimizer"], seed, batches,
+                        family, shape, tr["optimizer"], seed, batches,
                         rows=slice(0, int(tr["rows_per_chip"])), **kw)
             for side, got in sides.items():
                 rows.append({"seed": seed, "side": side, **{
@@ -280,22 +313,22 @@ class Runner:
         self.state = None
 
 
-_TRAINERS: Dict[tuple, ref.Trainer] = {}
+_TRAINERS: Dict[tuple, object] = {}
 
 
-def _trainer(shape: ref.Shape, optimizer: dict, precision: str,
-             rows_per_block: int) -> ref.Trainer:
-    """One `Trainer` (and so one compilation) per shape, optimizer and
-    precision, however many seeds follow it."""
-    key = (tuple(sorted(vars(shape).items())),
+def _trainer(family, shape, optimizer: dict, precision: str,
+             rows_per_block: int):
+    """One of the family's `Trainer`s (and so one compilation) per
+    shape, optimizer and precision, however many seeds follow it."""
+    key = (family.__name__, tuple(sorted(vars(shape).items())),
            tuple(sorted(optimizer.items())), precision, rows_per_block)
     if key not in _TRAINERS:
-        _TRAINERS[key] = ref.Trainer(shape, optimizer, precision,
-                                     rows_per_block)
+        _TRAINERS[key] = family.Trainer(shape, optimizer, precision,
+                                        rows_per_block)
     return _TRAINERS[key]
 
 
-def reference_steps(shape: ref.Shape, optimizer: dict, seed: int,
+def reference_steps(family, shape, optimizer: dict, seed: int,
                     batches, *, rows_per_block: int = 1, device=None,
                     precision: str = "float32", rows=None) -> dict:
     """The reference's readings over the checked steps: losses, first
@@ -303,10 +336,10 @@ def reference_steps(shape: ref.Shape, optimizer: dict, seed: int,
     to some of its rows: how the left-out-half and left-out-exchange
     faults are planted in the reference for calibration."""
     import jax
-    trainer = _trainer(shape, optimizer, precision, rows_per_block)
+    trainer = _trainer(family, shape, optimizer, precision, rows_per_block)
     with jax.default_device(device) if device is not None \
             else contextlib.nullcontext():
-        key = ref.seed_key(seed)
+        key = family.seed_key(seed)
         w = trainer.weights(key)
         m, v = trainer.init(w)
         losses, grad = [], None
@@ -316,7 +349,7 @@ def reference_steps(shape: ref.Shape, optimizer: dict, seed: int,
             loss, g = trainer.grads(w, tokens, labels)
             losses.append(float(loss))
             if t == 1:
-                grad = ref.leaf_norms(g)
+                grad = family.reference_leaf_norms(g)
             w, m, v = trainer.step(w, m, v, g, t)
-        change = ref.leaf_norms(trainer.moved(w, key))
+        change = family.reference_leaf_norms(trainer.moved(w, key))
     return {"loss": losses, "grad": grad, "change": change}

@@ -9,7 +9,16 @@ ones, the traffic generators, the plain reference against
 ``models/gpt.py`` at a tiny size, the control (the reference in fp8 put
 in the program's place) failing the comparison, a rehearsal of every
 cell end to end, the faults a cell can have each turning `correct`
-false, refusal off a TPU, and a throw-away cell added from files alone.
+false, refusal off a TPU, and throw-away cells added from files alone:
+one of the family that is here, one of ANOTHER architecture.
+
+Whatever depends on an architecture is found through the configuration's
+family (``chipbench/families/<family>.py``) and the configuration's own
+file; no table here is keyed by a configuration's name. The checks behind
+the tests parametrised over configurations and cells are functions
+(``check_*``), which the files-alone tests call on their throw-away
+entries too. The tests of `chipbench/reference.py`, `flops.py` and
+`gpt_layout.py` further down are the GPT-2 family's own.
 """
 import copy
 import json
@@ -26,7 +35,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from chipbench import flops, harness, xplane  # noqa: E402
+from chipbench import flops, harness, roofline, xplane  # noqa: E402
 from chipbench import manifest as mf  # noqa: E402
 from chipbench import reference as ref  # noqa: E402
 
@@ -42,9 +51,15 @@ def _ids(entries):
     return [e["name"] for e in entries]
 
 
+def _family(config, root=ROOT):
+    """A `configs` entry's family module, by its file's ``family``."""
+    return mf.load_module(mf.family_file(
+        mf.load_json(config["file"], root), config["name"]), root)
+
+
 def _shape(config_name):
     entry = next(c for c in CONFIGS if c["name"] == config_name)
-    return ref.Shape(mf.load_json(entry["file"]))
+    return _family(entry).Shape(mf.load_json(entry["file"]))
 
 
 # ---------------------------------------------------------------------------
@@ -55,11 +70,15 @@ def test_manifest_passes_the_drivers_rules():
     assert mf.validate(MANIFEST) == []
 
 
+def check_name_is_one_token(entry):
+    assert mf.NAME.match(entry["name"]), entry["name"]
+    assert not set(entry["name"]) & set(" ,/")
+
+
 @pytest.mark.parametrize("entry", CONFIGS + CELLS + METRICS,
                          ids=_ids(CONFIGS + CELLS + METRICS))
 def test_name_is_one_token(entry):
-    assert mf.NAME.match(entry["name"]), entry["name"]
-    assert not set(entry["name"]) & set(" ,/")
+    check_name_is_one_token(entry)
 
 
 @pytest.mark.parametrize("metric", METRICS, ids=_ids(METRICS))
@@ -91,10 +110,14 @@ def test_bound_is_within_the_contract(metric):
     assert metric["source"] in ("host_clock", "device_trace")
 
 
-@pytest.mark.parametrize("cell", CELLS, ids=_ids(CELLS))
-def test_cell_files_are_found_by_name(cell):
-    c = mf.Cell(MANIFEST, cell["name"])
+def check_cell_files_are_found_by_name(manifest, cell, root=ROOT):
+    c = mf.Cell(manifest, cell["name"], root)
     assert hasattr(c.runner(), "Runner")
+    # the family brings what the harness and this cell's runner ask of it
+    missing = [n for n in ("Shape", "REHEARSE_CONFIG", "PUBLISHED_WIDTHS",
+                           "WORK_COUNTS") + tuple(c.runner().FAMILY_NEEDS)
+               if not hasattr(c.family(), n)]
+    assert not missing, (c.config["family"], missing)
     assert c.generator() is not None
     assert all(v > 0 for v in c.limits().values())
     assert {m["name"] for m in c.end_to_end} >= {"setup_s"}
@@ -105,24 +128,31 @@ def test_cell_files_are_found_by_name(cell):
     assert len(cell["why"]) <= 200 and "\n" not in cell["why"]
 
 
-PUBLISHED = {   # config.json of the two checkpoints, by hand
-    "gpt2-medium": dict(d=1024, layers=24, heads=16, positions=1024),
-    "gpt2-xl": dict(d=1600, layers=48, heads=25, positions=1024),
-}
+@pytest.mark.parametrize("cell", CELLS, ids=_ids(CELLS))
+def test_cell_files_are_found_by_name(cell):
+    check_cell_files_are_found_by_name(MANIFEST, cell)
+
+
+def check_config_keeps_the_published_widths(config, root=ROOT):
+    """The sizes the family's `Shape` reads from the file's own keys are
+    the source's, which the file states a second time, by hand, under
+    ``published`` (every width the family names)."""
+    data = mf.load_json(config["file"], root)
+    family = _family(config, root)
+    shape = family.Shape(data)
+    published = data.get("published") or {}
+    assert set(published) == set(family.PUBLISHED_WIDTHS), config["name"]
+    assert {k: getattr(shape, k) for k in published} == published
+    assert shape.padded_vocab >= shape.vocab
+    assert shape.padded_vocab % 128 == 0
+    assert sorted(data["reduced"]) == sorted(config["reduced"])
+    assert not any(mf._WIDTH.search(k) for k in config["reduced"])
+    assert config["source"] == data["source"]
 
 
 @pytest.mark.parametrize("config", CONFIGS, ids=_ids(CONFIGS))
 def test_config_keeps_the_published_widths(config):
-    data = mf.load_json(config["file"])
-    shape = ref.Shape(data)
-    want = PUBLISHED[config["name"]]
-    assert (shape.d, shape.layers, shape.heads, shape.positions) == (
-        want["d"], want["layers"], want["heads"], want["positions"])
-    assert shape.head_dim == 64 and shape.ffn == 4 * shape.d
-    assert shape.vocab == 50257 and shape.padded_vocab % 128 == 0
-    assert sorted(data["reduced"]) == sorted(config["reduced"])
-    assert not any(mf._WIDTH.search(k) for k in config["reduced"])
-    assert config["source"] == data["source"]
+    check_config_keeps_the_published_widths(config)
 
 
 def _breach(name):
@@ -177,45 +207,37 @@ def test_validate_refuses(breach):
 # operations and bytes, against values worked by hand
 # ---------------------------------------------------------------------------
 
-HAND = {
-    "gpt2-medium": {
-        "layer_matmul_params": 12_582_912,      # 4*1024^2 + 2*1024*4096
-        "param_count": 406_382_592,
-        # 2*12,582,912*24 + 4*512.5*1024*24 + 2*1024*50304, times 3
-        "train_flops_per_token_1024": 2_272_149_504,
-        # 7 * (2*1024^2*64*16*8/2); 12 * (8*16*1024*64*2 B)
-        "flash_8x1024": {"flops": 60_129_542_144, "bytes": 201_326_592},
-        # 2000 cached tokens: K and V, 16 heads * 64 * 2 B each
-        "paged_2000": {"flops": 8_192_000, "bytes": 8_192_000},
-    },
-    "gpt2-xl": {
-        "layer_matmul_params": 30_720_000,      # 4*1600^2 + 2*1600*6400
-        "param_count": 1_638_172_800,
-        "train_flops_per_token_1024": 3 * (
-            2 * 30_720_000 * 48 + 4 * 512.5 * 1600 * 48
-            + 2 * 1600 * 50304),
-        "flash_8x1024": {"flops": 7 * 2 * 1024 * 1024 * 64 * 25 * 8 // 2,
-                         "bytes": 12 * 8 * 25 * 1024 * 64 * 2},
-        "paged_2000": {"flops": 12_800_000, "bytes": 12_800_000},
-    },
-}
+def hand_worked_cases(configs, root=ROOT):
+    """(configs entry, work count) for every hand-worked value the
+    configurations' files hold. One that brings none still gets a case,
+    which fails: the next configuration must bring its own."""
+    cases = []
+    for c in configs:
+        names = list(mf.load_json(c["file"], root).get("hand_worked") or {})
+        cases += [(c, n) for n in names or ["none_brought"]]
+    return cases
 
 
-@pytest.mark.parametrize("what", ["layer_matmul_params", "param_count",
-                                  "train_flops_per_token_1024",
-                                  "flash_8x1024", "paged_2000"])
-@pytest.mark.parametrize("config", _ids(CONFIGS))
+def check_flop_and_byte_functions(config, what, root=ROOT):
+    """A work count of the configuration's family, at the configuration's
+    sizes, against the value its file gives, worked by hand (``how``)."""
+    data = mf.load_json(config["file"], root)
+    family = _family(config, root)
+    hand = data.get("hand_worked") or {}
+    assert hand, f"{config['name']} brings no hand-worked values"
+    # every work count the family checks this way has a value here
+    assert set(hand) == set(family.WORK_COUNTS), config["name"]
+    got = family.WORK_COUNTS[what](family.Shape(data))
+    assert got == hand[what]["value"], hand[what]["how"]
+
+
+_HAND = hand_worked_cases(CONFIGS)
+
+
+@pytest.mark.parametrize(
+    "config, what", _HAND, ids=[f"{c['name']}-{w}" for c, w in _HAND])
 def test_flop_and_byte_functions(config, what):
-    shape = _shape(config)
-    got = {
-        "layer_matmul_params": lambda: flops.layer_matmul_params(shape),
-        "param_count": lambda: flops.param_count(shape),
-        "train_flops_per_token_1024":
-            lambda: flops.train_flops_per_token(shape, 1024),
-        "flash_8x1024": lambda: flops.flash_attention_work(shape, 8, 1024),
-        "paged_2000": lambda: flops.paged_decode_work(shape, 2000),
-    }[what]()
-    assert got == HAND[config][what]
+    check_flop_and_byte_functions(config, what)
 
 
 def test_serve_flops_by_hand():
@@ -231,11 +253,33 @@ def test_roofline_takes_the_larger_bound():
     assert (peak["bf16_flops_per_s"], peak["hbm_bytes_per_s"]) == (
         197e12, 819e9)
     work = flops.flash_attention_work(_shape("gpt2-medium"), 8, 1024)
-    assert flops.roofline_seconds(work, peak) == pytest.approx(
+    assert roofline.roofline_seconds(work, peak) == pytest.approx(
         60_129_542_144 / 197e12)              # compute-bound
     work = flops.paged_decode_work(_shape("gpt2-xl"), 2000)
-    assert flops.roofline_seconds(work, peak) == pytest.approx(
+    assert roofline.roofline_seconds(work, peak) == pytest.approx(
         12_800_000 / 819e9)                   # bandwidth-bound
+
+
+def test_what_the_readers_ask_the_gpt2_family_for():
+    """The whole model's work over recorded steps is the per-layer,
+    per-call counts above, summed: same arithmetic as before the seam."""
+    shape = _shape("gpt2-xl")
+    family = mf.load_module("chipbench/families/gpt2.py")
+    steps = [{"kind": "prefill", "prompt_tokens": 100,
+              "prompt_context": 5050, "decode_tokens": 0,
+              "decode_context": 0, "emitted": 1},
+             {"kind": "decode", "prompt_tokens": 0, "prompt_context": 0,
+              "decode_tokens": 8, "decode_context": 800, "emitted": 8}]
+    # the prefill's one emitted token costs a head product too
+    assert family.serve_flops(shape, steps) == (
+        321_589_862_400 + 160_972_800)
+    assert family.decode_attention_work(shape, steps) == {
+        "flops": 48 * 4 * 800 * 1600, "bytes": 48 * 2 * 800 * 1600 * 2}
+    assert family.attention_work(shape, 8, 1024) == {
+        "flops": 48 * 93_952_409_600, "bytes": 48 * 314_572_800}
+    assert family.decode_query_pattern(shape, 8) == r"\[8,25,1,64\]"
+    assert family.train_flops_per_token(shape, 1024) == 9_802_598_400
+    assert family.param_count(shape) == 1_638_172_800
 
 
 # ---------------------------------------------------------------------------
@@ -367,12 +411,17 @@ def test_flash_roofline_reader_on_the_recorded_trace(recorded):
     assert cell.reader("train.flash_attention_roofline").read(run) is None
 
 
-@pytest.mark.parametrize("metric", PER_LAYER, ids=_ids(PER_LAYER))
-def test_reader_with_nothing_to_read_returns_nothing(metric):
-    cell_name = metric.get("workloads", _ids(CELLS))[0]
-    cell = mf.Cell(MANIFEST, cell_name)
+def check_reader_with_nothing_to_read(manifest, metric, cell_name,
+                                      root=ROOT):
+    cell = mf.Cell(manifest, cell_name, root)
     run = harness.Run(cell, 0, 1.0, True, False)
     assert cell.reader(metric["name"]).read(run) is None
+
+
+@pytest.mark.parametrize("metric", PER_LAYER, ids=_ids(PER_LAYER))
+def test_reader_with_nothing_to_read_returns_nothing(metric):
+    check_reader_with_nothing_to_read(
+        MANIFEST, metric, metric.get("workloads", _ids(CELLS))[0])
 
 
 # ---------------------------------------------------------------------------
@@ -438,6 +487,7 @@ def test_shared_prefix_is_shared():
 # the plain reference, the control, the comparison
 # ---------------------------------------------------------------------------
 
+# the GPT-2 family's own parts: its reference, its control, its layout
 TINY = ref.Shape({"n_embd": 64, "n_layer": 2, "n_head": 4,
                   "n_positions": 32, "vocab_size": 500,
                   "layer_norm_epsilon": 1e-6,
@@ -508,9 +558,11 @@ def test_the_control_fails_the_comparison(seed, tiny_batches):
     train = _train_module()
     limits = mf.Cell(MANIFEST, "gpt2-medium.train-dp1").limits(True)
     batches = tiny_batches(seed)
-    want = train.reference_steps(TINY, ADAMW, seed, batches)
+    family = mf.load_module("chipbench/families/gpt2.py")
+    assert family.CONTROL == "fp8"
+    want = train.reference_steps(family, TINY, ADAMW, seed, batches)
     for precision, correct in (("bfloat16", True), ("fp8", False)):
-        got = train.reference_steps(TINY, ADAMW, seed, batches,
+        got = train.reference_steps(family, TINY, ADAMW, seed, batches,
                                     precision=precision)
         compared = train.compare_steps(got, want, limits)
         assert harness.compare(compared) is correct, (precision, compared)
@@ -571,19 +623,19 @@ def hvd_off():
     hvd.shutdown()
 
 
-def _rehearse(cell, capsys, seed=3, trace=0, seconds=1.0):
+def _rehearse(cell, capsys, seed=3, trace=0, seconds=1.0, root=ROOT):
     rc = harness.main(["--workload", cell, "--seed", str(seed),
                        "--seconds", str(seconds), "--trace", str(trace),
-                       "--rehearse"])
+                       "--rehearse"], root=root)
     out, err = capsys.readouterr()
     assert rc == 0, err[-2000:]
     return json.loads(out.strip().splitlines()[-1]), err
 
 
-@pytest.mark.parametrize("cell", CELLS, ids=_ids(CELLS))
-def test_rehearsal_ends_in_one_well_formed_line(cell, capsys, hvd_off):
+def check_rehearsal_ends_in_one_well_formed_line(manifest, cell, capsys,
+                                                 root=ROOT):
     result, err = _rehearse(cell["name"], capsys, seed=2 ** 32 + 21,
-                            trace=1)
+                            trace=1, root=root)
     assert list(result)[:5] == ["correct", "attempted", "failed",
                                 "metrics", "device"]
     assert list(result)[-1] == "compared"
@@ -599,13 +651,19 @@ def test_rehearsal_ends_in_one_well_formed_line(cell, capsys, hvd_off):
     assert result["compared"]["compiles_in_window"] == {"value": 0.0,
                                                         "limit": 0.0}
     if "serve_tokens_per_s" in [m["name"] for m in mf.Cell(
-            MANIFEST, cell["name"]).end_to_end]:
+            manifest, cell["name"], root).end_to_end]:
         # every token the rate counts was delivered, and no other
         assert result["compared"]["uncounted_tokens"] == {"value": 0.0,
                                                           "limit": 0.0}
     for name, c in result["compared"].items():
         assert f"compared {name} " in err
     assert err.strip().splitlines()[-1].startswith("compared ")
+    return result
+
+
+@pytest.mark.parametrize("cell", CELLS, ids=_ids(CELLS))
+def test_rehearsal_ends_in_one_well_formed_line(cell, capsys, hvd_off):
+    check_rehearsal_ends_in_one_well_formed_line(MANIFEST, cell, capsys)
 
 
 def _train_fault(monkeypatch, fault):
@@ -666,13 +724,17 @@ def test_an_altered_token_is_not_correct(monkeypatch, capsys, hvd_off):
     assert result["correct"] is False and c["value"] > c["limit"]
 
 
-@pytest.mark.parametrize("cell", CELLS, ids=_ids(CELLS))
-def test_no_measurement_off_a_tpu(cell, capsys):
+def check_no_measurement_off_a_tpu(cell, capsys, root=ROOT):
     rc = harness.main(["--workload", cell["name"], "--seed", "1",
-                       "--seconds", "1", "--trace", "0"])
+                       "--seconds", "1", "--trace", "0"], root=root)
     out, err = capsys.readouterr()
     assert rc != 0 and out == ""
     assert "measures a TPU" in err
+
+
+@pytest.mark.parametrize("cell", CELLS, ids=_ids(CELLS))
+def test_no_measurement_off_a_tpu(cell, capsys):
+    check_no_measurement_off_a_tpu(cell, capsys)
 
 
 def test_the_command_refuses_off_a_tpu():
@@ -725,60 +787,213 @@ def test_unknown_cell_prints_no_result(capsys):
 # a later PR adds files and manifest entries, and edits nothing
 # ---------------------------------------------------------------------------
 
-def test_a_cell_a_config_and_a_metric_are_added_by_files_alone(tmp_path):
+def _checkout(tmp_path):
+    """A copy of ``chipbench/`` in a temporary root, and every file's
+    bytes as copied: what a later PR starts from and may not edit."""
     root = str(tmp_path)
     shutil.copytree(os.path.join(ROOT, "chipbench"),
                     os.path.join(root, "chipbench"),
                     ignore=shutil.ignore_patterns("__pycache__"))
     before = {os.path.join(dp, p): open(os.path.join(dp, p), "rb").read()
               for dp, _, fs in os.walk(root) for p in fs}
+    return root, before
+
+
+def _add(root, files):
+    for rel, text in files.items():
+        assert not os.path.exists(os.path.join(root, rel)), rel
+        with open(os.path.join(root, rel), "w") as f:
+            f.write(text if isinstance(text, str) else json.dumps(text))
+
+
+def _list_cells(manifest, beside, new):
+    """Every metric that lists the cell `beside` lists `new` too."""
+    for metric in manifest["end_to_end"] + manifest["per_layer"]:
+        if beside in metric.get("workloads", ()):
+            metric["workloads"] += new
+
+
+def test_a_cell_a_config_and_a_metric_are_added_by_files_alone(tmp_path):
+    """Another configuration of the family that is here needs a file of
+    its own (its keys, its published widths, its hand-worked values) and
+    no family module."""
+    root, before = _checkout(tmp_path)
     config = mf.load_json("chipbench/configs/gpt2-medium.json")
-    config.update(source="https://huggingface.co/openai-community/gpt2",
-                  n_embd=768, n_layer=12, n_head=12)
+    config.update(
+        source="https://huggingface.co/openai-community/gpt2",
+        n_embd=768, n_layer=12, n_head=12,
+        published=dict(d=768, layers=12, heads=12, head_dim=64, ffn=3072,
+                       positions=1024, vocab=50257),
+        hand_worked={
+            "layer_matmul_params": {
+                "value": 4 * 768 * 768 + 2 * 768 * 3072, "how": "as said"},
+            "param_count": {
+                "value": 2 * 50304 * 768 + 1024 * 768 + 2 * 768 + 12 * (
+                    7_077_888 + 3 * 768 + 768 + 3072 + 768 + 4 * 768),
+                "how": "as said"},
+            "train_flops_per_token_1024": {
+                "value": 3 * (2 * 7_077_888 * 12 + 4 * 512.5 * 768 * 12
+                              + 2 * 768 * 50304), "how": "as said"},
+            "flash_8x1024": {"value": {
+                "flops": 7 * 2 * 1024 * 1024 * 64 * 12 * 8 // 2,
+                "bytes": 12 * 8 * 12 * 1024 * 64 * 2}, "how": "as said"},
+            "paged_2000": {"value": {"flops": 4 * 2000 * 768,
+                                     "bytes": 2 * 2000 * 768 * 2},
+                           "how": "as said"}})
     traffic = mf.load_json("chipbench/traffic/train-dp1.json")
     traffic["rows_per_chip"] = 16
     limits = mf.load_json("chipbench/limits/gpt2-medium.train-dp1.json")
-    added = {"chipbench/configs/gpt2-small.json": json.dumps(config),
-             "chipbench/traffic/train-b16.json": json.dumps(traffic),
-             "chipbench/limits/gpt2-small.train-b16.json":
-                 json.dumps(limits),
-             "chipbench/layer_metrics/train.steps_traced.py":
-                 "def read(run):\n"
-                 "    return run.traced.get('steps') or None\n"}
-    for rel, text in added.items():
-        with open(os.path.join(root, rel), "w") as f:
-            f.write(text)
+    _add(root, {
+        "chipbench/configs/gpt2-small.json": config,
+        "chipbench/traffic/train-b16.json": traffic,
+        "chipbench/limits/gpt2-small.train-b16.json": limits,
+        "chipbench/limits/gpt2-medium.train-b16.json": limits,
+        "chipbench/layer_metrics/train.steps_traced.py":
+            "def read(run):\n"
+            "    return run.traced.get('steps') or None\n"})
     m = copy.deepcopy(MANIFEST)
-    m["configs"].append({
-        "name": "gpt2-small", "file": "chipbench/configs/gpt2-small.json",
-        "source": config["source"], "reduced": list(config["reduced"]),
-        "why": "throw-away"})
-    m["workloads"].append({"name": "gpt2-small.train-b16", "chips": 1,
-                           "config": "gpt2-small", "traffic": "train-b16",
-                           "why": "throw-away"})
-    m["workloads"].append({"name": "gpt2-medium.train-b16", "chips": 1,
-                           "config": "gpt2-medium", "traffic": "train-b16",
-                           "why": "throw-away"})
-    with open(os.path.join(root, "chipbench/limits/"
-                                 "gpt2-medium.train-b16.json"), "w") as f:
-        json.dump(limits, f)
-    new = ["gpt2-small.train-b16", "gpt2-medium.train-b16"]
-    for metric in m["end_to_end"] + m["per_layer"]:
-        if "gpt2-medium.train-dp1" in metric.get("workloads", ()):
-            metric["workloads"] += new
+    small = {"name": "gpt2-small", "source": config["source"],
+             "file": "chipbench/configs/gpt2-small.json",
+             "reduced": list(config["reduced"]), "why": "throw-away"}
+    m["configs"].append(small)
+    new = [{"name": "gpt2-small.train-b16", "chips": 1,
+            "config": "gpt2-small", "traffic": "train-b16",
+            "why": "throw-away"},
+           {"name": "gpt2-medium.train-b16", "chips": 1,
+            "config": "gpt2-medium", "traffic": "train-b16",
+            "why": "throw-away"}]
+    m["workloads"] += new
+    _list_cells(m, "gpt2-medium.train-dp1", _ids(new))
     m["per_layer"].append({
         "name": "train.steps_traced", "unit": "steps", "better": "higher",
         "source": "program_counter", "layer": "train_step",
-        "moves": "train_tokens_per_s", "workloads": new})
+        "moves": "train_tokens_per_s", "workloads": _ids(new)})
     assert mf.validate(m, root) == []
+    check_config_keeps_the_published_widths(small, root)
+    cases = hand_worked_cases([small], root)
+    assert len(cases) == 5
+    for _, what in cases:
+        check_flop_and_byte_functions(small, what, root)
+    for entry in new:
+        check_name_is_one_token(entry)
+        check_cell_files_are_found_by_name(m, entry, root)
     cell = mf.Cell(m, "gpt2-small.train-b16", root)
-    assert ref.Shape(cell.config).d == 768
     assert cell.traffic["rows_per_chip"] == 16
     assert "train.steps_traced" in [x["name"] for x in cell.per_layer]
     run = harness.Run(cell, 0, 1.0, True, False)
+    assert run.shape.d == 768
     run.traced = {"steps": 8}
     assert cell.reader("train.steps_traced").read(run) == 8
     assert cell.runner().Runner(run).shape.layers == 12
+    # a configuration that brings no hand-worked values fails its case
+    del config["hand_worked"]
+    with open(os.path.join(root, small["file"]), "w") as f:
+        json.dump(config, f)
+    (_, what), = hand_worked_cases([small], root)
+    with pytest.raises(AssertionError, match="no hand-worked values"):
+        check_flop_and_byte_functions(small, what, root)
+    # nothing that was there has been edited
+    for path, data in before.items():
+        assert open(path, "rb").read() == data, path
+
+
+TOYGATED = {     # another architecture's keys; the family reads them
+    "source": "tests/test_chipbench/another_family.py",
+    "family": "toygated", "model_type": "toygated",
+    "hidden_size": 64, "num_hidden_layers": 4, "num_attention_heads": 8,
+    "num_key_value_heads": 2, "intermediate_size": 176,
+    "max_position_embeddings": 128, "vocab_size": 1000,
+    "rms_norm_eps": 1e-6, "rope_theta": 10000.0, "initializer_range": 0.02,
+    "reduced": [],
+    "assumed": {"padded_vocab_size": 1024, "compute_dtype": "float32"},
+    "published": dict(d=64, layers=4, heads=8, kv_heads=2, head_dim=8,
+                      ffn=176, positions=128, vocab=1000),
+    "hand_worked": {
+        "layer_matmul_params": {
+            "value": 44_032, "how": "2*64*64 + 2*64*(2*8) + 3*64*176"},
+        "param_count": {
+            "value": 307_776,
+            "how": "2*1024*64 + 64 + 4*(44032 + 2*64)"},
+        "serve_flops_10_prompt_2_decode": {
+            "value": 4_700_160,
+            "how": "2*44032*4*12 + 4*8*8*4*78 + 2*64*1024*3"},
+        "decode_attention_100": {
+            "value": {"flops": 102_400, "bytes": 51_200},
+            "how": "4*100*(8*8)*4; 2*100*(2*8)*4 B*4 layers"}},
+}
+
+
+def test_another_architecture_is_added_by_files_alone(tmp_path, capsys,
+                                                      hvd_off):
+    """A configuration whose keys, model, reference and work counts are
+    not GPT-2's comes as a configuration file, a family module, a limits
+    file and manifest entries. The `serve` runner, the
+    `closed_loop_requests` generator, the traffic file and the readers
+    are used as they stand, and every check the tests above run on the
+    manifest's configurations and cells passes for it."""
+    root, before = _checkout(tmp_path)
+    with open(os.path.join(os.path.dirname(__file__),
+                           "another_family.py")) as f:
+        family_source = f.read()
+    _add(root, {
+        "chipbench/configs/toygated.json": TOYGATED,
+        "chipbench/families/toygated.py": family_source,
+        "chipbench/limits/toygated.serve-closed8.json":
+            {"limits": {"served_logit_gap": 0.01}}})
+    m = copy.deepcopy(MANIFEST)
+    config = {"name": "toygated", "source": TOYGATED["source"],
+              "file": "chipbench/configs/toygated.json", "reduced": [],
+              "why": "throw-away"}
+    cell = {"name": "toygated.serve-closed8", "config": "toygated",
+            "traffic": "serve-closed8", "chips": 1, "why": "throw-away"}
+    m["configs"].append(config)
+    m["workloads"].append(cell)
+    _list_cells(m, "gpt2-xl.serve-closed8", [cell["name"]])
+    # the harness reads BENCHMARK.json from the root it is given
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(m, f)
+    assert mf.validate(m, root) == []
+
+    for entry in (config, cell):
+        check_name_is_one_token(entry)
+    check_config_keeps_the_published_widths(config, root)
+    cases = hand_worked_cases([config], root)
+    assert [w for _, w in cases] == list(TOYGATED["hand_worked"])
+    for _, what in cases:
+        check_flop_and_byte_functions(config, what, root)
+    check_cell_files_are_found_by_name(m, cell, root)
+    for metric in m["per_layer"]:
+        if cell["name"] in metric.get("workloads", ()):
+            check_reader_with_nothing_to_read(m, metric, cell["name"], root)
+    check_no_measurement_off_a_tpu(cell, capsys, root)
+    result = check_rehearsal_ends_in_one_well_formed_line(m, cell, capsys,
+                                                          root)
+    assert result["compared"]["served_logit_gap"]["limit"] == 0.01
+    assert "rehearsal.serve.step_mfu" in result["metrics"]
+
+    # the readers read THIS family's work, at this configuration's sizes
+    c = mf.Cell(m, cell["name"], root)
+    run = harness.Run(c, 0, 1.0, True, False)
+    family = run.family
+    assert family.__file__.startswith(root) and run.shape.kv_heads == 2
+    run.peak = {"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e6}
+    steps = [{"kind": "prefill", "prompt_tokens": 10, "prompt_context": 55,
+              "decode_tokens": 0, "decode_context": 0, "emitted": 1},
+             {"kind": "decode", "prompt_tokens": 0, "prompt_context": 0,
+              "decode_tokens": 2, "decode_context": 23, "emitted": 2}]
+    run.traced = {"seconds": 2.0, "steps": steps}
+    assert c.reader("serve.step_mfu").read(run) == pytest.approx(
+        100 * 4_700_160 / (2.0 * 1e9))
+    decode = ('%_paged_attention_call.4 = f32[8,8,1,8]{3,2,1,0} custom-call('
+              'f32[8,8,1,8]{3,2,1,0} %q), custom_call_target='
+              '"tpu_custom_call"')
+    prefill = decode.replace("[8,8,1,8]", "[8,8,24,8]")
+    run.trace = xplane.Trace(
+        ops={0: [(prefill, 0.0, 1.0), (decode, 1.0, 1.5)]},
+        host_spans=[(xplane.WINDOW_SPAN, 0.0, 2.0)])
+    # 23 cached tokens x 4 layers: K and V at key-value width, float32
+    assert c.reader("serve.paged_attention_roofline").read(run) == \
+        pytest.approx(100 * (2 * 23 * 2 * 8 * 4 * 4 / 1e6) / 0.5)
     # nothing that was there has been edited
     for path, data in before.items():
         assert open(path, "rb").read() == data, path
@@ -786,7 +1001,8 @@ def test_a_cell_a_config_and_a_metric_are_added_by_files_alone(tmp_path):
 
 def test_readme_says_how_to_add_each_kind_of_file():
     text = open(os.path.join(ROOT, "chipbench/README.md")).read()
-    for word in ("chipbench/configs/", "chipbench/traffic/",
-                 "chipbench/generators/", "chipbench/layer_metrics/",
-                 "chipbench/runners/", "chipbench/limits/"):
+    for word in ("chipbench/configs/", "chipbench/families/",
+                 "chipbench/traffic/", "chipbench/generators/",
+                 "chipbench/layer_metrics/", "chipbench/runners/",
+                 "chipbench/limits/"):
         assert word in text, word

@@ -93,7 +93,7 @@ def _planted(shift=0.0, window_len=P_STOP - P_START, with_window=True,
     cell = mf.Cell(MANIFEST, CELL)
     run = harness.Run(cell, 0, 45.0, True, False)
     run.tracer.t_start, run.tracer.t_stop = P_START, P_STOP
-    run.window_s = 45.0
+    run.window_open, run.window_s = P_START - 2.0, 45.0
     rec = ShiftedRecorder(shift, capacity)
     gaps, want = [], {}
     for i, kind in enumerate(["decode", "decode", "prefill", "decode",
@@ -252,7 +252,7 @@ def test_host_side_takes_the_undisturbed_requests():
     token gaps pooled; decode occupancy over the same stretch."""
     run, rec, _ = _planted()
     run.seconds, run.window_s = 45.0, 45.0
-    t_open = P_START - 2.0
+    t_open = run.window_open
     quiet = P_STOP + 3.0            # the stop stalled the loop for 3 s
     rec.plant("sched_iteration", quiet, quiet + 0.03)
     rec.plant("sched_iteration", quiet + 0.03, quiet + 0.06)
