@@ -46,14 +46,21 @@ def _sds(ref_array, shape, dtype):
 
 
 def _pos_mask(qi_base, kb_base, bq, bk, *, causal: bool,
-              seq_q: int, seq_q_p: int, seq_k: int, seq_k_p: int):
+              seq_q: int, seq_q_p: int, seq_k: int, seq_k_p: int,
+              window: Optional[int] = None):
     """[bq, bk] validity mask for a (query-block, key-block) tile:
-    causal lower-triangle plus real (unpadded) position bounds."""
+    causal lower-triangle plus real (unpadded) position bounds; with a
+    ``window``, also only the newest ``window`` keys of each query
+    (``q_pos - window < k_pos``). `qi_base` is the block's first
+    query's position among the KEYS (a prefill's queries start past a
+    cached prefix)."""
     q_pos = qi_base + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     k_pos = kb_base + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     mask = jnp.full((bq, bk), True)
     if causal:
         mask = q_pos >= k_pos
+    if window is not None:
+        mask = mask & (q_pos - window < k_pos)
     if seq_k != seq_k_p:
         mask = mask & (k_pos < seq_k)
     if seq_q != seq_q_p:
@@ -67,31 +74,43 @@ def _pos_mask(qi_base, kb_base, bq, bk, *, causal: bool,
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_ref,
                 scale: float, causal: bool, block_q: int, block_k: int,
-                seq_q: int, seq_q_p: int, seq_k: int, seq_k_p: int):
+                seq_q: int, seq_q_p: int, seq_k: int, seq_k_p: int,
+                window: Optional[int] = None, q_start=None,
+                mxu_dtype=jnp.float32):
+    """`window`, `q_start` and `mxu_dtype` are the serving prefill's
+    (:func:`flash_prefill`); left at their defaults the kernel is the
+    training forward, operation for operation."""
     qi = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32) * scale       # [bq, D]
     bq, d = q.shape
+    q = q.astype(mxu_dtype)
+    # position among the keys of this block's first query
+    q_base = qi * block_q if q_start is None else q_start + qi * block_q
 
     num_kb = seq_k_p // block_k
     if causal:
         # last key position this query block can see
-        last = (qi + 1) * block_q - 1
+        last = q_base + block_q - 1
         nkb = jnp.minimum(num_kb, (last // block_k) + 1)
     else:
         nkb = num_kb
+    # first key block that holds a key the block's first query can see:
+    # the blocks wholly behind the window are skipped, not masked
+    kb0 = 0 if window is None else \
+        jnp.maximum(q_base - window + 1, 0) // block_k
 
     def body(kb, carry):
         o, m, l = carry
         k = k_ref[0, 0, pl.dslice(kb * block_k, block_k), :].astype(
-            jnp.float32)                              # [bk, D]
+            mxu_dtype)                                # [bk, D]
         v = v_ref[0, 0, pl.dslice(kb * block_k, block_k), :].astype(
-            jnp.float32)
+            mxu_dtype)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)       # [bq, bk]
-        mask = _pos_mask(qi * block_q, kb * block_k, bq, block_k,
+        mask = _pos_mask(q_base, kb * block_k, bq, block_k,
                          causal=causal, seq_q=seq_q, seq_q_p=seq_q_p,
-                         seq_k=seq_k, seq_k_p=seq_k_p)
+                         seq_k=seq_k, seq_k_p=seq_k_p, window=window)
         s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
@@ -101,14 +120,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_ref,
         corr = jnp.where(m <= NEG_INF / 2, 0.0, corr)
         l_new = l * corr + p.sum(axis=-1)
         o_new = o * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(mxu_dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return o_new, m_new, l_new
 
     o0 = jnp.zeros((bq, d), jnp.float32)
     m0 = jnp.full((bq,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
-    o, m, l = jax.lax.fori_loop(0, nkb, body, (o0, m0, l0))
+    o, m, l = jax.lax.fori_loop(kb0, nkb, body, (o0, m0, l0))
     o = o / jnp.maximum(l, 1e-20)[:, None]
     o_ref[0, 0] = o.astype(o_ref.dtype)
     if maybe_lse_ref:   # training: emit per-row log-sum-exp for the VJP
@@ -150,6 +169,70 @@ def _fwd_impl(q, k, v, causal, scale, block_q, block_k,
         interpret=interpret,
     )(q, k, v)
     return out if emit_lse else (out[0], None)
+
+
+def _prefill_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, **kw):
+    """The forward kernel with each row's first query position read
+    from the scalar-prefetched ``start_ref``."""
+    _fwd_kernel(q_ref, k_ref, v_ref, o_ref,
+                q_start=start_ref[pl.program_id(0)], **kw)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "window", "block_q", "block_k", "interpret"))
+def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
+                  q_start: jax.Array, *, window: Optional[int] = None,
+                  block_q: int = 512, block_k: int = 512,
+                  interpret: bool = False) -> jax.Array:
+    """Causal attention of a serving prefill: row ``b``'s ``Sq`` queries
+    ``[B, H, Sq, D]`` sit at key positions ``q_start[b] + i`` of its
+    ``[B, H_kv, Skv, D]`` keys (a cached prefix first, then the fresh
+    tokens), query ``i`` sees keys ``<= q_start[b] + i`` and, with a
+    ``window``, only the newest ``window`` of them; key blocks wholly
+    behind the window or past the diagonal are skipped. Forward only,
+    operands to the MXU in the inputs' dtype with float32 accumulation
+    and softmax. Keys past a row's real length must be finite (they are
+    masked, not skipped)."""
+    from jax.experimental.pallas import tpu as pltpu
+    qq, kk, vv, scale, block_q, block_k, Sq, Skv, pad_q = _prepare(
+        q, k, v, None, block_q, block_k)
+    B, H, Sq_p, D = qq.shape
+    KV, Skv_p = kk.shape[1], kk.shape[2]
+    G = H // KV
+    kernel = functools.partial(
+        _prefill_kernel, scale=scale, causal=True, block_q=block_q,
+        # padded query rows are cut off below, not masked: a query's
+        # position is not its index here
+        block_k=block_k, seq_q=Sq_p, seq_q_p=Sq_p, seq_k=Skv,
+        seq_k_p=Skv_p, window=window, mxu_dtype=q.dtype)
+    # K and V of one kv head stay whole in VMEM (double-buffered), as in
+    # the training forward; at a 12,800-key context that is past the
+    # compiler's default scoped limit
+    tile = -(-D // 128) * 128
+    need = 4 * Skv_p * tile * k.dtype.itemsize \
+        + 6 * block_q * block_k * 4 + 8 * block_q * tile * 4
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, Sq_p // block_q),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, D),
+                             lambda b, h, qi, st: (b, h, qi, 0)),
+                pl.BlockSpec((1, 1, Skv_p, D),
+                             lambda b, h, qi, st: (b, h // G, 0, 0)),
+                pl.BlockSpec((1, 1, Skv_p, D),
+                             lambda b, h, qi, st: (b, h // G, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, block_q, D),
+                                   lambda b, h, qi, st: (b, h, qi, 0))),
+        out_shape=_sds(q, (B, H, Sq_p, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=max(16 << 20, need + need // 4)),
+        interpret=interpret,
+    )(jnp.asarray(q_start, jnp.int32), qq, kk, vv)
+    return out[:, :, :Sq] if pad_q else out
 
 
 # ---------------------------------------------------------------------------

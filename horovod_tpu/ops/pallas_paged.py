@@ -103,8 +103,15 @@ def resolve_kernel(explicit: Optional[str] = None, *,
 # paged decode / fused-verify attention kernel
 # ---------------------------------------------------------------------------
 
+def _first_window_block(pos, window: int, BS: int):
+    """Table entry that holds the oldest key a row's first query (at
+    ``pos``) can see through a window of ``window`` keys."""
+    return jnp.maximum(pos - window + 1, 0) // BS
+
+
 def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, kb_ref, vb_ref, o_ref,
-                       k_scr, v_scr, *, G: int, BS: int, nblk: int):
+                       k_scr, v_scr, *, G: int, BS: int, nblk: int,
+                       window: Optional[int] = None):
     """One (row, table entry) grid step. The pipeline has already
     fetched pool block ``max(table[b, j], 0)`` into ``kb_ref``/``vb_ref``
     (``[1, BS, KV, D]``); an assigned block is split by kv head into the
@@ -112,12 +119,26 @@ def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, kb_ref, vb_ref, o_ref,
     entry zero-fills its slice. The last entry's step runs the oracle's
     masked-attention math over the assembled view, one kv head at a
     time, for all ``T * G`` queries of that head (T positions x G
-    grouped query heads) at once."""
+    grouped query heads) at once.
+
+    With a ``window`` the grid's second axis is shorter than the table:
+    step ``j`` holds table entry ``first + j``, ``first`` being the
+    entry of the oldest key the row's first query can see, and the
+    assembly starts at key ``first * BS``."""
     b = pl.program_id(0)
     j = pl.program_id(1)
     KV, L, D = k_scr.shape
     dst = pl.ds(pl.multiple_of(j * BS, BS), BS)
-    blk = tbl_ref[b, j]
+    if window is None:
+        base = 0
+        blk = tbl_ref[b, j]
+    else:
+        first = _first_window_block(pos_ref[b], window, BS)
+        base = first * BS
+        entry = first + j
+        # entries past the table hold no key a query can see
+        blk = jnp.where(entry < nblk,
+                        tbl_ref[b, jnp.minimum(entry, nblk - 1)], -1)
 
     @pl.when(blk >= 0)
     def _():
@@ -133,7 +154,7 @@ def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, kb_ref, vb_ref, o_ref,
         k_scr[:, dst, :] = jnp.zeros((KV, BS, D), k_scr.dtype)
         v_scr[:, dst, :] = jnp.zeros((KV, BS, D), v_scr.dtype)
 
-    @pl.when(j == nblk - 1)
+    @pl.when(j == L // BS - 1)
     def _():
         pos = pos_ref[b]
         TG = q_ref.shape[2]
@@ -142,7 +163,13 @@ def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, kb_ref, vb_ref, o_ref,
         # integer division
         r_of = jax.lax.broadcasted_iota(jnp.int32, (TG, L), 0)
         j_of = jax.lax.broadcasted_iota(jnp.int32, (TG, L), 1)
-        valid = (j_of - pos) * G <= r_of
+        if window is None:
+            valid = (j_of - pos) * G <= r_of
+        else:
+            # ... and iff j > pos + t - window, i.e.
+            # (j - pos + window) * G > r
+            rel = j_of + (base - pos)
+            valid = (rel * G <= r_of) & ((rel + window) * G > r_of)
 
         def head(h, carry):
             q = q_ref[0, h].astype(jnp.float32)              # [T*G, D]
@@ -185,12 +212,22 @@ def _vmem_limit_bytes(TG: int, KV: int, D: int, BS: int, nblk: int,
     return max(16 << 20, need + need // 4)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+def window_entries(window: Optional[int], T: int, BS: int, nblk: int) -> int:
+    """Table entries one row's kernel walks: all ``nblk`` without a
+    window, else as many as ``T`` consecutive queries with ``window``
+    visible keys each can span (``window / BS + 1`` for one query)."""
+    if window is None:
+        return nblk
+    return min(nblk, (window + T - 2) // BS + 2)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def _paged_attention_call(q, pool_k, pool_v, block_tables, positions,
-                          interpret: bool):
+                          interpret: bool, window: Optional[int] = None):
     B, T, H, D = q.shape
     _NB, BS, KV, _ = pool_k.shape
     nblk = block_tables.shape[1]
+    nwin = window_entries(window, T, BS, nblk)
     G = H // KV
     # [B, T, H, D] -> [B, KV, T*G, D] (row order t*G + g, the oracle's
     # flattening): every block's two minor dims then span the whole
@@ -205,14 +242,19 @@ def _paged_attention_call(q, pool_k, pool_v, block_tables, positions,
         # -1 entries fetch block 0 and the kernel ignores what arrives;
         # the pipeline re-fetches only when the index changes, so a
         # row's unassigned tail costs one block, not nblk
+        if window is not None:
+            # the walk starts at the row's first in-window entry
+            j = jnp.minimum(_first_window_block(pos[b], window, BS) + j,
+                            nblk - 1)
         return jnp.maximum(tbl[b, j], 0), 0, 0, 0
 
-    kern = functools.partial(_paged_attn_kernel, G=G, BS=BS, nblk=nblk)
+    kern = functools.partial(_paged_attn_kernel, G=G, BS=BS, nblk=nblk,
+                             window=window)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,            # block tables, positions
-            grid=(B, nblk),
+            grid=(B, nwin),
             in_specs=[
                 pl.BlockSpec((1, KV, T * G, D), row_map),
                 pl.BlockSpec((1, BS, KV, D), blk_map),   # pool_k block
@@ -220,8 +262,8 @@ def _paged_attention_call(q, pool_k, pool_v, block_tables, positions,
             ],
             out_specs=pl.BlockSpec((1, KV, T * G, D), row_map),
             scratch_shapes=[
-                pltpu.VMEM((KV, nblk * BS, D), pool_k.dtype),
-                pltpu.VMEM((KV, nblk * BS, D), pool_v.dtype),
+                pltpu.VMEM((KV, nwin * BS, D), pool_k.dtype),
+                pltpu.VMEM((KV, nwin * BS, D), pool_v.dtype),
             ]),
         out_shape=jax.ShapeDtypeStruct((B, KV, T * G, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -229,7 +271,7 @@ def _paged_attention_call(q, pool_k, pool_v, block_tables, positions,
             # assembly in order and the output block is written last
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit_bytes(
-                T * G, KV, D, BS, nblk, pool_k.dtype.itemsize)),
+                T * G, KV, D, BS, nwin, pool_k.dtype.itemsize)),
         interpret=interpret,
     )(block_tables, positions, qf, pool_k, pool_v)
     return out.reshape(B, KV, T, G, D).transpose(0, 2, 1, 3, 4).reshape(
@@ -239,7 +281,8 @@ def _paged_attention_call(q, pool_k, pool_v, block_tables, positions,
 def paged_attention_fused(q: jax.Array, pool_k: jax.Array,
                           pool_v: jax.Array, block_tables: jax.Array,
                           positions: jax.Array, *,
-                          interpret: Optional[bool] = None) -> jax.Array:
+                          interpret: Optional[bool] = None,
+                          window: Optional[int] = None) -> jax.Array:
     """Drop-in fused replacement for `serve.kv_cache.paged_attention`.
 
     q ``[B, T, H, D]``; pool_k/pool_v ``[num_blocks, block_size, H_kv,
@@ -249,9 +292,18 @@ def paged_attention_fused(q: jax.Array, pool_k: jax.Array,
     block-table walk and one KV fetch per (row, kv head)). Output
     ``[B, T, H, D]`` — bit-exact against the oracle in interpret mode.
 
+    ``window`` (static, per layer): a query at position ``p`` sees keys
+    ``(p - window, p]`` only. The grid then covers just the table
+    entries that can hold a visible key (:func:`window_entries`,
+    starting at the row's first in-window block), so a window layer's
+    walk and VMEM assembly do not grow with the context. ``None`` is the
+    program without the argument, bit for bit.
+
     ``interpret=None`` auto-selects: compiled on TPU, interpret mode
     everywhere else (the CPU parity/CI tier).
     """
+    if window is not None and window < 1:
+        raise ValueError(f"window must be >= 1 or None; got {window}")
     if q.shape[2] % pool_k.shape[2]:
         raise ValueError(
             f"q heads {q.shape[2]} must be a multiple of kv heads "
@@ -260,7 +312,8 @@ def paged_attention_fused(q: jax.Array, pool_k: jax.Array,
         interpret = jax.default_backend() != "tpu"
     return _paged_attention_call(
         q, pool_k, pool_v, jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray(positions, jnp.int32), bool(interpret))
+        jnp.asarray(positions, jnp.int32), bool(interpret),
+        None if window is None else int(window))
 
 
 # ---------------------------------------------------------------------------

@@ -139,6 +139,20 @@ class ContinuousBatcher:
         self.executor = executor
         self.queue = queue
         self.buckets = buckets
+        #: rows a prefill step holds. None: the packed
+        #: ``[max_batch, bucket]`` step, every admitted row at the
+        #: longest one's bucket. A model whose config names
+        #: ``prefill_rows`` (long prompts: models/routed_lm.py) is
+        #: prefilled row-compact instead, ``[prefill_rows, bucket]`` a
+        #: step with each step at its own rows' bucket, so a prefill's
+        #: work follows the prompt tokens admitted and not
+        #: ``max_batch x`` the longest. Paged only: the rows of such a
+        #: step are addressed through their block tables
+        rows = getattr(getattr(executor.model, "cfg", None),
+                       "prefill_rows", None)
+        self.prefill_rows: Optional[int] = (
+            min(int(rows), executor.max_batch)
+            if rows and getattr(executor, "paged", False) else None)
         self.eos_id = eos_id
         #: fleet identity (None = standalone): labels the metric
         #: series and addresses chaos serve.step / serve.kv faults
@@ -430,9 +444,12 @@ class ContinuousBatcher:
         off = np.zeros(B, bool)
         tbl = (np.full((B, self.executor.blocks_per_seq), -1, np.int32)
                if self.paged else None)
+        R = self.prefill_rows or B      # a prefill step's rows
         for b in self.buckets:
-            self.executor.step(np.zeros((B, b), np.int32), zero, off,
-                               zero, kind="prefill", block_tables=tbl)
+            self.executor.step(
+                np.zeros((R, b), np.int32), zero[:R], off[:R], zero[:R],
+                kind="prefill",
+                block_tables=None if tbl is None else tbl[:R])
         self.executor.step(np.zeros((B, 1), np.int32), zero, off, zero,
                            kind="decode", block_tables=tbl)
         if self.paged:
@@ -857,23 +874,27 @@ class ContinuousBatcher:
                    error=error)
 
     # -- on-device sampling row data -----------------------------------------
-    def _sample_args(self, rows, ctr_offset: int = 0) -> dict:
+    def _sample_args(self, slots, ctr_offset: int = 0,
+                     rows=None) -> dict:
         """Per-row sampling arrays for the jitted step: each active
         row's request temperature / top-p / seed plus its draw counter
         (``rng_ctr + ctr_offset``). Rows not listed stay at the greedy
-        defaults (temperature 0) and are masked out anyway."""
-        B = self.executor.max_batch
+        defaults (temperature 0) and are masked out anyway. `rows`
+        (parallel to `slots`): where each slot's sequence sits in a
+        row-compact prefill step of `prefill_rows` rows; None = in its
+        own batch slot."""
+        B = self.executor.max_batch if rows is None else self.prefill_rows
         s = {"temperature": np.zeros(B, np.float32),
              "top_p": np.ones(B, np.float32),
              "seed": np.zeros(B, np.uint32),
              "ctr": np.zeros(B, np.int32)}
-        for slot in rows:
+        for at, slot in zip(slots if rows is None else rows, slots):
             seq = self._active[slot]
             req = seq.req
-            s["temperature"][slot] = getattr(req, "temperature", 0.0)
-            s["top_p"][slot] = getattr(req, "top_p", 1.0)
-            s["seed"][slot] = int(getattr(req, "seed", 0)) & 0xFFFFFFFF
-            s["ctr"][slot] = seq.rng_ctr + ctr_offset
+            s["temperature"][at] = getattr(req, "temperature", 0.0)
+            s["top_p"][at] = getattr(req, "top_p", 1.0)
+            s["seed"][at] = int(getattr(req, "seed", 0)) & 0xFFFFFFFF
+            s["ctr"][at] = seq.rng_ctr + ctr_offset
         return s
 
     # -- crc plumbing (slot- or block-granular) ------------------------------
@@ -1174,29 +1195,59 @@ class ContinuousBatcher:
                 if a.prefix_tokens:
                     hit_rows.append(a)
                 self.kv.ensure(row, len(a.req.prompt))
-        # ONE packed prefill at the smallest bucket fitting the longest
-        # SUFFIX (the unmatched prompt tail; the whole prompt when the
-        # prefix cache missed or is off)
-        bucket = self._bucket_for(
-            max(len(a.req.prompt) - a.prefix_tokens for a in admitted))
-        tokens = np.zeros((B, bucket), np.int32)
-        positions = np.zeros(B, np.int32)
-        mask = np.zeros(B, bool)
-        last_idx = np.zeros(B, np.int32)
-        for a in admitted:
-            m = a.prefix_tokens
-            suffix = a.req.prompt[m:]
-            tokens[a.slot, :len(suffix)] = suffix
-            positions[a.slot] = m
-            mask[a.slot] = True
-            last_idx[a.slot] = len(suffix) - 1
+        R = self.prefill_rows
+        if R is None:
+            # ONE packed prefill, row = the sequence's batch slot
+            waves = [[(a.slot, a) for a in admitted]]
+        else:
+            # row-compact: R sequences a step, longest first so that a
+            # step's rows share a bucket as nearly as they can; row =
+            # the sequence's place in its step
+            order = sorted(admitted, key=lambda a: a.prefix_tokens
+                           - len(a.req.prompt))
+            waves = [list(enumerate(order[i:i + R]))
+                     for i in range(0, len(order), R)]
+        rows = B if R is None else R
         expected_v = self._prefix_version
-        nxt = self.executor.step(
-            tokens, positions, mask, last_idx, kind="prefill",
-            stats=self._stats(),
-            sample=self._sample_args([a.slot for a in admitted]),
-            block_tables=self.kv.table() if self.paged else None)
-        if hit_rows and self.executor.last_step_version != expected_v:
+        #: slot -> (its first token, when its wave's step ended)
+        first: Dict[int, tuple] = {}
+        stale = False
+        for wave in waves:
+            # the smallest bucket fitting the wave's longest SUFFIX (the
+            # unmatched prompt tail; the whole prompt when the prefix
+            # cache missed or is off)
+            bucket = self._bucket_for(max(
+                len(a.req.prompt) - a.prefix_tokens for _, a in wave))
+            tokens = np.zeros((rows, bucket), np.int32)
+            positions = np.zeros(rows, np.int32)
+            mask = np.zeros(rows, bool)
+            last_idx = np.zeros(rows, np.int32)
+            tables = self.kv.table() if self.paged else None
+            if R is not None:
+                # each row's own table, in the step's row order
+                at = np.full(rows, -1)
+                at[[r for r, _ in wave]] = [a.slot for _, a in wave]
+                tables = np.where(at[:, None] >= 0, tables[at], -1)
+            for r, a in wave:
+                m = a.prefix_tokens
+                suffix = a.req.prompt[m:]
+                tokens[r, :len(suffix)] = suffix
+                positions[r] = m
+                mask[r] = True
+                last_idx[r] = len(suffix) - 1
+            nxt = self.executor.step(
+                tokens, positions, mask, last_idx, kind="prefill",
+                stats=self._stats(),
+                sample=self._sample_args(
+                    [a.slot for _, a in wave],
+                    rows=None if R is None else [r for r, _ in wave]),
+                block_tables=tables)
+            stale = stale or \
+                self.executor.last_step_version != expected_v
+            t_wave = time.monotonic()   # one stamp for the wave's rows
+            for r, a in wave:
+                first[a.slot] = (int(nxt[r]), t_wave)
+        if hit_rows and stale:
             # a weight swap landed between the prefix match and this
             # prefill: the hit rows mixed old-version cached KV with
             # new-version compute. Tear them down and re-prefill from
@@ -1214,10 +1265,9 @@ class ContinuousBatcher:
                 del self._active[a.slot]
                 self._reprefill.append(a.req)
             admitted = [a for a in admitted if a not in hit_rows]
-        # the wave's first tokens exist: one stamp for all its rows
-        t_first = time.monotonic()
         rec = _trace_recorder()
         for a in admitted:
+            token, t_first = first[a.slot]
             self._m_ttft.observe(
                 (t_first - a.req.submitted_at) * 1000.0)
             a.t_admit, a.t_first = t_p0, t_first
@@ -1234,7 +1284,7 @@ class ContinuousBatcher:
             a.rng_ctr = 1   # the prefill's first token consumed draw 0
             # the prompt is fully cached but only [0, n) is valid; the
             # first generated token is the prompt's last-logit argmax
-            a.out.append(int(nxt[a.slot]))
+            a.out.append(token)
             self.kv.lengths[a.slot] = n
             # crc-on-write covers exactly the written span [m, n) (pad
             # positions past n are unreachable and unverified; shared

@@ -201,7 +201,20 @@ class ShardedExecutor:
             return self.model.apply(
                 {"params": params, "cache": cache}, tokens,
                 positions=positions, update_mask=mask,
-                logits_idx=logits_idx, mutable=["cache"], **kw)
+                logits_idx=logits_idx, mutable=["cache", "stats"], **kw)
+
+        def with_stats(per_row, vout):
+            """A model that sows step counters (``stats`` collection:
+            experts that received a token, per layer) gets their sum
+            appended to the step's per-row int32 result, so the one
+            readback carries it; any other model's program is as it
+            was."""
+            if "stats" not in vout:
+                return per_row
+            total = sum(jnp.sum(x) for x in
+                        jax.tree_util.tree_leaves(vout["stats"]))
+            return jnp.concatenate(
+                [per_row, total.astype(per_row.dtype)[None]])
 
         if self.paged:
             def fwd_token(params, cache, tokens, positions, mask,
@@ -211,6 +224,7 @@ class ShardedExecutor:
                                            last_idx)
                 tok, probs = sample_with_probs(
                     logits[:, 0], temp, top_p, seed, ctr, stream=stream)
+                tok = with_stats(tok, vout)
                 if emit_probs:
                     return tok, probs, vout["cache"]
                 return tok, vout["cache"]
@@ -224,7 +238,7 @@ class ShardedExecutor:
                 emitted, n_acc = speculative_accept(
                     tokens, dprobs, logits, n_draft, temp, top_p, seed,
                     ctr)
-                return emitted, n_acc, vout["cache"]
+                return emitted, with_stats(n_acc, vout), vout["cache"]
         else:
             def fwd_token(params, cache, tokens, positions, mask,
                           last_idx, temp, top_p, seed, ctr):
@@ -258,15 +272,18 @@ class ShardedExecutor:
             kw = {"block_tables": tables} if self.paged else {}
             _, v = self.model.apply(
                 {"params": params}, tokens, positions=positions,
-                update_mask=mask, mutable=["cache"], **kw)
-            return v["cache"]
+                update_mask=mask, mutable=["cache", "stats"], **kw)
+            return v["cache"], "stats" in v
 
         z = jnp.zeros((max_batch, 1), jnp.int32)
         zt = jnp.full((max_batch, max(self.blocks_per_seq, 1)), -1,
                       jnp.int32)
-        self.cache = jax.jit(make_cache, static_argnums=())(
+        #: does the model sow step counters (see `with_stats`)? A plain
+        #: bool out of the trace: the collection is there or it is not
+        self.cache, has_stats = jax.jit(make_cache)(
             params, z, jnp.zeros((max_batch,), jnp.int32),
             jnp.zeros((max_batch,), bool), zt)
+        self._has_stats = bool(has_stats)
 
         if self.paged:
             # CoW block copy, jitted once (shapes are static): donation
@@ -301,10 +318,9 @@ class ShardedExecutor:
                 "role": role, "backend": jax.default_backend()})
 
     # -- the one step --------------------------------------------------------
-    def _default_sample(self) -> Dict[str, np.ndarray]:
+    def _default_sample(self, B: int) -> Dict[str, np.ndarray]:
         """Greedy row data: temperature 0 everywhere (the all-greedy
         `lax.cond` fast path inside the jitted step)."""
-        B = self.max_batch
         return {"temperature": np.zeros(B, np.float32),
                 "top_p": np.ones(B, np.float32),
                 "seed": np.zeros(B, np.uint32),
@@ -324,6 +340,12 @@ class ShardedExecutor:
         [max_batch, blocks_per_seq] int32 (paged executors only).
         ``sample`` carries the per-row sampling data (temperature /
         top_p / seed / ctr arrays, [max_batch] each); None is greedy.
+        A paged PREFILL may be row-compact: ``[rows, T]`` tokens with
+        ``rows`` < max_batch and every per-row array (the block tables
+        too) of that many rows: the pool is addressed through the
+        tables alone, so a step's rows need not be the batch's (the
+        batcher prefills a long prompt alone; each ``(rows, T)`` shape
+        is one compiled program, to be warmed like any other).
         `stats` (queue depth, occupancy, shed count — batcher-supplied)
         is folded into the SERVE event.
 
@@ -346,21 +368,32 @@ class ShardedExecutor:
         self.signatures.add((kind, T))
         if self.paged and block_tables is None:
             raise ValueError("a paged executor step needs block_tables")
+        B = int(tokens.shape[0])
+        if B != self.max_batch and not (self.paged and kind == "prefill"):
+            raise ValueError(
+                f"{kind} step of {B} rows on an executor of "
+                f"{self.max_batch}: only a paged prefill may be "
+                f"row-compact")
         n_tok = int(np.sum(mask))
         rec = _trace_recorder()
         probs = None
+        attrs = {"kind": kind, "rows": n_tok}
+        if kind == "prefill":
+            # prompt tokens this step ingests (each row's up to its
+            # emitting position; the rest of the bucket is padding)
+            attrs["tokens"] = int(np.sum(
+                (np.asarray(last_idx) + 1)[np.asarray(mask, bool)]))
         # the step and its three legs land in the process's span ring
         # (docs/tracing.md): which of them the device waits for is what
         # the serve cell's idle metrics read
-        with rec.span("exec_step", kind=kind, rows=n_tok):
+        with rec.span("exec_step", **attrs) as step_span:
             with rec.span("exec_upload"):
                 s = sample if sample is not None \
-                    else self._default_sample()
+                    else self._default_sample(B)
                 args = [jnp.asarray(tokens, jnp.int32),
                         jnp.asarray(positions, jnp.int32),
                         jnp.asarray(mask, bool)]
                 if kind == "verify":
-                    B = self.max_batch
                     if draft_probs is None:
                         draft_probs = jnp.zeros(
                             (B, T - 1, self.vocab_size), jnp.float32)
@@ -392,6 +425,14 @@ class ShardedExecutor:
                         nxt = np.asarray(out[0])
                         if self.role == "draft":
                             probs = out[1]
+            if self._has_stats:
+                # the model's step counter rode in on the readback as
+                # one more entry of the per-row result
+                if kind == "verify":
+                    nxt, hit = (nxt[0], nxt[1][:-1]), nxt[1][-1]
+                else:
+                    nxt, hit = nxt[:-1], nxt[-1]
+                step_span.set(experts_hit=int(hit))
         dt_ms = (time.perf_counter() - t0) * 1000.0
         self.steps += 1
         self._m_step_ms.get(kind, self._m_step_ms["decode"]).observe(dt_ms)
@@ -646,7 +687,7 @@ class ShardedExecutor:
         is compiled, run or donated."""
         B = self.max_batch
         zi = jnp.zeros((B,), jnp.int32)
-        s = self._default_sample()
+        s = self._default_sample(B)
         args = [self.params, self.cache, jnp.zeros((B, 1), jnp.int32), zi,
                 jnp.zeros((B,), bool), zi,
                 jnp.asarray(s["temperature"]), jnp.asarray(s["top_p"]),

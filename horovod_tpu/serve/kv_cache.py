@@ -80,13 +80,15 @@ def cached_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
 
 
 def masked_attention(q: jax.Array, keys: jax.Array, vals: jax.Array,
-                     positions: jax.Array) -> jax.Array:
+                     positions: jax.Array,
+                     window: Optional[int] = None) -> jax.Array:
     """THE masked-attention contract — the single reference
     implementation shared by every decode read in the tree.
 
     Causal attention of `T` query tokens over each row's
     ``[B, L, H_kv, D]`` key/value view, valid positions
-    ``[0, positions[b] + t]`` only; f32 score math, divide-after-dot
+    ``[0, positions[b] + t]`` only (with a ``window``, the newest
+    ``window`` of them); f32 score math, divide-after-dot
     ``1/sqrt(D)`` scaling, large-negative additive masking, output cast
     back to ``q.dtype``. `cached_attention` (slotted), `paged_attention`
     (the gathered-pool XLA path) and the models' decode attention all
@@ -115,8 +117,10 @@ def masked_attention(q: jax.Array, keys: jax.Array, vals: jax.Array,
         qf, kf, (((3,), (3,)), ((0, 1), (0, 1))),
         preferred_element_type=jnp.float32) / np.sqrt(D)  # [B, KV, TG, L]
     t_of = jnp.arange(T * G) // G
-    valid = jnp.arange(L)[None, None, None, :] <= (
-        positions[:, None, None, None] + t_of[None, None, :, None])
+    q_pos = positions[:, None, None, None] + t_of[None, None, :, None]
+    valid = jnp.arange(L)[None, None, None, :] <= q_pos
+    if window is not None:
+        valid &= jnp.arange(L)[None, None, None, :] > q_pos - window
     scores = jnp.where(valid, scores, _MASK_VALUE)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jax.lax.dot_general(
@@ -173,8 +177,8 @@ def write_kv_paged(pool_k: jax.Array, pool_v: jax.Array, k_new: jax.Array,
 
 
 def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
-                    block_tables: jax.Array,
-                    positions: jax.Array) -> jax.Array:
+                    block_tables: jax.Array, positions: jax.Array,
+                    window: Optional[int] = None) -> jax.Array:
     """Block-table-aware masked attention over the pooled cache.
 
     Gathers each row's blocks into a contiguous
@@ -182,14 +186,15 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     same positional validity mask as the slotted read. Unassigned table
     entries (-1) are sanitized to block 0; whatever they gather is
     unreachable — a sequence's valid prefix never extends past its
-    assigned blocks.
+    assigned blocks. ``window``: each query sees its newest ``window``
+    keys only (the fused kernel's argument of the same name).
     """
     NB, BS = pool_k.shape[0], pool_k.shape[1]
     B, nblk = block_tables.shape
     tbl = jnp.maximum(block_tables, 0)
     keys = pool_k[tbl].reshape(B, nblk * BS, *pool_k.shape[2:])
     vals = pool_v[tbl].reshape(B, nblk * BS, *pool_v.shape[2:])
-    return masked_attention(q, keys, vals, positions)
+    return masked_attention(q, keys, vals, positions, window)
 
 
 def pool_blocks_for(max_batch: int, max_len: int, block_size: int,

@@ -179,3 +179,95 @@ def test_four_chip_train_step_compiles(v5e):
     step, args = _train_step(mesh)
     compiled = _aot_compile(step, args)
     assert "all-reduce" in compiled.as_text()      # the dp gradient sum
+
+
+# -- the long-context serving shapes: window and full layers side by side -----
+# 16 rows, 28 query heads over 4 kv heads of 128, a pool of 800 blocks of
+# 128 tokens, 100 table entries a row (12,800 positions); prompts of up to
+# 12,288 tokens prefilled one row a step. A full layer's paged kernel asks
+# for about 55 MB of scoped VMEM at these sizes; a Mosaic refusal (VMEM,
+# tiling, the dynamic loop bounds of the window) is found here.
+
+_LONG = dict(rows=16, H=28, KV=4, D=128, block=128, entries=100, pool=800,
+             window=4096, prompt=12288)
+
+
+def _long_paged_args(sharding=None):
+    c = _LONG
+    pool = _sds((c["pool"], c["block"], c["KV"], c["D"]), jnp.bfloat16,
+                sharding)
+    return (_sds((c["rows"], 1, c["H"], c["D"]), jnp.bfloat16, sharding),
+            pool, pool, _sds((c["rows"], c["entries"]), jnp.int32, sharding),
+            _sds((c["rows"],), jnp.int32, sharding))
+
+
+def _long_prefill_args(sharding=None):
+    c = _LONG
+    keys = _sds((1, c["KV"], c["entries"] * c["block"], c["D"]),
+                jnp.bfloat16, sharding)
+    return (_sds((1, c["H"], c["prompt"], c["D"]), jnp.bfloat16, sharding),
+            keys, keys, _sds((1,), jnp.int32, sharding))
+
+
+def _long_paged(window):
+    return lambda *a: pallas_paged._paged_attention_call(
+        *a, interpret=False, window=window)
+
+
+def _long_prefill(window):
+    from horovod_tpu.ops.pallas_attention import flash_prefill
+    return lambda *a: flash_prefill(*a, window=window)
+
+
+_LONG_ENTRY_POINTS = [
+    (f"{name}-{'full' if w is None else 'window'}", fn(w), args)
+    for name, fn, args in (("paged", _long_paged, _long_paged_args),
+                           ("flash_prefill", _long_prefill,
+                            _long_prefill_args))
+    for w in (None, _LONG["window"])]
+_LONG_IDS = [name for name, _, _ in _LONG_ENTRY_POINTS]
+
+
+@pytest.mark.parametrize("name,fn,make_args", _LONG_ENTRY_POINTS,
+                         ids=_LONG_IDS)
+def test_long_context_kernel_lowers_for_tpu(name, fn, make_args):
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*make_args())
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+@pytest.mark.parametrize("name,fn,make_args", _LONG_ENTRY_POINTS,
+                         ids=_LONG_IDS)
+def test_mosaic_accepts_long_context_kernel(v5e, name, fn, make_args):
+    _aot_compile(jax.jit(fn), make_args(SingleDeviceSharding(v5e[0])))
+
+
+def test_window_kernel_asks_for_less_vmem_than_the_full_one():
+    c = _LONG
+    group = c["H"] // c["KV"]
+    full = pallas_paged._vmem_limit_bytes(
+        group, c["KV"], c["D"], c["block"], c["entries"], 2)
+    entries = pallas_paged.window_entries(c["window"], 1, c["block"],
+                                          c["entries"])
+    windowed = pallas_paged._vmem_limit_bytes(
+        group, c["KV"], c["D"], c["block"], entries, 2)
+    assert entries == 33 and windowed < full / 2
+    assert 40 << 20 < full < 100 << 20      # the chip has 128 MiB
+
+
+@pytest.mark.parametrize("tokens", [16, 2048])
+def test_mosaic_accepts_the_grouped_expert_matmul(v5e, tokens):
+    """Dropless top-6 of 64 gated experts of width 768 over a 2560-wide
+    stream (`parallel/ep.py routed_experts` over jax's Pallas grouped
+    matmul), at a decode step's 16 tokens and a short prefill's 2,048."""
+    from horovod_tpu.parallel import ep
+    sh = SingleDeviceSharding(v5e[0])
+    E, K, D, F = 64, 6, 2560, 768
+    args = (_sds((tokens, D), jnp.bfloat16, sh),
+            _sds((tokens, K), jnp.int32, sh),
+            _sds((tokens, K), jnp.float32, sh), _sds((tokens,), bool, sh),
+            _sds((E, D, 2 * F), jnp.bfloat16, sh),
+            _sds((E, F, D), jnp.bfloat16, sh))
+    compiled = _aot_compile(
+        jax.jit(lambda *a: ep.routed_experts(*a, impl="gmm")), args)
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 2
