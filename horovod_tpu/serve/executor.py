@@ -10,7 +10,7 @@ iteration granularity without ever invalidating the jit cache — the
 no-recompile contract the continuous batcher (serve/batcher.py) is
 built on.
 
-Two decisions are frozen at build time so the jit cache stays flat:
+Three decisions are frozen at build time so the jit cache stays flat:
 
 * **Kernel**: paged executors resolve the decode-attention kernel ONCE
   (``HOROVOD_SERVE_KERNEL`` via `ops.pallas_paged.resolve_kernel` —
@@ -28,6 +28,15 @@ Two decisions are frozen at build time so the jit cache stays flat:
   speculative verify step applies the rejection-sampling accept rule
   on device (`ops.pallas_paged.speculative_accept`), returning the
   emitted tokens instead of raw argmaxes.
+
+* **Resident dtypes**: each parameter is held in the dtype the step
+  multiplies it in — where the model's own trace shows a leaf only
+  ever converted to one narrower float dtype (float32 `Dense` kernels
+  and biases under bfloat16 compute), the constructor and
+  `swap_params` make that conversion once, in one program, and the
+  step reads half the bytes; every other leaf (norms, embeddings, a
+  float32 head, a checkpoint already in its compute dtype) is held as
+  given. The caller's tree is not consumed.
 
 Sharding rides the training stack unchanged: pass `mesh` plus the
 model's `PartitionRules` (parallel/tp.py) and parameters are placed with
@@ -58,6 +67,36 @@ from ..obs import metrics as obs_metrics
 from ..trace.spans import get_recorder as _trace_recorder
 
 logger = logging.getLogger("horovod_tpu")
+
+
+def _resident_dtypes(jaxpr, given: list) -> list:
+    """The dtype each parameter leaf is HELD in, read off the traced
+    forward: a leaf whose every use is a ``convert_element_type`` to one
+    narrower floating dtype is held in that dtype (the cast moves from
+    every step to once; each later operation receives the same rounded
+    number), every other leaf as given. ``jaxpr.invars`` starts with the
+    parameter leaves in flatten order. A leaf consumed by anything else
+    (a nested call included, whatever that call does with it) or
+    returned as is keeps its dtype: the rule errs towards not casting."""
+    uses = {id(v): set() for v in jaxpr.invars[:len(given)]}
+    for v in jaxpr.outvars:
+        if id(v) in uses:
+            uses[id(v)].add(None)
+    for eqn in jaxpr.eqns:
+        to = eqn.params.get("new_dtype") \
+            if eqn.primitive is jax.lax.convert_element_type_p else None
+        for v in eqn.invars:
+            if id(v) in uses:
+                uses[id(v)].add(to)
+    held = []
+    for v, dt in zip(jaxpr.invars, given):
+        (to,) = uses[id(v)] if len(uses[id(v)]) == 1 else (None,)
+        narrower = to is not None and \
+            jnp.issubdtype(dt, jnp.floating) and \
+            jnp.issubdtype(to, jnp.floating) and \
+            jnp.dtype(to).itemsize < jnp.dtype(dt).itemsize
+        held.append(jnp.dtype(to) if narrower else dt)
+    return held
 
 
 class ShardedExecutor:
@@ -123,10 +162,10 @@ class ShardedExecutor:
         # params are placed exactly like the originals
         self._mesh = mesh
         self._rules = partition_rules
-        if mesh is not None and partition_rules is not None:
+        placed = mesh is not None and partition_rules is not None
+        if placed:
             from ..parallel.tp import shard_params
             params = shard_params(params, mesh, partition_rules)
-        self.params = params
         # the swap/version fence: step() holds this lock for the whole
         # forward, swap_params() takes it to replace self.params — a
         # swap can therefore land only BETWEEN decode iterations, never
@@ -266,24 +305,60 @@ class ShardedExecutor:
         self._fwd_token = jax.jit(fwd_token, donate_argnums=donate)
         self._fwd_verify = jax.jit(fwd_verify, donate_argnums=donate)
 
-        # materialize the zero cache once (a separate cache-creating
-        # trace; steady-state steps all go through _fwd_token/_fwd_verify)
+        # -- the resident state, from ONE trace of the model and no
+        # program that holds its forward: the trace says which dtype
+        # each parameter is multiplied in (`_resident_dtypes`) and what
+        # the cache collection looks like; every model creates that
+        # collection as zeros, so shapes and dtypes are all of it
         def make_cache(params, tokens, positions, mask, tables):
             kw = {"block_tables": tables} if self.paged else {}
             _, v = self.model.apply(
                 {"params": params}, tokens, positions=positions,
                 update_mask=mask, mutable=["cache", "stats"], **kw)
-            return v["cache"], "stats" in v
+            return v
 
-        z = jnp.zeros((max_batch, 1), jnp.int32)
-        zt = jnp.full((max_batch, max(self.blocks_per_seq, 1)), -1,
-                      jnp.int32)
-        #: does the model sow step counters (see `with_stats`)? A plain
-        #: bool out of the trace: the collection is there or it is not
-        self.cache, has_stats = jax.jit(make_cache)(
-            params, z, jnp.zeros((max_batch,), jnp.int32),
-            jnp.zeros((max_batch,), bool), zt)
-        self._has_stats = bool(has_stats)
+        S = jax.ShapeDtypeStruct
+        traced, collections = jax.make_jaxpr(make_cache, return_shape=True)(
+            params, S((max_batch, 1), jnp.int32), S((max_batch,), jnp.int32),
+            S((max_batch,), bool),
+            S((max_batch, max(self.blocks_per_seq, 1)), jnp.int32))
+        #: does the model sow step counters (see `with_stats`)? The
+        #: collection is there or it is not
+        self._has_stats = "stats" in collections
+        leaves, self._treedef = jax.tree_util.tree_flatten(params)
+        # the zeros go where the parameters are: replicated over a mesh
+        # (the Megatron serving layout), else COMMITTED to the device
+        # the caller committed its tree to, as the cache every step
+        # hands back will be (committed or not is in jit's signature)
+        where = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec()) if placed else next(
+            (x.sharding for x in leaves if getattr(x, "committed", False)),
+            None)
+        self.cache = jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype, device=where),
+            collections["cache"])
+
+        # parameters are HELD in the dtype the step multiplies them in:
+        # cast here and in `swap_params` by one program over the leaves
+        # that change (the others ARE the caller's arrays), never inside
+        # the step. The caller's arrays are not donated: a fleet builds
+        # several replicas from one tree.
+        self._given_dtypes = [jnp.dtype(x.dtype) for x in leaves]
+        self._resident_dtypes = _resident_dtypes(
+            traced.jaxpr, self._given_dtypes)
+        self._cast_idx = [i for i, (g, r) in enumerate(zip(
+            self._given_dtypes, self._resident_dtypes)) if g != r]
+        to = [self._resident_dtypes[i] for i in self._cast_idx]
+
+        def resident_cast(xs):
+            return [x.astype(d) for x, d in zip(xs, to)]
+
+        # a `shard_params` placement survives the cast
+        self._cast = jax.jit(
+            resident_cast, out_shardings=[
+                leaves[i].sharding for i in self._cast_idx]
+            if placed else None)
+        self.params = self._to_resident(params)
 
         if self.paged:
             # CoW block copy, jitted once (shapes are static): donation
@@ -306,12 +381,19 @@ class ShardedExecutor:
         #: inside the step lock) — what lets the batcher detect a swap
         #: landing between its prefix-cache lookup and the prefill
         self.last_step_version: Optional[int] = None
-        # one-shot KERNEL instant: names the RESOLVED decode kernel so
-        # a silent fallback to XLA on TPU is visible in the trace
+        # the bytes given and held are on the log line, so a silent
+        # fall-back to "nothing cast" shows in a run's log
+        nbytes = [sum(int(np.prod(x.shape)) * d.itemsize
+                      for x, d in zip(leaves, dts))
+                  for dts in (self._given_dtypes, self._resident_dtypes)]
         logger.info(
             "serve executor (replica=%s role=%s): decode kernel=%s "
-            "paged=%s backend=%s", replica_id, role, self.kernel,
-            self.paged, jax.default_backend())
+            "paged=%s backend=%s; resident bytes %d -> %d, %d of %d "
+            "leaves cast", replica_id, role, self.kernel, self.paged,
+            jax.default_backend(), *nbytes, len(self._cast_idx),
+            len(leaves))
+        # one-shot KERNEL instant: names the RESOLVED decode kernel so
+        # a silent fallback to XLA on TPU is visible in the trace
         if self.timeline is not None:
             self.timeline.instant("KERNEL", {
                 "kernel": self.kernel, "paged": self.paged,
@@ -451,6 +533,18 @@ class ShardedExecutor:
             return nxt, probs
         return nxt
 
+    def _to_resident(self, params: Any) -> Any:
+        """``params`` in the given dtypes -> the tree the step consumes:
+        the leaves `_resident_dtypes` narrows go through the one cast
+        program, the others are passed on as they are."""
+        if not self._cast_idx:
+            return params
+        leaves = jax.tree_util.tree_leaves(params)
+        cast = self._cast([leaves[i] for i in self._cast_idx])
+        for i, x in zip(self._cast_idx, cast):
+            leaves[i] = x
+        return jax.tree_util.tree_unflatten(self._treedef, leaves)
+
     # -- hot weight swap (redist/stream.py consumer) -------------------------
     def swap_params(self, new_params: Any, *,
                     version: Optional[int] = None) -> bool:
@@ -462,32 +556,32 @@ class ShardedExecutor:
         a ``version`` at or below the current one is refused (returns
         False) so out-of-order polls across replicas can never roll
         weights backwards. The structure must match the serving params
-        exactly (same treedef/shapes); placement (mesh + partition
-        rules) mirrors the constructor.
+        exactly (same treedef/shapes), in the dtypes the constructor
+        was GIVEN (a publisher of float32 master weights need not know
+        the serving dtype: the executor casts, and does not consume the
+        caller's arrays) or in the RESIDENT dtypes; placement (mesh +
+        partition rules) mirrors the constructor.
 
         Returns True on adoption; observes ``hvd_weight_swap_ms`` and
         emits a SWAP timeline instant."""
-        import jax
-
         t0 = time.perf_counter()
         if version is not None and self.params_version is not None \
                 and version <= self.params_version:
             return False
-        old_leaves, old_def = jax.tree_util.tree_flatten(self.params)
+        old_leaves = jax.tree_util.tree_leaves(self.params)
         new_leaves, new_def = jax.tree_util.tree_flatten(new_params)
-        if old_def != new_def or any(
+        # .dtype without np.asarray: materializing device arrays to
+        # host just to read their dtype would cost an O(model) transfer
+        # per swap (and raise on multi-host GSPMD leaves)
+        dtypes = [getattr(x, "dtype", None) for x in new_leaves]
+        if new_def != self._treedef or any(
                 np.shape(a) != np.shape(b)
-                # .dtype without np.asarray: materializing device
-                # arrays to host just to read their dtype would cost an
-                # O(model) transfer per swap (and raise on multi-host
-                # GSPMD leaves)
-                or getattr(a, "dtype", None) != getattr(b, "dtype",
-                                                        None)
-                for a, b in zip(old_leaves, new_leaves)):
-            # dtype is part of the jitted step's signature: adopting
-            # fp32 master weights into a bf16 executor would not error
-            # — it would recompile EVERY bucket mid-traffic. Fail fast
-            # instead; the publisher must cast to the serving dtype.
+                for a, b in zip(old_leaves, new_leaves)) \
+                or dtypes not in (self._given_dtypes,
+                                  self._resident_dtypes):
+            # dtype is part of the jitted step's signature: adopting a
+            # tree in any other dtype would not error — it would
+            # recompile EVERY bucket mid-traffic. Fail fast instead.
             raise ValueError(
                 "swap_params: replacement tree does not match the "
                 "serving params (treedef/shape/dtype mismatch) — "
@@ -499,6 +593,12 @@ class ShardedExecutor:
                                       self._rules)
         else:
             new_params = jax.tree_util.tree_map(jnp.asarray, new_params)
+        if dtypes != self._resident_dtypes:
+            # the constructor's program, outside the step lock; waited
+            # for, so the swap's time holds the cast and the given tree
+            # can be let go when this returns
+            new_params = jax.block_until_ready(
+                self._to_resident(new_params))
         with self._swap_lock:
             # re-check under the lock: another subscriber thread may
             # have adopted a newer version while we placed this one

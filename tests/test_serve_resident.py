@@ -1,0 +1,371 @@
+"""`ShardedExecutor` holds each parameter in the dtype the step
+multiplies it in (serve/executor.py, "Resident dtypes"): what is cast
+and what is not, that the numbers are the ones the float32 tree gives,
+`swap_params`' dtype contract, and that the constructor builds its
+state from one trace of the model and no program that holds its
+forward. Single process, CPU; Pallas in interpret mode."""
+import logging
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.models.gpt import GPT, GPTConfig
+from horovod_tpu.models.routed_lm import RoutedLM, RoutedLMConfig
+from horovod_tpu.serve import ShardedExecutor
+
+_KW = dict(vocab_size=64, num_layers=2, num_heads=2, head_dim=8,
+           max_seq_len=48, attention_impl="reference")
+_PAGED = dict(decode=True, kv_block_size=4, kv_pool_blocks=24)
+_ROWS, _MAX_LEN, _BUCKET, _DECODES = 2, 48, 8, 6
+_PROMPTS = [[5, 9, 2, 41, 7], [11, 3, 60, 8, 1, 33, 2]]
+
+
+def _gpt_params(**kw):
+    return GPT(GPTConfig(**dict(_KW, **kw))).init(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))["params"]
+
+
+def _gpt_executor(params, kernel="xla", **kw):
+    model = GPT(GPTConfig(decode_kernel=kernel, **_PAGED,
+                          **dict(_KW, **kw)))
+    return ShardedExecutor(model, params, max_batch=_ROWS,
+                           max_len=_MAX_LEN)
+
+
+def _routed():
+    cfg = RoutedLMConfig(vocab_size=64, num_layers=2, embed_dim=32,
+                         num_heads=2, head_dim=16, num_experts=4,
+                         experts_per_token=2, expert_dim=16, window=8,
+                         max_seq_len=_MAX_LEN, decode_kernel="xla",
+                         kv_block_size=4, kv_pool_blocks=24)
+    model = RoutedLM(cfg)
+    z = jnp.zeros((_ROWS,), jnp.int32)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((_ROWS, 1), jnp.int32),
+        positions=z, update_mask=jnp.zeros((_ROWS,), bool),
+        block_tables=jnp.full((_ROWS, 12), -1, jnp.int32))["params"]
+    # a checkpoint published in bfloat16: every leaf, the norms' too
+    return model, jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16), params)
+
+
+def _tables(ex):
+    n = ex.blocks_per_seq
+    return np.arange(_ROWS * n, dtype=np.int32).reshape(_ROWS, n)
+
+
+def _prompt_batch():
+    """`_PROMPTS` padded to the bucket, and each row's length."""
+    tokens = np.zeros((_ROWS, _BUCKET), np.int32)
+    for r, p in enumerate(_PROMPTS):
+        tokens[r, :len(p)] = p
+    return tokens, np.array([len(p) for p in _PROMPTS], np.int32)
+
+
+def _first_step(ex):
+    """One prefill of `_PROMPTS` through `ex.step`: the greedy tokens."""
+    tokens, lengths = _prompt_batch()
+    return ex.step(tokens, np.zeros(_ROWS, np.int32),
+                   np.ones(_ROWS, bool), lengths - 1, kind="prefill",
+                   block_tables=_tables(ex))
+
+
+# -- (a) the numbers are the float32 tree's -----------------------------------
+
+def _drive(ex, params):
+    """A prefill and `_DECODES` greedy decode steps of `ex.model` over
+    `params`, by `model.apply` (float32 logits out): what an executor
+    that kept the tree as given computes. -> {kind: (logits, tokens)}"""
+    tables = jnp.asarray(_tables(ex))
+
+    @jax.jit
+    def apply(cache, tokens, positions, last_idx):
+        logits, v = ex.model.apply(
+            {"params": params, "cache": cache}, tokens,
+            positions=positions, update_mask=jnp.ones((_ROWS,), bool),
+            logits_idx=last_idx, block_tables=tables, mutable=["cache"])
+        return logits[:, 0], v["cache"]
+
+    tokens, lengths = _prompt_batch()
+    cache = jax.tree_util.tree_map(jnp.zeros_like, ex.cache)
+    logits, cache = apply(cache, jnp.asarray(tokens),
+                          jnp.zeros((_ROWS,), jnp.int32),
+                          jnp.asarray(lengths - 1))
+    out = {"prefill": ([np.asarray(logits)],
+                       [np.asarray(logits).argmax(-1)])}
+    dl, dt = [], []
+    for i in range(_DECODES):
+        nxt = np.asarray(logits).argmax(-1).astype(np.int32)
+        logits, cache = apply(cache, jnp.asarray(nxt[:, None]),
+                              jnp.asarray(lengths + i),
+                              jnp.zeros((_ROWS,), jnp.int32))
+        dl.append(np.asarray(logits))
+        dt.append(np.asarray(logits).argmax(-1))
+    out["decode"] = (dl, dt)
+    return out
+
+
+def _serve(ex):
+    """The same prefill and decode steps through `ex.step`."""
+    nxt = _first_step(ex)
+    out = {"prefill": [nxt], "decode": []}
+    _, lengths = _prompt_batch()
+    for i in range(_DECODES):
+        nxt = ex.step(nxt[:, None].astype(np.int32), lengths + i,
+                      np.ones(_ROWS, bool), np.zeros(_ROWS, np.int32),
+                      kind="decode", block_tables=_tables(ex))
+        out["decode"].append(nxt)
+    return out
+
+
+@pytest.fixture(scope="module")
+def driven():
+    """Per kernel: the given float32 tree and the resident tree through
+    `model.apply`, and the executor's own steps."""
+    given = _gpt_params()
+    out = {}
+    for kernel in ("xla", "pallas"):
+        ex = _gpt_executor(given, kernel)
+        assert ex._cast_idx, "nothing was cast: the test compares a " \
+            "tree with itself"
+        out[kernel] = SimpleNamespace(given=_drive(ex, given),
+                                      resident=_drive(ex, ex.params),
+                                      served=_serve(ex))
+    return out
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_resident_tree_gives_the_float32_trees_numbers(driven, kind, kernel):
+    d = driven[kernel]
+    for got, want in zip(d.resident[kind][0], d.given[kind][0]):
+        assert got.dtype == np.float32
+        np.testing.assert_array_equal(got, want)       # bit for bit
+    for served, want in zip(d.served[kind], d.given[kind][1]):
+        np.testing.assert_array_equal(served, want)
+
+
+# -- (b) which leaves are held in which dtype ---------------------------------
+
+_GROUPS = {
+    "dense_kernels": (lambda p: p[-1] == "kernel" and p[-2] != "lm_head",
+                      jnp.bfloat16),
+    "dense_biases": (lambda p: p[-1] == "bias" and not p[-2].startswith("ln"),
+                     jnp.bfloat16),
+    "layernorm": (lambda p: p[-2].startswith("ln"), jnp.float32),
+    "embed": (lambda p: p[0] == "embed", jnp.float32),
+    "pos_embed": (lambda p: p[0] == "pos_embed", jnp.float32),
+    "lm_head": (lambda p: p[0] == "lm_head", jnp.float32),
+}
+
+
+def _by_path(tree):
+    return {tuple(k.key for k in path): leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+@pytest.fixture(scope="module")
+def gpt_bf16():
+    given = _gpt_params()
+    return given, _gpt_executor(given)
+
+
+@pytest.mark.parametrize("group", list(_GROUPS))
+def test_gpt_resident_dtype_by_leaf_group(gpt_bf16, group):
+    given, ex = gpt_bf16
+    member, dtype = _GROUPS[group]
+    held, was = _by_path(ex.params), _by_path(given)
+    paths = [p for p in held if member(p)]
+    assert paths
+    for p in paths:
+        assert was[p].dtype == jnp.float32
+        assert held[p].dtype == dtype, p
+        if dtype == jnp.float32:
+            assert held[p] is was[p]            # passed on, not copied
+        else:
+            np.testing.assert_array_equal(
+                np.asarray(held[p]), np.asarray(was[p].astype(dtype)))
+    assert set().union(*(
+        {p for p in held if m(p)} for m, _ in _GROUPS.values())) == set(held)
+
+
+def _same_arrays(a, b):
+    return all(x is y for x, y in zip(jax.tree_util.tree_leaves(a),
+                                      jax.tree_util.tree_leaves(b)))
+
+
+def test_float32_compute_casts_nothing():
+    given = _gpt_params(dtype=jnp.float32)
+    ex = _gpt_executor(given, dtype=jnp.float32)
+    assert ex._cast_idx == [] and _same_arrays(ex.params, given)
+
+
+def test_bfloat16_checkpoint_is_held_as_given():
+    """Every `RoutedLM` leaf arrives in the compute dtype, and the
+    router and the head are WIDENED at use: nothing to cast."""
+    model, given = _routed()
+    assert {x.dtype for x in jax.tree_util.tree_leaves(given)} == \
+        {jnp.dtype(jnp.bfloat16)}
+    ex = ShardedExecutor(model, given, max_batch=_ROWS, max_len=_MAX_LEN)
+    assert ex._cast_idx == [] and _same_arrays(ex.params, given)
+
+
+# -- (c) swap_params' dtype contract ------------------------------------------
+
+def _other(tree):
+    return jax.tree_util.tree_map(lambda x: x + 0.1 * jnp.sign(x + 0.5), tree)
+
+
+@pytest.fixture()
+def swapping():
+    given = _gpt_params()
+    ex = _gpt_executor(given)
+    _first_step(ex)
+    return given, ex
+
+
+def _swap_given(given, ex):
+    assert ex.swap_params(_other(given), version=2) is True
+    want = _gpt_executor(_other(given))
+    for a, b in zip(jax.tree_util.tree_leaves(ex.params),
+                    jax.tree_util.tree_leaves(want.params)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _swap_resident(given, ex):
+    tree = _gpt_executor(_other(given)).params
+    assert ex.swap_params(tree, version=2) is True
+    assert _same_arrays(ex.params, tree)
+
+
+def _swap_wrong_shape(given, ex):
+    bad = jax.tree_util.tree_map(lambda x: x, given)
+    bad["lm_head"]["kernel"] = jnp.zeros((16, 65), jnp.float32)
+    with pytest.raises(ValueError, match="shape"):
+        ex.swap_params(bad, version=2)
+
+
+def _swap_foreign_dtype(given, ex):
+    f16 = jax.tree_util.tree_map(lambda x: x.astype(jnp.float16), given)
+    with pytest.raises(ValueError, match="dtype"):
+        ex.swap_params(f16, version=2)
+    mixed = jax.tree_util.tree_map(lambda x: x, given)     # half-cast
+    mixed["lm_head"]["kernel"] = given["lm_head"]["kernel"].astype(
+        jnp.bfloat16)
+    with pytest.raises(ValueError, match="dtype"):
+        ex.swap_params(mixed, version=2)
+
+
+def _swap_stale_version(given, ex):
+    assert ex.swap_params(given, version=5) is True
+    assert ex.swap_params(_other(given), version=5) is False
+    assert ex.swap_params(_other(given), version=4) is False
+    assert ex.params_version == 5 and ex.swaps == 1
+
+
+def _swap_keeps_the_jit_cache_flat(given, ex):
+    before = ex.jit_cache_size()
+    first = _first_step(ex)
+    ex.swap_params(_other(given), version=2)           # given dtypes
+    ex.swap_params(_gpt_executor(given).params, version=3)   # resident
+    assert ex.jit_cache_size() == before
+    np.testing.assert_array_equal(_first_step(ex), first)
+    assert ex.jit_cache_size() == before
+    assert ex._cast._cache_size() == 1      # the constructor's program
+
+
+@pytest.mark.parametrize("case", [
+    _swap_given, _swap_resident, _swap_wrong_shape, _swap_foreign_dtype,
+    _swap_stale_version, _swap_keeps_the_jit_cache_flat],
+    ids=lambda f: f.__name__.lstrip("_"))
+def test_swap_params(swapping, case):
+    case(*swapping)
+
+
+# -- (d) the constructor: one trace, no forward compiled ----------------------
+
+@pytest.fixture()
+def compiled_names(caplog):
+    """-> a function: the names of the programs compiled since it was
+    last called (jax logs each at WARNING under `jax_log_compiles`)."""
+    seen = [0]
+
+    def since():
+        new, seen[0] = caplog.records[seen[0]:], len(caplog.records)
+        return [r.getMessage().split()[1] for r in new
+                if r.getMessage().startswith("Compiling ")]
+    with jax.log_compiles(True), caplog.at_level(logging.WARNING, "jax"):
+        yield since
+
+
+def test_constructor_compiles_no_forward(compiled_names, monkeypatch):
+    given = _gpt_params()
+    calls = []
+    inner = GPT.__call__
+    monkeypatch.setattr(
+        GPT, "__call__",
+        lambda self, *a, **k: (calls.append(1), inner(self, *a, **k))[1])
+    compiled_names()
+    ex = _gpt_executor(given, "pallas")
+    names = compiled_names()
+    assert len(calls) == 1                  # the model is traced ONCE
+    # the one cast, and eager `jnp.zeros` (a broadcast) where this
+    # process has not made such zeros before: nothing that could hold a
+    # matmul, and no cache-making forward
+    assert "jit(resident_cast)" in names, names
+    assert set(names) <= {"jit(resident_cast)", "jit(broadcast_in_dim)"}, \
+        names
+    assert ex.jit_cache_size() == 0
+
+
+@pytest.mark.parametrize("arch", ["gpt", "routed"])
+def test_cache_is_the_zeros_a_forward_would_make(arch):
+    if arch == "gpt":
+        params = _gpt_params()
+        ex = _gpt_executor(params)
+        model = ex.model
+    else:
+        model, params = _routed()
+        ex = ShardedExecutor(model, params, max_batch=_ROWS,
+                             max_len=_MAX_LEN)
+    z = jnp.zeros((_ROWS,), jnp.int32)
+    _, made = jax.jit(lambda p: model.apply(
+        {"params": p}, jnp.zeros((_ROWS, 1), jnp.int32), positions=z,
+        update_mask=jnp.zeros((_ROWS,), bool),
+        block_tables=jnp.full((_ROWS, ex.blocks_per_seq), -1, jnp.int32),
+        mutable=["cache"]))(params)
+    want = jax.tree_util.tree_flatten_with_path(made["cache"])[0]
+    got = jax.tree_util.tree_flatten_with_path(ex.cache)[0]
+    assert [p for p, _ in got] == [p for p, _ in want] and got
+    for (_, a), (_, b) in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert not np.asarray(a).any() and not np.asarray(b).any()
+    assert ex._has_stats is (arch == "routed")
+
+
+# -- (e) the caller's tree is not consumed ------------------------------------
+
+def test_two_executors_from_one_given_tree_both_step():
+    given = _gpt_params()
+    a, b = _gpt_executor(given), _gpt_executor(given)
+    np.testing.assert_array_equal(_first_step(a), _first_step(b))
+    assert not any(x.is_deleted()
+                   for x in jax.tree_util.tree_leaves(given))
+    assert _gpt_executor(given)._cast_idx       # and a third can be built
+
+
+def test_a_committed_tree_gets_a_committed_cache():
+    """Committed or not is in jit's signature: the zero cache of a tree
+    the caller put on a device must be committed there like the cache
+    every step hands back, or the second step is a second program."""
+    given = jax.device_put(_gpt_params(), jax.devices()[0])
+    ex = _gpt_executor(given)
+    assert all(x.committed for x in jax.tree_util.tree_leaves(ex.cache))
+    _first_step(ex)
+    once = ex.jit_cache_size()
+    _first_step(ex)
+    assert ex.jit_cache_size() == once == 1

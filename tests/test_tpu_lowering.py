@@ -271,3 +271,47 @@ def test_mosaic_accepts_the_grouped_expert_matmul(v5e, tokens):
         jax.jit(lambda *a: ep.routed_experts(*a, impl="gmm")), args)
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
         == 2
+
+
+def test_decode_step_reads_no_float32_matmul_kernel(v5e, monkeypatch):
+    """The decode step of `ShardedExecutor` at GPT-2 XL widths (2
+    layers, 8 rows, the Pallas kernel), compiled with the tree the
+    executor HOLDS: the four `Dense` kernels of a block enter the
+    program in the compute dtype, so no step reads them in float32
+    (serve/executor.py, "Resident dtypes")."""
+    from horovod_tpu.models.gpt import GPT, GPTConfig
+    from horovod_tpu.serve import ShardedExecutor
+    kw = dict(vocab_size=256, num_layers=2, num_heads=25, head_dim=64,
+              max_seq_len=640)
+    params = jax.jit(lambda k: GPT(GPTConfig(**kw)).init(
+        k, jnp.zeros((1, 8), jnp.int32))["params"])(jax.random.PRNGKey(0))
+    # the model asks the default backend (the CPU, here) whether to
+    # interpret the kernel: steer it from the test
+    monkeypatch.setattr(
+        pallas_paged, "paged_attention_fused", functools.partial(
+            pallas_paged.paged_attention_fused, interpret=False))
+    rows, block = 8, 16
+    ex = ShardedExecutor(
+        GPT(GPTConfig(decode=True, kv_block_size=block,
+                      kv_pool_blocks=pool_blocks_for(rows, 640, block),
+                      decode_kernel="pallas", **kw)),
+        params, max_batch=rows, max_len=640)
+    sh = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _sds(x.shape, x.dtype, sh), tree)
+    i32 = functools.partial(_sds, dtype=jnp.int32, sharding=sh)
+    f32 = _sds((rows,), jnp.float32, sh)
+    compiled = _aot_compile(ex._fwd_token, [
+        on_chip(ex.params), on_chip(ex.cache), i32((rows, 1)), i32((rows,)),
+        _sds((rows,), bool, sh), i32((rows,)), f32, f32,
+        _sds((rows,), jnp.uint32, sh), i32((rows,)),
+        i32((rows, ex.blocks_per_seq))])
+    entry = next(line for line in compiled.as_text().splitlines()
+                 if "entry_computation_layout" in line)
+    for shape in ("[1600,4800]", "[1600,1600]", "[1600,6400]",
+                  "[6400,1600]"):
+        assert "f32" + shape not in entry, shape
+        assert entry.count("bf16" + shape) == 2, shape       # one a layer
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
