@@ -19,19 +19,11 @@ inception3 — the reference's full headline scaling trio
 obs registry's histogram into the summary line and prints the end-of-run
 registry snapshot as a second JSON line (docs/metrics.md).
 
-`--serve` runs the serving ACCEPTANCE GATE (slotted vs paged+prefix vs
-speculative over a shared-system-prompt overload burst; bit-identity,
-paged-speedup bar (HVD_BENCH_SERVE_SPEEDUP_BAR, default 1.25 since the
-round-6 last_idx baseline speedup), token-bounded KV,
-TTFT/jit-flat/spec bars all
-asserted — exit nonzero on violation — plus the decode-KERNEL bars:
-the full configuration on pallas vs xla, parity gated everywhere,
-speed gated on TPU, docs/serving.md), `--kernel-parity` the standalone
-pallas==xla token-stream gate ({GPT, Llama-GQA} x {greedy, spec,
-sampled}),
+`--kernel-parity` runs the standalone pallas==xla token-stream gate
+({GPT, Llama-GQA} x {greedy, spec, sampled}, docs/serving.md),
 `--serve-soak` the chaos-hardened fleet soak (serve_p99_under_fault_ms
 + failover_ms from a seeded crash/partition/corrupt/slow incident,
-now paged+prefix+speculative by default —
+prefix cache and speculation on by default —
 docs/serving.md), `--serve-fleet` the MULTI-PROCESS fleet loopback
 (fleet_failover_ms + degraded-capacity shed rate from real replica
 worker processes under a seeded SIGKILL + dispatch blips —
@@ -384,8 +376,7 @@ def run_disagg_benchmark() -> int:
         long_len, long_new = 24, 24
         worker = {
             "builder": "horovod_tpu.serve.worker:tiny_gpt_builder",
-            "builder_kwargs": {"seed": 0, "paged": True,
-                               "kv_pool_blocks": 48},
+            "builder_kwargs": {"seed": 0, "kv_pool_blocks": 48},
             "buckets": [8, 32], "max_queue": 64,
             "deadline_ms": 20000.0, "kv_crc": False, "spec_k": 0,
             "prefix_cache": False}
@@ -396,8 +387,7 @@ def run_disagg_benchmark() -> int:
         # for resident capacity — total chip-equivalent budget stays
         # comparable to the 2-worker colocated fleet
         prefill_worker = dict(worker, builder_kwargs={
-            "seed": 0, "paged": True, "max_batch": 8,
-            "kv_pool_blocks": 96})
+            "seed": 0, "max_batch": 8, "kv_pool_blocks": 96})
 
         def drive(router) -> dict:
             stop = threading.Event()
@@ -579,323 +569,6 @@ def run_autoscale_benchmark() -> int:
     except Exception as e:  # noqa: BLE001 — structured error, no traceback
         for metric, unit in (("autoscale_ttft_p99_outside_ms", "ms"),
                              ("autoscale_scale_events", "events")):
-            print(json.dumps({"metric": metric, "value": None,
-                              "unit": unit, "error": str(e)[-500:]}),
-                  flush=True)
-        return 1
-
-
-def run_serve_benchmark() -> int:
-    """Serving acceptance GATE (`bench.py --serve`): the ROADMAP item 2
-    bars, asserted — not just reported. One workload (a long shared
-    system prompt + short unique tails, submitted as a 2x-overload
-    burst) is driven through three configurations of the continuous
-    batcher over one tiny GPT decoder:
-
-      slotted            the PR 2 baseline layout (slots x max_len)
-      paged+prefix       HOROVOD_SERVE_KV_BLOCK + _PREFIX_CACHE on
-      paged+prefix+spec  ... + HOROVOD_SERVE_SPEC_K (drafter attached)
-
-    and the gate asserts (exit nonzero on any violation, each verdict
-    printed as a JSON line):
-
-      * bit-identical output: every configuration emits exactly the
-        slotted greedy baseline's tokens (same tokens, same stops);
-      * speedup: paged+prefix tokens/s >= HVD_BENCH_SERVE_SPEEDUP_BAR
-        (default 1.25) x slotted on this shared-prefix workload — the
-        bar was 1.5 until round 6's last_idx logits restriction sped
-        the slotted BASELINE's prefill (every absolute number
-        improved; the ratio honestly shrank);
-      * tokens/s floor: the full configuration sustains >=
-        HVD_BENCH_SERVE_TOKS_BAR tok/s per chip;
-      * memory: peak KV tokens RESIDENT in the paged pool stay under
-        a bound computed from tokens actually touched — and under the
-        slotted layout's slots x max_len worst case (which the paged
-        pool is provisioned below by construction);
-      * p99 TTFT under the 2x-overload burst <=
-        HVD_BENCH_SERVE_TTFT_P99_MS, with zero expiries/errors;
-      * jit-cache-flat: the admission churn of the overload burst adds
-        zero compiled programs after warmup in every configuration;
-      * speculation: < 0.7 target-model steps per generated token
-        (machine-independent), acceptance rate exported via obs;
-      * tracing overhead: the full configuration with the tracing
-        plane armed (a per-request context, every batcher record site
-        live) emits bit-identical tokens, stays within
-        HVD_BENCH_SERVE_TRACE_OVERHEAD (default 3%) of untraced
-        tokens/s, and adds zero compiled programs.
-
-    Keeps emitting serve_tokens_per_s / serve_p50_ms (now for the full
-    configuration) so the bench trajectory stays comparable."""
-    import numpy as np
-
-    try:
-        import jax
-        import jax.numpy as jnp
-
-        from horovod_tpu.core.config import Config
-        from horovod_tpu.models.gpt import GPT, GPTConfig
-        from horovod_tpu.obs import metrics as obs_metrics
-        from horovod_tpu.serve import (AdmissionQueue, ContinuousBatcher,
-                                       ShardedExecutor)
-
-        cfg = Config.from_env()
-        platform = jax.devices()[0].platform
-        n_req = int(os.environ.get("HVD_BENCH_SERVE_REQUESTS", "32"))
-        toks_bar = float(os.environ.get("HVD_BENCH_SERVE_TOKS_BAR", "25"))
-        # paged-vs-slotted ratio bar. Recalibrated 1.5 -> 1.25 in round
-        # 6: the last_idx logits restriction (serve/executor.py) cut
-        # the SLOTTED baseline's per-prefill cost by the whole
-        # [B, bucket, V] lm_head + argmax (~30-50% on this tiny-vocab
-        # bench model), so the ratio shrank while every absolute
-        # number improved (slotted 700->1050 tok/s class on the CPU
-        # container; paged+prefix unchanged ~1370). The absolute floor
-        # (toks_bar) and the token-bounded-KV gate still ratchet the
-        # layout's value; this bar guards the prefix cache's RELATIVE
-        # win on the shared-prompt workload.
-        speedup_bar = float(os.environ.get(
-            "HVD_BENCH_SERVE_SPEEDUP_BAR", "1.25"))
-        ttft_bar_ms = float(os.environ.get(
-            "HVD_BENCH_SERVE_TTFT_P99_MS", "5000"))
-        max_batch = cfg.serve_max_batch
-        # prefill-dominated on purpose: the speedup under test is
-        # "shared system prompts computed once", so the workload keeps
-        # the generation tail short and the shared prefix long
-        sys_len, tail_max, max_new, spec_k = 160, 8, 4, 3
-        max_len = 192
-        buckets = (8, 168)
-        # the three knobs ARE the configuration under test: block size
-        # from HOROVOD_SERVE_KV_BLOCK (default 8 for the tiny bench
-        # model), spec depth from HOROVOD_SERVE_SPEC_K, prefix cache
-        # forced on for the paged phases
-        block = cfg.serve_kv_block or 8
-        spec_k = cfg.serve_spec_k or spec_k
-        from horovod_tpu.serve import pool_blocks_for
-        pool_blocks = pool_blocks_for(cfg.serve_max_batch, max_len,
-                                      block)
-        kw = dict(vocab_size=256, num_layers=2, num_heads=4, head_dim=16,
-                  max_seq_len=max_len,
-                  dtype=jnp.bfloat16 if platform == "tpu" else jnp.float32,
-                  attention_impl=None if platform == "tpu" else "reference")
-        params = GPT(GPTConfig(**kw)).init(
-            jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))["params"]
-
-        # the workload: one long system prompt shared by every request,
-        # each with a short unique tail — the shape the radix cache is
-        # for. The PRIME request warms the prefix cache (production
-        # serves a standing system prompt); the burst is 2x-overload
-        # high concurrency: all n_req land at once on max_batch rows.
-        rng = np.random.RandomState(0)
-        system = list(rng.randint(0, 256, sys_len))
-        prompts = [system + list(rng.randint(0, 256,
-                                             rng.randint(4, tail_max + 1)))
-                   for _ in range(n_req)]
-        prime = system + list(rng.randint(0, 256, tail_max))
-
-        def drive(paged, prefix, spec, kernel="xla", traced=False):
-            from horovod_tpu.trace.context import TraceContext
-
-            def _trace():
-                # a fresh wire-form context per request: every record
-                # site in the batcher goes live, exactly the armed-
-                # tracing cost a traced fleet pays per request
-                return (TraceContext.mint().to_wire() if traced
-                        else None)
-            mcfg = GPTConfig(decode=True, **kw,
-                             kv_block_size=block if paged else 0,
-                             kv_pool_blocks=pool_blocks if paged else 0,
-                             decode_kernel=kernel if paged else None)
-            ex = ShardedExecutor(GPT(mcfg), params, max_batch=max_batch,
-                                 max_len=max_len)
-            draft = None
-            if spec:
-                draft = ShardedExecutor(
-                    GPT(GPTConfig(decode=True, **kw)), params,
-                    max_batch=max_batch, max_len=max_len, role="draft")
-            q = AdmissionQueue(max_queue=max(cfg.serve_max_queue,
-                                             n_req + 1),
-                               default_deadline_ms=cfg.serve_deadline_ms)
-            b = ContinuousBatcher(ex, q, buckets=buckets,
-                                  prefix_cache=prefix,
-                                  draft_executor=draft, spec_k=spec_k)
-            b.warmup()
-            jit0 = ex.jit_cache_size()
-            q.submit(prime, max_new_tokens=max_new, trace=_trace())
-            b.run()                      # prime: publishes the prefix run
-            # best-of-2 bursts: one shared-machine hiccup must not turn
-            # a real 2x layout win into a flaky gate verdict
-            wall, handles = None, None
-            for _ in range(2):
-                t0 = time.perf_counter()
-                hs = [q.submit(p, max_new_tokens=max_new,
-                               trace=_trace())
-                      for p in prompts]
-                b.run()
-                dt = time.perf_counter() - t0
-                bad = [h.status for h in hs if h.status != "ok"]
-                if bad:
-                    raise RuntimeError(
-                        f"burst requests failed under the gate: {bad[:5]}")
-                if wall is None or dt < wall:
-                    wall = dt
-                if handles is None:
-                    handles = hs
-            ttft = obs_metrics.get_registry().get("hvd_serve_ttft_ms")
-            return {
-                "tokens": [h.tokens for h in handles],
-                "tok_s": sum(len(h.tokens) for h in handles) / wall,
-                "p50_ms": sorted(h.latency_ms for h in handles)[
-                    len(handles) // 2],
-                "ttft_p99_ms": (ttft.percentile(0.99)
-                                if ttft is not None and ttft.count
-                                else None),
-                "jit_flat": ex.jit_cache_size() == jit0,
-                "peak_tokens": (b.kv.pool.peak_in_use * block
-                                if paged else max_batch * max_len),
-                "prefix_hits": b.prefix.hits if b.prefix else 0,
-                "tokens_saved": (b.prefix.tokens_saved
-                                 if b.prefix else 0),
-                "steps_per_token": (b.gen_steps / b.gen_tokens
-                                    if b.gen_tokens else None),
-            }
-
-        slotted = drive(False, False, False)
-        paged = drive(True, True, False)
-        full = drive(True, True, True)
-        # kernel bars: the identical full configuration on the fused
-        # Pallas kernels (compiled on TPU; interpret mode on CPU —
-        # an EMULATOR, so off-TPU the speed ratio only documents the
-        # emulation cost and the gate asserts PARITY, not speed)
-        full_pallas = drive(True, True, True, kernel="pallas")
-        # tracing armed: identical full configuration, every batcher
-        # record site live with a per-request context — the tracing
-        # plane's overhead gate (docs/tracing.md)
-        trace_bar = float(os.environ.get(
-            "HVD_BENCH_SERVE_TRACE_OVERHEAD", "0.03"))
-        full_traced = drive(True, True, True, traced=True)
-
-        accept = obs_metrics.get_registry().get(
-            "hvd_serve_spec_accept_rate")
-        speedup = paged["tok_s"] / slotted["tok_s"]
-        kernel_speedup = full_pallas["tok_s"] / full["tok_s"]
-        trace_ratio = full_traced["tok_s"] / full["tok_s"]
-        # tokens-resident bound: the shared prefix run plus each row's
-        # private tail+generation+speculative-margin blocks, with 1.5x
-        # slack for re-prefills/CoW — far under slots x max_len
-        bs = block
-        per_row = -(-(tail_max + max_new + spec_k + 1) // bs) + 1
-        token_bound = 1.5 * ((-(-len(prime) // bs)) * bs
-                             + max_batch * per_row * bs)
-        slot_bound = max_batch * max_len
-        gates = {
-            "bit_identical_paged": paged["tokens"] == slotted["tokens"],
-            "bit_identical_spec": full["tokens"] == slotted["tokens"],
-            "speedup_ge_bar": speedup >= speedup_bar,
-            "tokens_per_s_ge_bar": full["tok_s"] >= toks_bar,
-            "kv_peak_bounded_by_tokens":
-                paged["peak_tokens"] <= token_bound < slot_bound
-                and full["peak_tokens"] <= token_bound,
-            "ttft_p99_under_2x_overload":
-                full["ttft_p99_ms"] is not None
-                and full["ttft_p99_ms"] <= ttft_bar_ms,
-            "jit_cache_flat": (slotted["jit_flat"] and paged["jit_flat"]
-                               and full["jit_flat"]),
-            "spec_steps_per_token_lt_0p7":
-                full["steps_per_token"] is not None
-                and full["steps_per_token"] < 0.7,
-            "spec_accept_rate_exported":
-                accept is not None and accept.count > 0,
-            # the Pallas path must emit the identical token stream;
-            # the tokens/s ratchet is asserted only where the kernel
-            # actually compiles (TPU) — interpret mode is an emulator
-            "kernel_parity": full_pallas["tokens"] == slotted["tokens"],
-            "kernel_jit_flat": full_pallas["jit_flat"],
-            **({"kernel_speedup_ge_1": kernel_speedup >= 1.0}
-               if platform == "tpu" else {}),
-            # tracing must be free where it matters: identical
-            # tokens, tokens/s within the overhead bar, zero new
-            # compiled programs (spans never touch traced jax code)
-            "trace_bit_identical":
-                full_traced["tokens"] == slotted["tokens"],
-            "trace_overhead_within_bar":
-                trace_ratio >= 1.0 - trace_bar,
-            "trace_jit_flat": full_traced["jit_flat"],
-        }
-        common = {"platform": platform, "requests": n_req,
-                  "max_batch": max_batch, "system_prompt_len": sys_len,
-                  "max_new_tokens": max_new, "spec_k": spec_k,
-                  "kv_block": block, "kv_pool_blocks": pool_blocks}
-        if os.environ.get("HVD_BENCH_METRICS") == "1":
-            from horovod_tpu import obs
-            hist = obs.get_registry().get(
-                "hvd_serve_step_ms",
-                {"kind": "decode", "kernel": "pallas"})
-            if hist is not None and hist.count:
-                common["step_ms_p50"] = round(hist.percentile(0.50), 3)
-                common["step_ms_p99"] = round(hist.percentile(0.99), 3)
-            print(json.dumps({"metric": "metrics_snapshot",
-                              "value": obs.get_registry().snapshot()}),
-                  flush=True)
-        print(json.dumps({
-            "metric": "serve_tokens_per_s",
-            "value": round(full["tok_s"], 2), "unit": "tok/s",
-            "slotted_tokens_per_s": round(slotted["tok_s"], 2),
-            "paged_prefix_tokens_per_s": round(paged["tok_s"], 2),
-            **common}), flush=True)
-        print(json.dumps({
-            "metric": "serve_p50_ms",
-            "value": round(full["p50_ms"], 2), "unit": "ms",
-            **common}), flush=True)
-        print(json.dumps({
-            "metric": "serve_paged_speedup",
-            "value": round(speedup, 3), "unit": "x", "bar": speedup_bar,
-            "prefix_hits": paged["prefix_hits"],
-            "prefix_tokens_saved": paged["tokens_saved"],
-            **common}), flush=True)
-        print(json.dumps({
-            "metric": "serve_kv_peak_tokens",
-            "value": paged["peak_tokens"], "unit": "tokens",
-            "token_bound": int(token_bound),
-            "slots_x_max_len": slot_bound, **common}), flush=True)
-        print(json.dumps({
-            "metric": "serve_ttft_p99_ms",
-            "value": (None if full["ttft_p99_ms"] is None
-                      else round(full["ttft_p99_ms"], 1)),
-            "unit": "ms", "bar": ttft_bar_ms, **common}), flush=True)
-        print(json.dumps({
-            "metric": "serve_kernel_speedup",
-            "value": round(kernel_speedup, 3), "unit": "x",
-            "pallas_tokens_per_s": round(full_pallas["tok_s"], 2),
-            "xla_tokens_per_s": round(full["tok_s"], 2),
-            "pallas_ttft_p99_ms": (
-                None if full_pallas["ttft_p99_ms"] is None
-                else round(full_pallas["ttft_p99_ms"], 1)),
-            "xla_ttft_p99_ms": (None if full["ttft_p99_ms"] is None
-                                else round(full["ttft_p99_ms"], 1)),
-            "gated_on_speed": platform == "tpu",
-            **common}), flush=True)
-        print(json.dumps({
-            "metric": "serve_trace_overhead",
-            "value": round(1.0 - trace_ratio, 4), "unit": "fraction",
-            "bar": trace_bar,
-            "traced_tokens_per_s": round(full_traced["tok_s"], 2),
-            "untraced_tokens_per_s": round(full["tok_s"], 2),
-            **common}), flush=True)
-        print(json.dumps({
-            "metric": "serve_spec_steps_per_token",
-            "value": (None if full["steps_per_token"] is None
-                      else round(full["steps_per_token"], 3)),
-            "unit": "steps/tok", "bar": 0.7,
-            "accept_rate_samples": int(accept.count) if accept else 0,
-            **common}), flush=True)
-        print(json.dumps({"metric": "serve_gate",
-                          "value": all(gates.values()),
-                          "gates": gates, **common}), flush=True)
-        if not all(gates.values()):
-            return 1
-        return 0
-    except Exception as e:  # noqa: BLE001 — structured error, no traceback
-        for metric, unit in (("serve_tokens_per_s", "tok/s"),
-                             ("serve_p50_ms", "ms")):
             print(json.dumps({"metric": metric, "value": None,
                               "unit": unit, "error": str(e)[-500:]}),
                   flush=True)
@@ -1357,7 +1030,7 @@ def run_ckpt_benchmark() -> int:
     """Loopback checkpoint benchmark (`bench.py --ckpt`): drive the
     sharded checkpoint plane (horovod_tpu/ckpt) over a synthetic
     parameter tree and print THREE JSON metric lines consistent with
-    `--serve`/`--metrics` — ckpt_save_ms (synchronous save, submit ->
+    `--metrics` — ckpt_save_ms (synchronous save, submit ->
     durable commit), ckpt_blocking_ms (async save()'s step-loop stall:
     device sync + bounded handoff only) and ckpt_restore_ms (read ->
     full CRC-verified tree). The async/sync ratio is the tentpole's
@@ -1493,7 +1166,7 @@ def run_redist_benchmark() -> int:
     replaces, at MATCHED tree sizes — plus a serve hot-swap latency
     (`weight_swap_ms`: publish -> poll -> swap_params on a tiny GPT
     executor). Emits one JSON line per metric consistent with
-    --serve/--ckpt: redist_ms, redist_bytes_per_s, weight_swap_ms
+    --ckpt: redist_ms, redist_bytes_per_s, weight_swap_ms
     (each carrying ckpt_roundtrip_ms + in_memory_over_ckpt for the
     comparison)."""
     import shutil
@@ -1553,16 +1226,13 @@ def run_redist_benchmark() -> int:
         from horovod_tpu.serve import ShardedExecutor
 
         srv = StoreServer()
-        cfg = GPTConfig(vocab_size=256, num_layers=2, num_heads=4,
-                        head_dim=16, max_seq_len=64, decode=True,
-                        dtype=jnp.float32,
-                        attention_impl="reference")
-        model = GPT(cfg)
-        toks = jnp.zeros((2, 8), jnp.int32)
-        params = model.init(
-            jax.random.PRNGKey(0), toks,
-            positions=jnp.zeros((2,), jnp.int32),
-            update_mask=jnp.zeros((2,), bool))["params"]
+        kw = dict(vocab_size=256, num_layers=2, num_heads=4,
+                  head_dim=16, max_seq_len=64, dtype=jnp.float32,
+                  attention_impl="reference")
+        model = GPT(GPTConfig(decode=True, **kw))
+        params = GPT(GPTConfig(**kw)).init(
+            jax.random.PRNGKey(0),
+            jnp.zeros((2, 8), jnp.int32))["params"]
         ex = ShardedExecutor(model, params, max_batch=2, max_len=64)
         pub = WeightPublisher("bench", kv_addr="127.0.0.1",
                               kv_port=srv.port)
@@ -1653,9 +1323,6 @@ if __name__ == "__main__":
     elif "--kv-tier" in sys.argv or \
             os.environ.get("HVD_BENCH_KVTIER") == "1":
         sys.exit(run_kvtier_benchmark())
-    elif "--serve" in sys.argv or \
-            os.environ.get("HVD_BENCH_SERVE") == "1":
-        sys.exit(run_serve_benchmark())
     elif "--ckpt" in sys.argv or \
             os.environ.get("HVD_BENCH_CKPT") == "1":
         sys.exit(run_ckpt_benchmark())

@@ -36,10 +36,8 @@ boundaries:
   crash/slow a replica mid-decode (crash kills the replica's scheduler
   THREAD, not the process — the in-process replica-loss analog),
   ``serve.kv`` corrupt (one live sequence's device cache bytes
-  bit-flipped — a slot row under the slotted layout, a BLOCK of the
-  paged pool under the paged one; the crc-on-write option, per-slot
-  or per-block respectively, must catch it before a client sees
-  output), ``serve.route`` partition (the router's dispatches to one
+  bit-flipped, in a BLOCK of the pool; the per-block crc-on-write
+  option must catch it before a client sees output), ``serve.route`` partition (the router's dispatches to one
   replica are refused for the window), ``serve.admit`` delay/drop at
   the queue door. Serve faults address replicas via ``peer``; guards
   pass the replica-local invocation counter explicitly.

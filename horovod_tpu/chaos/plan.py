@@ -191,9 +191,9 @@ class Fault:
     seconds: Optional[float] = None
     peer: Optional[int] = None
     shard: Optional[int] = None
-    #: serve.kv corrupt only: the KV slot (slotted layout) or batch
-    #: row (paged layout — the flip lands in that row's newest block)
-    #: to hit; default: the lowest live slot/row at fire time
+    #: serve.kv corrupt only: the batch row to hit (the flip lands in
+    #: that row's newest block); default: the lowest live row at fire
+    #: time
     slot: Optional[int] = None
     epoch: Optional[int] = None
     #: flaky only: per-crossing drop probability in (0, 1], drawn from

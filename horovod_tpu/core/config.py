@@ -201,19 +201,10 @@ class Config:
     # device->host readback per step plus one prefix readback per
     # retiring request. Off by default; the serving soak forces it on.
     serve_kv_crc: bool = False
-    # Paged KV block size in tokens (HOROVOD_SERVE_KV_BLOCK): 0 keeps
-    # the slotted [slots, max_seq_len] cache layout; > 0 switches
-    # decode-mode models to vLLM-style block-pool storage
-    # (serve/kv_cache.py BlockPool/PagedKVCache) where occupancy is
-    # bounded by tokens resident, not slots x max_seq_len. The model
-    # config (kv_block_size/kv_pool_blocks) is what actually shapes the
-    # device arrays; this knob is the serving default the helpers read.
-    serve_kv_block: int = 0
     # Radix prefix cache over prompt token ids (HOROVOD_SERVE_PREFIX_
     # CACHE): shared system prompts map to refcounted read-only block
     # runs, so a cached prefix copies block references instead of
-    # recomputing attention. Paged-only (the slotted layout has no
-    # shareable unit); flushed on every weight-version swap.
+    # recomputing attention. Flushed on every weight-version swap.
     serve_prefix_cache: bool = True
     # Paged decode attention kernel (HOROVOD_SERVE_KERNEL): "pallas"
     # runs the fused block-table-aware Pallas kernels
@@ -246,7 +237,7 @@ class Config:
     # conversations promote them back through the crc-gated
     # version-fenced install path, and the fleet routers steer
     # prefix-heavy requests to the replica holding the longest cached
-    # run. Paged + prefix-cache only; off by default.
+    # run. With the prefix cache off the knob is inert; off by default.
     serve_kvtier: bool = False
     # Host-RAM ring bound for demoted KV blocks, in MiB per replica
     # (HOROVOD_SERVE_KVTIER_HOST_MB). Overflow spills to the disk tier
@@ -525,8 +516,6 @@ class Config:
                     f"list of ints; got {raw_buckets!r}")
         c.serve_kv_crc = _env_bool("HOROVOD_SERVE_KV_CRC",
                                    c.serve_kv_crc)
-        c.serve_kv_block = _env_int_strict(
-            "HOROVOD_SERVE_KV_BLOCK", c.serve_kv_block)
         c.serve_prefix_cache = _env_bool(
             "HOROVOD_SERVE_PREFIX_CACHE", c.serve_prefix_cache)
         c.serve_spec_k = _env_int_strict(
@@ -760,13 +749,6 @@ class Config:
             raise ValueError(
                 f"HOROVOD_SERVE_KV_CRC must be a boolean; got "
                 f"{self.serve_kv_crc!r}")
-        kb = self.serve_kv_block
-        if not isinstance(kb, int) or not (0 <= kb <= 4096):
-            raise ValueError(
-                f"HOROVOD_SERVE_KV_BLOCK must be an int in [0, 4096] "
-                f"tokens (0 keeps the slotted layout; the block size "
-                f"shapes the device pool, so a typo here would change "
-                f"every compiled serving program); got {kb!r}")
         if not isinstance(self.serve_prefix_cache, bool):
             raise ValueError(
                 f"HOROVOD_SERVE_PREFIX_CACHE must be a boolean; got "

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..parallel import sp as sp_lib
+from ..serve import kv_cache as kvc
 
 
 class GPTConfig:
@@ -33,7 +34,7 @@ class GPTConfig:
                  remat: bool = False,
                  logits_dtype=jnp.float32,
                  decode: bool = False,
-                 kv_block_size: int = 0,
+                 kv_block_size: int = kvc.KV_BLOCK_SIZE,
                  kv_pool_blocks: int = 0,
                  decode_kernel: Optional[str] = None):
         if decode and attention != "dense":
@@ -41,22 +42,18 @@ class GPTConfig:
                 f"decode mode supports attention='dense' only (got "
                 f"{attention!r}); sequence parallelism shards the axis "
                 "the KV cache grows along")
-        if kv_block_size and not decode:
-            raise ValueError("kv_block_size is a decode-mode knob")
-        if kv_block_size and kv_pool_blocks < 1:
+        if kv_pool_blocks and not decode:
+            raise ValueError("a KV pool is a decode-mode cache")
+        if decode and (kv_block_size < 1 or kv_pool_blocks < 0):
             raise ValueError(
-                "paged decode (kv_block_size > 0) needs kv_pool_blocks "
-                ">= 1 — the device pool shape is static")
+                f"decode mode needs kv_block_size >= 1 and "
+                f"kv_pool_blocks >= 0 (0: the executor sizes the pool); "
+                f"got {kv_block_size}, {kv_pool_blocks}")
         if decode_kernel not in (None, "pallas", "xla"):
             raise ValueError(
                 f"decode_kernel must be None (resolve from "
                 f"HOROVOD_SERVE_KERNEL at executor build), 'pallas' or "
                 f"'xla'; got {decode_kernel!r}")
-        if decode_kernel == "pallas" and not kv_block_size:
-            raise ValueError(
-                "decode_kernel='pallas' is paged-only (the fused kernel "
-                "reads the block pool in place); set kv_block_size > 0 "
-                "or keep the slotted XLA path")
         self.vocab_size = vocab_size
         self.num_layers = num_layers
         self.num_heads = num_heads
@@ -84,19 +81,21 @@ class GPTConfig:
         #: either way (ops/pallas_ce.py), so only the stored logit
         #: values lose precision (standard TPU LM recipe)
         self.logits_dtype = logits_dtype
-        #: inference mode (horovod_tpu/serve): attention threads a
-        #: slotted KV cache (flax "cache" collection) and __call__ takes
-        #: per-row `positions` + `update_mask` at fixed [slots, T]
-        #: shapes — the serving executor's no-recompile contract
+        #: inference mode (horovod_tpu/serve): attention threads a KV
+        #: block pool (flax "cache" collection) and __call__ takes
+        #: per-row `positions`, `update_mask` and `block_tables` at
+        #: fixed [rows, T] shapes — the serving executor's
+        #: no-recompile contract
         self.decode = decode
-        #: paged decode: cache blocks of this many tokens in a pool of
-        #: kv_pool_blocks (serve/kv_cache.py write_kv_paged), addressed
-        #: by per-row block tables passed to __call__ — occupancy is
-        #: bounded by tokens resident, not slots x max_seq_len. 0 keeps
-        #: the slotted layout.
+        #: the decode cache: blocks of this many tokens in a pool of
+        #: kv_pool_blocks (serve/kv_cache.py), addressed by per-row
+        #: block tables passed to __call__ — occupancy is bounded by
+        #: tokens resident, not rows x max_seq_len. kv_pool_blocks 0:
+        #: the model's ShardedExecutor sizes the pool for its worst
+        #: case, max_batch x ceil(max_len / kv_block_size).
         self.kv_block_size = kv_block_size
         self.kv_pool_blocks = kv_pool_blocks
-        #: paged decode attention implementation: "pallas" (the fused
+        #: decode attention implementation: "pallas" (the fused
         #: block-table-aware kernel, ops/pallas_paged.py — interpret
         #: mode off TPU), "xla" (the gather+masked-einsum oracle), or
         #: None — resolve from HOROVOD_SERVE_KERNEL once at executor
@@ -123,49 +122,13 @@ class Attention(nn.Module):
         # predate the decode flag
         if getattr(cfg, "decode", False):
             # serving path: write the S new tokens' K/V into this
-            # layer's cache at each row's offset, then attend over the
-            # cached prefix (horovod_tpu/serve/kv_cache.py). Same
-            # qkv/out params as training — the cache lives in the
-            # separate "cache" collection. Paged configs store a block
-            # POOL addressed through per-row block tables; slotted ones
-            # a [slots, max_seq_len] row per sequence.
-            from ..serve import kv_cache as kvc
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # [B,S,H,D]
-            if getattr(cfg, "kv_block_size", 0):
-                if block_tables is None:
-                    raise ValueError(
-                        "paged decode needs per-row `block_tables` "
-                        "(see horovod_tpu/serve/executor.py)")
-                ck = self.variable(
-                    "cache", "k", jnp.zeros,
-                    (cfg.kv_pool_blocks, cfg.kv_block_size,
-                     cfg.num_heads, cfg.head_dim), cfg.dtype)
-                cv = self.variable(
-                    "cache", "v", jnp.zeros,
-                    (cfg.kv_pool_blocks, cfg.kv_block_size,
-                     cfg.num_heads, cfg.head_dim), cfg.dtype)
-                ck.value, cv.value = kvc.write_kv_paged(
-                    ck.value, cv.value, k, v, positions, update_mask,
-                    block_tables)
-                if getattr(cfg, "decode_kernel", None) == "pallas":
-                    from ..ops.pallas_paged import paged_attention_fused
-                    o = paged_attention_fused(q, ck.value, cv.value,
-                                              block_tables, positions)
-                else:
-                    o = kvc.paged_attention(q, ck.value, cv.value,
-                                            block_tables, positions)
-            else:
-                ck = self.variable(
-                    "cache", "k", jnp.zeros,
-                    (B, cfg.max_seq_len, cfg.num_heads, cfg.head_dim),
-                    cfg.dtype)
-                cv = self.variable(
-                    "cache", "v", jnp.zeros,
-                    (B, cfg.max_seq_len, cfg.num_heads, cfg.head_dim),
-                    cfg.dtype)
-                ck.value, cv.value = kvc.write_kv(
-                    ck.value, cv.value, k, v, positions, update_mask)
-                o = kvc.cached_attention(q, ck.value, cv.value, positions)
+            # layer's block pool through each row's table, then attend
+            # over the row's blocks (serve/kv_cache.py). Same qkv/out
+            # params as training — the pool lives in the separate
+            # "cache" collection.
+            o = kvc.pool_attention(
+                self, cfg, qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                positions, update_mask, block_tables)
             o = o.reshape(B, S, cfg.embed_dim)
             return nn.Dense(cfg.embed_dim, dtype=cfg.dtype,
                             param_dtype=jnp.float32, name="out")(o)

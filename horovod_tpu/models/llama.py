@@ -30,6 +30,7 @@ from flax import linen as nn
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..parallel import sp as sp_lib
+from ..serve import kv_cache as kvc
 
 
 class LlamaConfig:
@@ -44,7 +45,7 @@ class LlamaConfig:
                  remat: bool = False,
                  logits_dtype=jnp.float32,
                  decode: bool = False,
-                 kv_block_size: int = 0,
+                 kv_block_size: int = kvc.KV_BLOCK_SIZE,
                  kv_pool_blocks: int = 0,
                  decode_kernel: Optional[str] = None):
         if decode_kernel not in (None, "pallas", "xla"):
@@ -52,22 +53,18 @@ class LlamaConfig:
                 f"decode_kernel must be None (resolve from "
                 f"HOROVOD_SERVE_KERNEL at executor build), 'pallas' or "
                 f"'xla'; got {decode_kernel!r}")
-        if decode_kernel == "pallas" and not kv_block_size:
-            raise ValueError(
-                "decode_kernel='pallas' is paged-only (the fused kernel "
-                "reads the block pool in place); set kv_block_size > 0 "
-                "or keep the slotted XLA path")
         if decode and attention != "dense":
             raise ValueError(
                 f"decode mode supports attention='dense' only (got "
                 f"{attention!r}); sequence parallelism shards the axis "
                 "the KV cache grows along")
-        if kv_block_size and not decode:
-            raise ValueError("kv_block_size is a decode-mode knob")
-        if kv_block_size and kv_pool_blocks < 1:
+        if kv_pool_blocks and not decode:
+            raise ValueError("a KV pool is a decode-mode cache")
+        if decode and (kv_block_size < 1 or kv_pool_blocks < 0):
             raise ValueError(
-                "paged decode (kv_block_size > 0) needs kv_pool_blocks "
-                ">= 1 — the device pool shape is static")
+                f"decode mode needs kv_block_size >= 1 and "
+                f"kv_pool_blocks >= 0 (0: the executor sizes the pool); "
+                f"got {kv_block_size}, {kv_pool_blocks}")
         self.vocab_size = vocab_size
         self.num_layers = num_layers
         self.num_heads = num_heads
@@ -99,17 +96,18 @@ class LlamaConfig:
         #: logits/dlogits HBM traffic — the fused CE kernel computes in
         #: f32 internally either way
         self.logits_dtype = logits_dtype
-        #: inference mode (horovod_tpu/serve): attention threads a
-        #: slotted KV cache at kv width (GQA's H/KV HBM saving carries
+        #: inference mode (horovod_tpu/serve): attention threads a KV
+        #: block pool at kv width (GQA's H/KV HBM saving carries
         #: straight into the cache) and __call__ takes per-row
-        #: `positions` + `update_mask` at fixed [slots, T] shapes
+        #: `positions`, `update_mask` and `block_tables` at fixed
+        #: [rows, T] shapes
         self.decode = decode
-        #: paged decode (see GPTConfig.kv_block_size): block-pool cache
-        #: at kv width, addressed by per-row block tables — GQA's HBM
-        #: saving compounds with token-bounded occupancy
+        #: the decode cache's block size and pool size (see
+        #: GPTConfig.kv_block_size): GQA's HBM saving compounds with
+        #: token-bounded occupancy
         self.kv_block_size = kv_block_size
         self.kv_pool_blocks = kv_pool_blocks
-        #: paged decode attention implementation (see
+        #: decode attention implementation (see
         #: GPTConfig.decode_kernel): "pallas" | "xla" | None = resolve
         #: from HOROVOD_SERVE_KERNEL at executor build
         self.decode_kernel = decode_kernel
@@ -180,48 +178,18 @@ class LlamaAttention(nn.Module):
         if cfg.decode:
             # serving path: rotate the S new tokens by each row's
             # absolute positions, write K/V (kv width — GQA) into this
-            # layer's cache, attend over the cached prefix
+            # layer's block pool, attend over each row's blocks
             # (horovod_tpu/serve/kv_cache.py). Keys are cached
             # post-RoPE, the standard absolute-rotation layout (which
             # is also what makes a cached shared-prefix block reusable
             # verbatim across sequences: the rotation is absolute).
-            from ..serve import kv_cache as kvc
             table = rope_frequencies(D, cfg.max_seq_len, cfg.rope_theta)
             win = table[positions[:, None] + jnp.arange(S)[None, :]]
             q = apply_rope(q.transpose(0, 2, 1, 3), win)
             k = apply_rope(k.transpose(0, 2, 1, 3), win)
             q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
-            if cfg.kv_block_size:
-                if block_tables is None:
-                    raise ValueError(
-                        "paged decode needs per-row `block_tables` "
-                        "(see horovod_tpu/serve/executor.py)")
-                ck = self.variable(
-                    "cache", "k", jnp.zeros,
-                    (cfg.kv_pool_blocks, cfg.kv_block_size, KV, D),
-                    cfg.dtype)
-                cv = self.variable(
-                    "cache", "v", jnp.zeros,
-                    (cfg.kv_pool_blocks, cfg.kv_block_size, KV, D),
-                    cfg.dtype)
-                ck.value, cv.value = kvc.write_kv_paged(
-                    ck.value, cv.value, k, v, positions, update_mask,
-                    block_tables)
-                if getattr(cfg, "decode_kernel", None) == "pallas":
-                    from ..ops.pallas_paged import paged_attention_fused
-                    o = paged_attention_fused(q, ck.value, cv.value,
-                                              block_tables, positions)
-                else:
-                    o = kvc.paged_attention(q, ck.value, cv.value,
-                                            block_tables, positions)
-            else:
-                ck = self.variable("cache", "k", jnp.zeros,
-                                   (B, cfg.max_seq_len, KV, D), cfg.dtype)
-                cv = self.variable("cache", "v", jnp.zeros,
-                                   (B, cfg.max_seq_len, KV, D), cfg.dtype)
-                ck.value, cv.value = kvc.write_kv(
-                    ck.value, cv.value, k, v, positions, update_mask)
-                o = kvc.cached_attention(q, ck.value, cv.value, positions)
+            o = kvc.pool_attention(self, cfg, q, k, v, positions,
+                                   update_mask, block_tables)
             return dense(cfg.embed_dim, name="wo")(
                 o.reshape(B, S, H * D))
 

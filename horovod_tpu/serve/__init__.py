@@ -7,8 +7,9 @@ timeline for observability. See docs/serving.md for the architecture
 and the bucket/no-recompile contract.
 
     queue.py     admission control: bounded queue, deadlines, load shed
-    kv_cache.py  KV storage: slotted rows and vLLM-style paged blocks
-                 (BlockPool free-list allocator, per-block crc ledger)
+    kv_cache.py  KV storage: one vLLM-style block pool read through
+                 per-row tables (BlockPool free-list allocator,
+                 per-block crc ledger)
     prefix.py    radix prefix cache: shared system prompts computed
                  once, refcounted block runs, CoW at divergence, LRU
                  eviction, weight-version flush
@@ -59,9 +60,8 @@ from .http import (                                            # noqa: F401
 )
 from .proc_fleet import ProcessFleetRouter, ProcessReplica     # noqa: F401
 from .kv_cache import (                                        # noqa: F401
-    BlockPool, PagedKVCache, SlotKVCache, cached_attention,
-    masked_attention, paged_attention, paged_model_kwargs,
-    pool_blocks_for, write_kv, write_kv_paged,
+    BlockPool, PagedKVCache, masked_attention, paged_attention,
+    pool_blocks_for, write_kv_paged,
 )
 from .kvtier import (                                          # noqa: F401
     DiskTier, FleetRadixIndex, HostRing, ReplicaKVTier, TierEntry,

@@ -13,12 +13,12 @@ One `step()` is one scheduling iteration:
 
 1. **retire** — finished (max_new_tokens / EOS / context-full) and
    deadline-expired sequences resolve their handles and free their KV
-   capacity (slot, or block-table references + prefix refcounts) in
+   capacity (row, block-table references + prefix refcounts) in
    the SAME iteration — a leaked block is capacity gone forever.
-2. **admit** — pop queued requests into free capacity. Slotted caches
-   admit on free slots; paged caches (serve/kv_cache.py `PagedKVCache`)
-   admit on free BLOCKS — tokens, not slots — through
-   `queue.pop_fitting`. With the radix prefix cache enabled
+2. **admit** — pop queued requests into free capacity: a free row and
+   free BLOCKS of the pool (serve/kv_cache.py `PagedKVCache`) —
+   tokens, not rows — through `queue.pop_fitting`. With the radix
+   prefix cache enabled
    (serve/prefix.py), each prompt is first matched against cached
    shared prefixes: matched blocks join the sequence's table by
    reference (copy-on-write at a mid-block divergence) and only the
@@ -48,7 +48,7 @@ import numpy as np
 from ..chaos import inject as _chaos
 from ..obs import metrics as obs_metrics
 from ..trace.spans import get_recorder as _trace_recorder
-from .kv_cache import BlockPool, PagedKVCache, SlotKVCache
+from .kv_cache import BlockPool, PagedKVCache
 from .kvtier.tier import ReplicaKVTier
 from .prefix import RadixPrefixCache
 from .queue import AdmissionQueue, ServeRequest
@@ -74,7 +74,7 @@ class _Active:
     out: List[int] = field(default_factory=list)
     #: tokens written into the KV cache (prompt + confirmed generations)
     cache_len: int = 0
-    #: paged admission plan: prefix-matched blocks awaiting attachment
+    #: admission plan: prefix-matched blocks awaiting attachment
     plan: Optional[dict] = None
     #: prompt tokens served from the prefix cache instead of recompute
     prefix_tokens: int = 0
@@ -146,13 +146,11 @@ class ContinuousBatcher:
         #: prefilled row-compact instead, ``[prefill_rows, bucket]`` a
         #: step with each step at its own rows' bucket, so a prefill's
         #: work follows the prompt tokens admitted and not
-        #: ``max_batch x`` the longest. Paged only: the rows of such a
-        #: step are addressed through their block tables
-        rows = getattr(getattr(executor.model, "cfg", None),
-                       "prefill_rows", None)
+        #: ``max_batch x`` the longest: the rows of such a step are
+        #: addressed through their block tables
+        rows = getattr(executor.model.cfg, "prefill_rows", None)
         self.prefill_rows: Optional[int] = (
-            min(int(rows), executor.max_batch)
-            if rows and getattr(executor, "paged", False) else None)
+            min(int(rows), executor.max_batch) if rows else None)
         self.eos_id = eos_id
         #: fleet identity (None = standalone): labels the metric
         #: series and addresses chaos serve.step / serve.kv faults
@@ -162,7 +160,7 @@ class ContinuousBatcher:
                 or kv_tier is None:
             from ..core.config import Config
             cfg = Config.from_env()
-        #: per-slot/per-block crc-on-write / verify-on-read
+        #: per-block crc-on-write / verify-on-read
         #: (HOROVOD_SERVE_KV_CRC or explicit): every cache write is
         #: folded into the crc ledger and every retiring request's
         #: valid prefix is re-read and verified BEFORE its tokens can
@@ -177,33 +175,27 @@ class ContinuousBatcher:
         self.kv_corruptions_detected = 0
         self.kv_corruptions_injected = 0
         self.kv_reprefills = 0
-        #: a fired serve.kv corrupt waiting for a written slot, (slot,)
+        #: a fired serve.kv corrupt waiting for a written row, (row,)
         self._pending_corrupt = None
         # unservable prompts get shed at submit time, not discovered
-        # holding a decode slot
+        # holding a decode row
         if queue.max_prompt_len is None or \
                 queue.max_prompt_len > buckets[-1]:
             queue.max_prompt_len = buckets[-1]
 
-        # -- KV storage: paged (block pool + optional radix prefix
-        # cache) when the model config says so, slotted otherwise
-        self.paged = bool(getattr(executor, "paged", False))
-        if self.paged:
-            pool = BlockPool(executor.kv_pool_blocks,
-                             executor.kv_block_size)
-            self.kv = PagedKVCache(executor.max_batch,
-                                   executor.blocks_per_seq, pool)
-            if prefix_cache is None:
-                prefix_cache = cfg.serve_prefix_cache
-            self.prefix: Optional[RadixPrefixCache] = (
-                RadixPrefixCache(pool, replica_id=replica_id)
-                if prefix_cache else None)
-            if self.prefix is not None:
-                self.kv.evictable = self.prefix.evictable_blocks
-                self.kv.evictor = self.prefix.evict
-        else:
-            self.kv = SlotKVCache(executor.max_batch, executor.max_len)
-            self.prefix = None
+        # -- KV storage: the block pool, with the radix prefix cache
+        # over it when enabled
+        pool = BlockPool(executor.kv_pool_blocks, executor.kv_block_size)
+        self.kv = PagedKVCache(executor.max_batch,
+                               executor.blocks_per_seq, pool)
+        if prefix_cache is None:
+            prefix_cache = cfg.serve_prefix_cache
+        self.prefix: Optional[RadixPrefixCache] = (
+            RadixPrefixCache(pool, replica_id=replica_id)
+            if prefix_cache else None)
+        if self.prefix is not None:
+            self.kv.evictable = self.prefix.evictable_blocks
+            self.kv.evictor = self.prefix.evict
         #: params version the prefix cache's contents were computed
         #: under; a swap flushes the cache before any further lookup
         self._prefix_version = executor.params_version
@@ -212,13 +204,12 @@ class ContinuousBatcher:
 
         # -- fleet KV tier (serve/kvtier/): evicted prefix runs demote
         # down the HBM -> host -> disk ladder and promote back through
-        # the verified install path. Paged + prefix-cache only — with
-        # either off the knob is inert (same contract as the prefix
-        # cache itself being paged-only).
+        # the verified install path. With the prefix cache off the
+        # knob is inert.
         if kv_tier is None:
             kv_tier = cfg.serve_kvtier
         self.kvtier: Optional[ReplicaKVTier] = None
-        if kv_tier and self.paged and self.prefix is not None:
+        if kv_tier and self.prefix is not None:
             if kvtier_host_mb is None or kvtier_dir is None:
                 if cfg is None:
                     from ..core.config import Config
@@ -241,11 +232,6 @@ class ContinuousBatcher:
         self.spec_k = int(spec_k) if draft_executor is not None else 0
         self.draft = draft_executor if self.spec_k > 0 else None
         if self.draft is not None:
-            if getattr(self.draft, "paged", False):
-                raise ValueError(
-                    "the draft executor must use the slotted cache "
-                    "(its rows mirror the target batch 1:1; paging the "
-                    "throwaway draft state buys nothing)")
             if self.draft.max_batch != executor.max_batch:
                 raise ValueError(
                     f"draft max_batch {self.draft.max_batch} must equal "
@@ -254,17 +240,30 @@ class ContinuousBatcher:
                 raise ValueError(
                     f"largest prefill bucket {buckets[-1]} exceeds the "
                     f"draft model context {self.draft.max_len}")
+            # the drafter's rows mirror the target's 1:1 and its state
+            # is thrown away with its row, so its pool gets no block
+            # accounting: row r owns blocks [r * n, (r + 1) * n) for
+            # good, read through this one table
+            n = self.draft.blocks_per_seq
+            if self.draft.kv_pool_blocks < executor.max_batch * n:
+                raise ValueError(
+                    f"the draft pool of {self.draft.kv_pool_blocks} "
+                    f"blocks cannot hold {executor.max_batch} rows of "
+                    f"{n} (one fixed run a row)")
+            self._draft_tables = np.arange(
+                executor.max_batch * n, dtype=np.int32).reshape(
+                executor.max_batch, n)
         #: (per-SEQUENCE target verify+decode step participations,
         #: tokens emitted by them) — the machine-independent
-        #: speculative win the bench gate asserts (< 0.7 target steps
-        #: per generated token). Row-granular on purpose: batched plain
+        #: speculative win tests/test_serve_paged.py asserts (< 0.7
+        #: target steps per generated token). Row-granular on purpose: batched plain
         #: decode pegs at exactly 1.0 (each row pays one target step
         #: per token it emits), so only speculation can push the ratio
         #: below 1 — batching wins cannot masquerade as draft wins.
         self.gen_steps = 0
         self.gen_tokens = 0
 
-        self._active: Dict[int, _Active] = {}   # slot/row -> sequence
+        self._active: Dict[int, _Active] = {}   # row -> sequence
         self._reprefill: List[ServeRequest] = []
         # -- disaggregated serving (serve/disagg.py, serve/kv_migrate.py)
         #: PARKED sequences: cleanly retired hold_kv requests whose row
@@ -308,7 +307,7 @@ class ContinuousBatcher:
         #: router-visible drain flag (mirrored into /healthz)
         self.draining = False
         # -- metrics: time-to-first-token (admission wait + prefill),
-        # live KV occupancy, and — paged — the block-occupancy gauge.
+        # live KV occupancy and the block-occupancy gauge.
         # Standalone batchers claim fresh; fleet replicas use labeled
         # children (same discipline as AdmissionQueue/ShardedExecutor).
         rl = {} if replica_id is None else {"replica": str(replica_id)}
@@ -324,11 +323,11 @@ class ContinuousBatcher:
             rl or None)
         self._m_occupancy = R.gauge(
             "hvd_serve_kv_occupancy",
-            "fraction of KV capacity in use (slots, or pool blocks "
-            "when paged — tokens resident, not sequences)", rl or None)
+            "fraction of KV pool blocks in use (tokens resident, not "
+            "sequences)", rl or None)
         self._m_blocks = R.gauge(
             "hvd_serve_kv_blocks_in_use",
-            "paged KV blocks currently allocated (0 when slotted)",
+            "KV pool blocks currently allocated",
             rl or None)
         self._m_accept = R.histogram(
             "hvd_serve_spec_accept_rate",
@@ -336,7 +335,7 @@ class ContinuousBatcher:
             "verify step", rl or None, bounds=_ACCEPT_BOUNDS)
         self._m_kv_corrupt = R.counter(
             "hvd_serve_kv_corruptions_total",
-            "KV slots whose verify-on-read crc failed (corruption "
+            "KV rows whose verify-on-read crc failed (corruption "
             "caught before reaching a client)", rl or None)
         self._m_migrate_corrupt = R.counter(
             "hvd_serve_migrate_corrupt_total",
@@ -442,27 +441,26 @@ class ContinuousBatcher:
         B = self.executor.max_batch
         zero = np.zeros(B, np.int32)
         off = np.zeros(B, bool)
-        tbl = (np.full((B, self.executor.blocks_per_seq), -1, np.int32)
-               if self.paged else None)
+        tbl = np.full((B, self.executor.blocks_per_seq), -1, np.int32)
         R = self.prefill_rows or B      # a prefill step's rows
         for b in self.buckets:
             self.executor.step(
                 np.zeros((R, b), np.int32), zero[:R], off[:R], zero[:R],
-                kind="prefill",
-                block_tables=None if tbl is None else tbl[:R])
+                kind="prefill", block_tables=tbl[:R])
         self.executor.step(np.zeros((B, 1), np.int32), zero, off, zero,
                            kind="decode", block_tables=tbl)
-        if self.paged:
-            self.executor.copy_kv_block(0, 0)   # compile the CoW copy
+        self.executor.copy_kv_block(0, 0)   # compile the CoW copy
         if self.draft is not None:
             self.executor.step(
                 np.zeros((B, self.spec_k + 1), np.int32), zero, off,
                 zero, kind="verify", block_tables=tbl)
             for b in self.buckets:
                 self.draft.step(np.zeros((B, b), np.int32), zero, off,
-                                zero, kind="prefill")
+                                zero, kind="prefill",
+                                block_tables=self._draft_tables)
             self.draft.step(np.zeros((B, 1), np.int32), zero, off, zero,
-                            kind="decode")
+                            kind="decode",
+                            block_tables=self._draft_tables)
 
     # -- chaos guards (one attribute read when disarmed) ---------------------
     def _fire_step_chaos(self) -> None:
@@ -481,9 +479,9 @@ class ContinuousBatcher:
 
     def _fire_kv_chaos(self) -> None:
         """``serve.kv`` site: corrupt flips a real bit inside a live
-        sequence's device cache — a slot row when slotted, a BLOCK of
-        the pool when paged (detection must come from the per-block crc
-        ledger, nothing else knows). A corrupt fired on an iteration
+        sequence's device cache, in the row's newest BLOCK of the pool
+        (detection must come from the per-block crc ledger, nothing
+        else knows). A corrupt fired on an iteration
         with no written data is DEFERRED to the next one that has some,
         so an exact-``at`` address always lands exactly one flip."""
         if _chaos._INJ is None and self._pending_corrupt is None:
@@ -501,14 +499,11 @@ class ContinuousBatcher:
             length = self._active[slot].cache_len
             if length > 0:
                 self._pending_corrupt = None
-                if self.paged:
-                    bs = self.kv.block_size
-                    bi = (int(length) - 1) // bs
-                    blk = self.kv.blocks[slot][bi]
-                    self.executor.corrupt_kv_block(
-                        blk, ((int(length) - 1) % bs) + 1)
-                else:
-                    self.executor.corrupt_kv_slot(slot, int(length))
+                bs = self.kv.block_size
+                bi = (int(length) - 1) // bs
+                blk = self.kv.blocks[slot][bi]
+                self.executor.corrupt_kv_block(
+                    blk, ((int(length) - 1) % bs) + 1)
                 self.kv_corruptions_injected += 1
 
     # -- one scheduling iteration -------------------------------------------
@@ -539,8 +534,8 @@ class ContinuousBatcher:
         self._maybe_flush_prefix()
         with rec.span("sched_retire"):
             # expired-but-still-queued requests get their structured
-            # deadline completion NOW, even when every slot is busy —
-            # within one iteration, not at slot-drain time
+            # deadline completion NOW, even when every row is busy —
+            # within one iteration, not at row-drain time
             self.queue.reap_expired()
             # migration plumbing (single-writer: all pool/row
             # bookkeeping happens HERE, on the scheduler thread — the
@@ -576,7 +571,7 @@ class ContinuousBatcher:
         # evaluated EVERY iteration, busy or idle: the iteration counter
         # ticks regardless, so an exact-'at' corrupt landing while
         # the replica is idle must still be captured (and deferred to
-        # the next written slot) — inside the busy branch the counter
+        # the next written row) — inside the busy branch the counter
         # would walk past the address without fire() ever seeing it
         self._fire_kv_chaos()
         if self._active:
@@ -637,15 +632,13 @@ class ContinuousBatcher:
     def load(self) -> float:
         """The fleet router's capacity signal: waiting plus in-flight,
         with in-flight measured in the unit that actually limits this
-        batcher — live rows when slotted, BLOCKS in use scaled to
-        row-equivalents when paged. Two paged replicas with the same
-        sequence count can differ several-fold in memory pressure (one
-        long context vs many short ones); routing on blocks sends the
-        next long prompt to the replica that can actually hold it."""
-        if self.paged:
-            per_row = max(self.executor.blocks_per_seq, 1)
-            return self.queue.depth() + self.kv.pool.in_use() / per_row
-        return self.queue.depth() + float(self.kv.live())
+        batcher — BLOCKS in use scaled to row-equivalents. Two replicas
+        with the same sequence count can differ several-fold in memory
+        pressure (one long context vs many short ones); routing on
+        blocks sends the next long prompt to the replica that can
+        actually hold it."""
+        return self.queue.depth() + \
+            self.kv.pool.in_use() / self.executor.blocks_per_seq
 
     # -- disaggregated serving: park / migrate-install ----------------------
     def parked_seq(self, rid: int) -> Optional[_Active]:
@@ -707,7 +700,7 @@ class ContinuousBatcher:
             seqs = [self.parked.pop(rid) for rid in rids + reap
                     if rid in self.parked]
         for seq in seqs:
-            self._free_seq(seq.slot)
+            self.kv.free_row(seq.slot)
 
     def submit_migrated(self, meta: dict,
                         blocks: List[dict]) -> dict:
@@ -762,8 +755,6 @@ class ContinuousBatcher:
         seeding, batch enrollment. Returns ("installed", None) or a
         structured ("version_mismatch"|"rejected"|"incompatible",
         detail) the endpoint acks back to the sender."""
-        if not self.paged:
-            return ("incompatible", "decode replica is not paged")
         meta, blocks = ent["meta"], ent["blocks"]
         # -- weight-version FENCE: migrated KV was computed under the
         # sender's version; installing it under any other version
@@ -854,8 +845,7 @@ class ContinuousBatcher:
         fields only where the executor has a timeline to write to."""
         occ = self.kv.occupancy()
         self._m_occupancy.set(occ)
-        if self.paged:
-            self._m_blocks.set(self.kv.pool.in_use())
+        self._m_blocks.set(self.kv.pool.in_use())
         if self.executor.timeline is None:
             return None
         return {"queue_depth": self.queue.depth(),
@@ -897,29 +887,14 @@ class ContinuousBatcher:
             s["ctr"][at] = seq.rng_ctr + ctr_offset
         return s
 
-    # -- crc plumbing (slot- or block-granular) ------------------------------
+    # -- crc plumbing (block-granular) ---------------------------------------
     def _crc_write(self, slot: int, lo: int, hi: int) -> None:
-        """Fold cache positions ``[lo, hi)`` just written for ``slot``
-        into the crc ledger. Paged: per-BLOCK ledger entries; an
-        overwrite below a block's high-water mark (speculative
-        rollback) recomputes that block's crc from a fresh readback —
-        streaming crc32 cannot be truncated."""
+        """Fold cache positions ``[lo, hi)`` just written for row
+        ``slot`` into the per-BLOCK crc ledger; an overwrite below a
+        block's high-water mark (speculative rollback) recomputes that
+        block's crc from a fresh readback — streaming crc32 cannot be
+        truncated."""
         if not self.kv_crc or hi <= lo:
-            return
-        if not self.paged:
-            filled = self.kv.crc_filled(slot)
-            if lo == filled:
-                self.kv.crc_update(
-                    slot, self.executor.kv_slot_bytes(slot, lo, hi), hi)
-            else:
-                # speculative rollback overwrote below the high-water
-                # mark: the append-only stream breaks — recompute the
-                # slot's ledger from a full re-read
-                new_filled = max(filled, hi)
-                self.kv.crc_reset(
-                    slot,
-                    self.executor.kv_slot_bytes(slot, 0, new_filled),
-                    new_filled)
             return
         bs = self.kv.block_size
         pool = self.kv.pool
@@ -945,17 +920,13 @@ class ContinuousBatcher:
         and check it against the write-side crc ledger. Runs only at
         retirement (and only with kv_crc on), so a request's tokens are
         NEVER released to a client from cache bytes that changed behind
-        the scheduler's back. Paged sequences verify per BLOCK — shared
-        prefix blocks included, under the pool-wide ledger."""
+        the scheduler's back. Sequences verify per BLOCK — shared
+        prefix blocks included, under the pool-wide ledger — and each
+        block over exactly its covered prefix (the ledger's high-water
+        mark can exceed cache_len: a verify step's rejected tail is
+        written but not accepted)."""
         if not self.kv_crc or seq.cache_len <= 0:
             return True
-        if not self.paged:
-            # the ledger's high-water mark can exceed cache_len (a
-            # verify step's rejected tail is written but not accepted);
-            # verify exactly the covered prefix
-            hi = self.kv.crc_filled(seq.slot) or seq.cache_len
-            raw = self.executor.kv_slot_bytes(seq.slot, 0, hi)
-            return self.kv.crc_check(seq.slot, raw)
         pool = self.kv.pool
         for blk in self.kv.blocks[seq.slot]:
             filled = pool.crc_filled(blk)
@@ -965,15 +936,6 @@ class ContinuousBatcher:
                     blk, self.executor.kv_block_bytes(blk, 0, filled)):
                 return False
         return True
-
-    def _free_seq(self, slot: int) -> None:
-        """Release a retiring sequence's KV capacity — its slot, or its
-        whole block table (decrementing shared-prefix refcounts) — in
-        the SAME iteration it retires."""
-        if self.paged:
-            self.kv.free_row(slot)
-        else:
-            self.kv.free(slot)
 
     def _retire(self) -> None:
         now = time.monotonic()
@@ -991,22 +953,21 @@ class ContinuousBatcher:
             if not self._kv_verify(seq):
                 # corrupted KV: the generated tokens are untrusted and
                 # must not reach the client. Re-prefill from the prompt
-                # (a fresh slot, a clean generation) while the deadline
+                # (a fresh row, a clean generation) while the deadline
                 # allows; otherwise fail cleanly.
                 self.kv_corruptions_detected += 1
                 self._m_kv_corrupt.inc()
                 logger.warning(
-                    "serve replica %s: KV %s %d failed crc "
+                    "serve replica %s: KV row %d failed crc "
                     "verify-on-read (request %d) — %s",
-                    self.replica_id,
-                    "row" if self.paged else "slot", slot, req.rid,
+                    self.replica_id, slot, req.rid,
                     "re-prefilling" if self.on_kv_corrupt == "reprefill"
                     and not expired else "failing the request")
                 if self.prefix is not None:
                     # the corrupt block may BE a cached prefix run; a
                     # re-prefill matching it would corrupt again
                     self.prefix.flush()
-                self._free_seq(slot)
+                self.kv.free_row(slot)
                 del self._active[slot]
                 if self.on_kv_corrupt == "reprefill" and not expired:
                     self.kv_reprefills += 1
@@ -1014,8 +975,7 @@ class ContinuousBatcher:
                 else:
                     self._resolve(seq, "error", ms, error="kv_corrupt")
                 continue
-            if seq.t_first is not None \
-                    and not (req.hold_kv and self.paged):
+            if seq.t_first is not None and not req.hold_kv:
                 trace, root = req.trace_ids()
                 _trace_recorder().record_local(
                     "decode", seq.t_first, now, trace=trace,
@@ -1024,7 +984,7 @@ class ContinuousBatcher:
             if expired and not done_ok:
                 self.queue.expired_count += 1
                 self._resolve(seq, "expired", ms)
-            elif req.hold_kv and self.paged:
+            elif req.hold_kv:
                 # disaggregated prefill: PARK the verified sequence —
                 # row and blocks stay allocated so the endpoint can
                 # migrate them (serve/kv_migrate.py pack_parked).
@@ -1041,7 +1001,7 @@ class ContinuousBatcher:
             else:
                 self._resolve(seq, "ok", ms)
                 self.queue.note_service_ms(ms)
-            self._free_seq(slot)
+            self.kv.free_row(slot)
             del self._active[slot]
 
     # -- admission -----------------------------------------------------------
@@ -1053,7 +1013,7 @@ class ContinuousBatcher:
                    self.executor.max_len)
 
     def _plan(self, req: ServeRequest) -> dict:
-        """Paged admission plan: prefix match (references pinned) plus
+        """Admission plan: prefix match (references pinned) plus
         the fresh-block budget the admission gate charges."""
         if self.prefix is not None:
             full, partial, m = self.prefix.match(req.prompt)
@@ -1073,8 +1033,6 @@ class ContinuousBatcher:
             self.prefix.release([plan["partial"][0]])
 
     def _admit(self) -> List[_Active]:
-        if not self.paged:
-            return self._admit_slotted()
         free_rows = self.kv.num_rows - self.kv.live()
         if free_rows <= 0:
             return []
@@ -1144,22 +1102,6 @@ class ContinuousBatcher:
             admit_one(req, plans[req.rid])
         return admitted
 
-    def _admit_slotted(self) -> List[_Active]:
-        free = self.kv.num_slots - self.kv.live()
-        if free <= 0:
-            return []
-        admitted: List[_Active] = []
-        while self._reprefill and len(admitted) < free:
-            req = self._reprefill.pop(0)
-            slot = self.kv.alloc()
-            admitted.append(_Active(req=req, slot=slot))
-            self._active[slot] = admitted[-1]
-        for req in self.queue.pop(free - len(admitted)):
-            slot = self.kv.alloc()  # free>=len(pop) => never None
-            admitted.append(_Active(req=req, slot=slot))
-            self._active[slot] = admitted[-1]
-        return admitted
-
     def _bucket_for(self, length: int) -> int:
         for b in self.buckets:
             if length <= b:
@@ -1173,28 +1115,27 @@ class ContinuousBatcher:
         B = self.executor.max_batch
         t_p0 = time.monotonic()   # queue_wait ends / prefill begins
         hit_rows: List[_Active] = []
-        if self.paged:
-            # materialize each admission plan: shared full blocks join
-            # the table by reference; a mid-block partial match is
-            # copy-on-written into a fresh block the suffix then
-            # overwrites from its divergence point
-            for a in admitted:
-                plan, row = a.plan, a.slot
-                for blk in plan["full"]:
-                    self.kv.attach_shared(row, blk)
-                if plan["partial"] is not None:
-                    src, _j = plan["partial"]
-                    dst = self.kv.append_block(row)
-                    self.executor.copy_kv_block(src, dst)
-                    self.kv.pool.crc_clone(src, dst)
-                    self.prefix.release([src])   # drop the CoW pin
-                a.prefix_tokens = plan["m"]
-                a.plan = None
-                if self.prefix is not None:
-                    self.prefix.note_lookup(a.prefix_tokens)
-                if a.prefix_tokens:
-                    hit_rows.append(a)
-                self.kv.ensure(row, len(a.req.prompt))
+        # materialize each admission plan: shared full blocks join
+        # the table by reference; a mid-block partial match is
+        # copy-on-written into a fresh block the suffix then
+        # overwrites from its divergence point
+        for a in admitted:
+            plan, row = a.plan, a.slot
+            for blk in plan["full"]:
+                self.kv.attach_shared(row, blk)
+            if plan["partial"] is not None:
+                src, _j = plan["partial"]
+                dst = self.kv.append_block(row)
+                self.executor.copy_kv_block(src, dst)
+                self.kv.pool.crc_clone(src, dst)
+                self.prefix.release([src])   # drop the CoW pin
+            a.prefix_tokens = plan["m"]
+            a.plan = None
+            if self.prefix is not None:
+                self.prefix.note_lookup(a.prefix_tokens)
+            if a.prefix_tokens:
+                hit_rows.append(a)
+            self.kv.ensure(row, len(a.req.prompt))
         R = self.prefill_rows
         if R is None:
             # ONE packed prefill, row = the sequence's batch slot
@@ -1222,7 +1163,7 @@ class ContinuousBatcher:
             positions = np.zeros(rows, np.int32)
             mask = np.zeros(rows, bool)
             last_idx = np.zeros(rows, np.int32)
-            tables = self.kv.table() if self.paged else None
+            tables = self.kv.table()
             if R is not None:
                 # each row's own table, in the step's row order
                 at = np.full(rows, -1)
@@ -1261,7 +1202,7 @@ class ContinuousBatcher:
                 self.executor.params_version)
             self._prefix_flush.set()
             for a in hit_rows:
-                self._free_seq(a.slot)
+                self.kv.free_row(a.slot)
                 del self._active[a.slot]
                 self._reprefill.append(a.req)
             admitted = [a for a in admitted if a not in hit_rows]
@@ -1290,7 +1231,7 @@ class ContinuousBatcher:
             # positions past n are unreachable and unverified; shared
             # prefix blocks carry their writer's ledger already)
             self._crc_write(a.slot, a.prefix_tokens, n)
-            if self.paged and self.prefix is not None:
+            if self.prefix is not None:
                 # publish this prompt's FULL blocks for future sharing
                 self.prefix.insert(a.req.prompt,
                                    self.kv.blocks[a.slot])
@@ -1320,7 +1261,7 @@ class ContinuousBatcher:
             mask[a.slot] = True
             last_idx[a.slot] = n - 1
         self.draft.step(tokens, positions, mask, last_idx,
-                        kind="prefill")
+                        kind="prefill", block_tables=self._draft_tables)
         for a in admitted:
             a.draft_len = len(a.req.prompt)
 
@@ -1356,12 +1297,11 @@ class ContinuousBatcher:
             tokens[slot, 0] = seq.out[-1]
             positions[slot] = seq.cache_len
             mask[slot] = True
-            if self.paged:
-                self.kv.ensure(slot, seq.cache_len + 1)
+            self.kv.ensure(slot, seq.cache_len + 1)
         nxt = self.executor.step(
             tokens, positions, mask, last_idx, kind="decode",
             stats=self._stats(), sample=self._sample_args(rows),
-            block_tables=self.kv.table() if self.paged else None)
+            block_tables=self.kv.table())
         t_tok = time.monotonic()   # one stamp for the step's rows
         self.gen_steps += len(rows)
         for slot in rows:
@@ -1430,7 +1370,8 @@ class ContinuousBatcher:
                 mask[slot] = True
             out, probs = self.draft.step(
                 tokens, positions, mask, zero, kind="decode",
-                sample=self._sample_args(rows, ctr_offset=i))
+                sample=self._sample_args(rows, ctr_offset=i),
+                block_tables=self._draft_tables)
             step_probs.append(probs)
             for slot in rows:
                 o = int(out[slot])
@@ -1462,8 +1403,7 @@ class ContinuousBatcher:
             mask[slot] = True
             n_draft[slot] = len(drafts[slot])
             offs[slot] = max(first_prop[slot], 0)
-            if self.paged:
-                self.kv.ensure(slot, seq.cache_len + k + 1)
+            self.kv.ensure(slot, seq.cache_len + k + 1)
         stacked = jnp.stack(step_probs)                    # [k, B, V]
         idx = np.clip(offs[:, None] + np.arange(k)[None, :], 0, k - 1)
         dprobs = stacked[jnp.asarray(idx),
@@ -1472,7 +1412,7 @@ class ContinuousBatcher:
             tokens, positions, mask, zero, kind="verify",
             stats=self._stats(), sample=self._sample_args(rows),
             draft_probs=dprobs, n_draft=n_draft,
-            block_tables=self.kv.table() if self.paged else None)
+            block_tables=self.kv.table())
         t_tok = time.monotonic()   # one stamp for all the step emitted
         self.gen_steps += len(rows)
         for slot in rows:

@@ -12,7 +12,7 @@ built on.
 
 Three decisions are frozen at build time so the jit cache stays flat:
 
-* **Kernel**: paged executors resolve the decode-attention kernel ONCE
+* **Kernel**: the decode-attention kernel is resolved ONCE
   (``HOROVOD_SERVE_KERNEL`` via `ops.pallas_paged.resolve_kernel` —
   fused Pallas on TPU by default, the XLA gather oracle as CPU
   fallback) and stamp it into the model config before the first trace.
@@ -53,6 +53,7 @@ trace.
 """
 from __future__ import annotations
 
+import copy
 import logging
 import threading
 import time
@@ -110,16 +111,20 @@ class ShardedExecutor:
             raise ValueError(f"max_batch must be >= 1; got {max_batch}")
         if role not in ("target", "draft"):
             raise ValueError(f"role must be 'target'|'draft'; got {role!r}")
-        model_max = getattr(getattr(model, "cfg", None), "max_seq_len",
-                            None)
-        if model_max is not None and max_len > model_max:
-            # the cache arrays are shaped by the model's max_seq_len; a
-            # larger executor max_len would silently clamp cache writes
-            # and position lookups instead of erroring
+        if max_len > model.cfg.max_seq_len:
+            # the position tables are shaped by the model's
+            # max_seq_len; a larger executor max_len would silently
+            # clamp position lookups instead of erroring
             raise ValueError(
                 f"max_len {max_len} exceeds the model's max_seq_len "
-                f"{model_max}")
-        self.model = model
+                f"{model.cfg.max_seq_len}")
+        # the executor's own copy of the model config: the pool size
+        # and the kernel resolved below are stamped on it before the
+        # first trace (the model reads both at trace time), and the
+        # caller's model stays as given, free to build an executor of
+        # another shape
+        cfg = copy.copy(model.cfg)
+        self.model = model = model.clone(cfg=cfg)
         self.max_batch = max_batch
         self.max_len = max_len
         self.timeline = timeline
@@ -127,37 +132,30 @@ class ShardedExecutor:
         #: process with a target executor: they must neither reclaim
         #: the serve metric families nor blend into the target's series
         self.role = role
-        # -- paged layout (model-config driven): the device cache is a
-        # block pool and every step takes per-row block tables
-        cfg = getattr(model, "cfg", None)
-        self.kv_block_size = int(getattr(cfg, "kv_block_size", 0) or 0)
-        self.kv_pool_blocks = int(getattr(cfg, "kv_pool_blocks", 0) or 0)
-        self.paged = self.kv_block_size > 0
+        # -- the KV storage (model-config driven): the device cache is
+        # a block pool and every step takes per-row block tables
+        self.kv_block_size = int(cfg.kv_block_size)
         #: fixed block-table width: enough entries to address max_len
-        self.blocks_per_seq = (
-            -(-max_len // self.kv_block_size) if self.paged else 0)
-        if self.paged and \
-                self.kv_pool_blocks < self.blocks_per_seq:
+        self.blocks_per_seq = -(-max_len // self.kv_block_size)
+        if not cfg.kv_pool_blocks:
+            # a config that names no pool size gets the worst case,
+            # every row at max_len: a table that never changes can
+            # address it, and admission by blocks never waits where
+            # rows are free
+            cfg.kv_pool_blocks = max_batch * self.blocks_per_seq
+        self.kv_pool_blocks = int(cfg.kv_pool_blocks)
+        if self.kv_pool_blocks < self.blocks_per_seq:
             raise ValueError(
                 f"kv_pool_blocks {self.kv_pool_blocks} cannot cover one "
                 f"max_len sequence ({self.blocks_per_seq} blocks of "
                 f"{self.kv_block_size})")
         #: vocab width — the verify step's draft-probs row shape
-        self.vocab_size = int(getattr(cfg, "vocab_size", 0) or 0)
-        # -- decode kernel, resolved ONCE before the first trace: the
-        # model reads cfg.decode_kernel at trace time, so stamping the
-        # resolution here keeps every compiled program on one path and
-        # the jit cache flat. Slotted executors (draft models included)
-        # always run the XLA path — the fused kernel is block-table
-        # shaped; HOROVOD_SERVE_KERNEL names the PAGED hot path.
+        self.vocab_size = int(cfg.vocab_size)
+        # -- decode kernel, resolved ONCE: stamping the resolution here
+        # keeps every compiled program on one path and the jit cache
+        # flat
         from ..ops.pallas_paged import resolve_kernel
-        if self.paged:
-            self.kernel = resolve_kernel(
-                getattr(cfg, "decode_kernel", None))
-            if cfg is not None:
-                cfg.decode_kernel = self.kernel
-        else:
-            self.kernel = "xla"
+        self.kernel = cfg.decode_kernel = resolve_kernel(cfg.decode_kernel)
         # kept for hot weight swaps (redist/stream.py): replacement
         # params are placed exactly like the originals
         self._mesh = mesh
@@ -236,11 +234,11 @@ class ShardedExecutor:
 
         def apply_model(params, cache, tokens, positions, mask, tables,
                         logits_idx):
-            kw = {"block_tables": tables} if self.paged else {}
             return self.model.apply(
                 {"params": params, "cache": cache}, tokens,
                 positions=positions, update_mask=mask,
-                logits_idx=logits_idx, mutable=["cache", "stats"], **kw)
+                logits_idx=logits_idx, mutable=["cache", "stats"],
+                block_tables=tables)
 
         def with_stats(per_row, vout):
             """A model that sows step counters (``stats`` collection:
@@ -255,49 +253,24 @@ class ShardedExecutor:
             return jnp.concatenate(
                 [per_row, total.astype(per_row.dtype)[None]])
 
-        if self.paged:
-            def fwd_token(params, cache, tokens, positions, mask,
-                          last_idx, temp, top_p, seed, ctr, tables):
-                logits, vout = apply_model(params, cache, tokens,
-                                           positions, mask, tables,
-                                           last_idx)
-                tok, probs = sample_with_probs(
-                    logits[:, 0], temp, top_p, seed, ctr, stream=stream)
-                tok = with_stats(tok, vout)
-                if emit_probs:
-                    return tok, probs, vout["cache"]
-                return tok, vout["cache"]
+        def fwd_token(params, cache, tokens, positions, mask, last_idx,
+                      temp, top_p, seed, ctr, tables):
+            logits, vout = apply_model(params, cache, tokens, positions,
+                                       mask, tables, last_idx)
+            tok, probs = sample_with_probs(
+                logits[:, 0], temp, top_p, seed, ctr, stream=stream)
+            tok = with_stats(tok, vout)
+            if emit_probs:
+                return tok, probs, vout["cache"]
+            return tok, vout["cache"]
 
-            def fwd_verify(params, cache, tokens, positions, mask,
-                           temp, top_p, seed, ctr, dprobs, n_draft,
-                           tables):
-                logits, vout = apply_model(params, cache, tokens,
-                                           positions, mask, tables,
-                                           None)
-                emitted, n_acc = speculative_accept(
-                    tokens, dprobs, logits, n_draft, temp, top_p, seed,
-                    ctr)
-                return emitted, with_stats(n_acc, vout), vout["cache"]
-        else:
-            def fwd_token(params, cache, tokens, positions, mask,
-                          last_idx, temp, top_p, seed, ctr):
-                logits, vout = apply_model(params, cache, tokens,
-                                           positions, mask, None,
-                                           last_idx)
-                tok, probs = sample_with_probs(
-                    logits[:, 0], temp, top_p, seed, ctr, stream=stream)
-                if emit_probs:
-                    return tok, probs, vout["cache"]
-                return tok, vout["cache"]
-
-            def fwd_verify(params, cache, tokens, positions, mask,
-                           temp, top_p, seed, ctr, dprobs, n_draft):
-                logits, vout = apply_model(params, cache, tokens,
-                                           positions, mask, None, None)
-                emitted, n_acc = speculative_accept(
-                    tokens, dprobs, logits, n_draft, temp, top_p, seed,
-                    ctr)
-                return emitted, n_acc, vout["cache"]
+        def fwd_verify(params, cache, tokens, positions, mask, temp,
+                       top_p, seed, ctr, dprobs, n_draft, tables):
+            logits, vout = apply_model(params, cache, tokens, positions,
+                                       mask, tables, None)
+            emitted, n_acc = speculative_accept(
+                tokens, dprobs, logits, n_draft, temp, top_p, seed, ctr)
+            return emitted, with_stats(n_acc, vout), vout["cache"]
 
         # donating the cache lets XLA update it in place on TPU; CPU
         # does not support donation and would only warn
@@ -311,17 +284,17 @@ class ShardedExecutor:
         # the cache collection looks like; every model creates that
         # collection as zeros, so shapes and dtypes are all of it
         def make_cache(params, tokens, positions, mask, tables):
-            kw = {"block_tables": tables} if self.paged else {}
             _, v = self.model.apply(
                 {"params": params}, tokens, positions=positions,
-                update_mask=mask, mutable=["cache", "stats"], **kw)
+                update_mask=mask, mutable=["cache", "stats"],
+                block_tables=tables)
             return v
 
         S = jax.ShapeDtypeStruct
         traced, collections = jax.make_jaxpr(make_cache, return_shape=True)(
             params, S((max_batch, 1), jnp.int32), S((max_batch,), jnp.int32),
             S((max_batch,), bool),
-            S((max_batch, max(self.blocks_per_seq, 1)), jnp.int32))
+            S((max_batch, self.blocks_per_seq), jnp.int32))
         #: does the model sow step counters (see `with_stats`)? The
         #: collection is there or it is not
         self._has_stats = "stats" in collections
@@ -360,23 +333,17 @@ class ShardedExecutor:
             if placed else None)
         self.params = self._to_resident(params)
 
-        if self.paged:
-            # CoW block copy, jitted once (shapes are static): donation
-            # makes it an in-place pool write on TPU instead of a full
-            # pool copy per CoW
-            NB, BS = self.kv_pool_blocks, self.kv_block_size
+        # CoW block copy, jitted once (shapes are static): donation
+        # makes it an in-place pool write on TPU instead of a full
+        # pool copy per CoW
+        def copy_block(cache, src, dst):
+            return jax.tree_util.tree_map(
+                lambda leaf: leaf.at[dst].set(leaf[src])
+                if self._is_pool(leaf) else leaf, cache)
 
-            def copy_block(cache, src, dst):
-                def cp(leaf):
-                    if getattr(leaf, "ndim", 0) == 4 and \
-                            leaf.shape[0] == NB and leaf.shape[1] == BS:
-                        return leaf.at[dst].set(leaf[src])
-                    return leaf
-                return jax.tree_util.tree_map(cp, cache)
-
-            self._copy_block = jax.jit(
-                copy_block, donate_argnums=() if
-                jax.default_backend() == "cpu" else (0,))
+        self._copy_block = jax.jit(
+            copy_block, donate_argnums=() if
+            jax.default_backend() == "cpu" else (0,))
         #: params_version the most recent step actually ran under (set
         #: inside the step lock) — what lets the batcher detect a swap
         #: landing between its prefix-cache lookup and the prefill
@@ -388,16 +355,17 @@ class ShardedExecutor:
                   for dts in (self._given_dtypes, self._resident_dtypes)]
         logger.info(
             "serve executor (replica=%s role=%s): decode kernel=%s "
-            "paged=%s backend=%s; resident bytes %d -> %d, %d of %d "
-            "leaves cast", replica_id, role, self.kernel, self.paged,
+            "pool=%dx%d backend=%s; resident bytes %d -> %d, %d of %d "
+            "leaves cast", replica_id, role, self.kernel,
+            self.kv_pool_blocks, self.kv_block_size,
             jax.default_backend(), *nbytes, len(self._cast_idx),
             len(leaves))
         # one-shot KERNEL instant: names the RESOLVED decode kernel so
         # a silent fallback to XLA on TPU is visible in the trace
         if self.timeline is not None:
             self.timeline.instant("KERNEL", {
-                "kernel": self.kernel, "paged": self.paged,
-                "role": role, "backend": jax.default_backend()})
+                "kernel": self.kernel, "role": role,
+                "backend": jax.default_backend()})
 
     # -- the one step --------------------------------------------------------
     def _default_sample(self, B: int) -> Dict[str, np.ndarray]:
@@ -419,10 +387,10 @@ class ShardedExecutor:
 
         tokens [max_batch, T] int32; positions/last_idx [max_batch]
         int32; mask [max_batch] bool; block_tables
-        [max_batch, blocks_per_seq] int32 (paged executors only).
+        [max_batch, blocks_per_seq] int32, -1 for unassigned entries.
         ``sample`` carries the per-row sampling data (temperature /
         top_p / seed / ctr arrays, [max_batch] each); None is greedy.
-        A paged PREFILL may be row-compact: ``[rows, T]`` tokens with
+        A PREFILL may be row-compact: ``[rows, T]`` tokens with
         ``rows`` < max_batch and every per-row array (the block tables
         too) of that many rows: the pool is addressed through the
         tables alone, so a step's rows need not be the batch's (the
@@ -448,14 +416,13 @@ class ShardedExecutor:
         t0 = time.perf_counter()
         T = int(tokens.shape[1])
         self.signatures.add((kind, T))
-        if self.paged and block_tables is None:
-            raise ValueError("a paged executor step needs block_tables")
+        if block_tables is None:
+            raise ValueError("an executor step needs block_tables")
         B = int(tokens.shape[0])
-        if B != self.max_batch and not (self.paged and kind == "prefill"):
+        if B != self.max_batch and kind != "prefill":
             raise ValueError(
                 f"{kind} step of {B} rows on an executor of "
-                f"{self.max_batch}: only a paged prefill may be "
-                f"row-compact")
+                f"{self.max_batch}: only a prefill may be row-compact")
         n_tok = int(np.sum(mask))
         rec = _trace_recorder()
         probs = None
@@ -489,8 +456,7 @@ class ShardedExecutor:
                          jnp.asarray(s["top_p"], jnp.float32),
                          jnp.asarray(s["seed"], jnp.uint32),
                          jnp.asarray(s["ctr"], jnp.int32)] + tail
-                if self.paged:
-                    args.append(jnp.asarray(block_tables, jnp.int32))
+                args.append(jnp.asarray(block_tables, jnp.int32))
             with self._swap_lock:   # the weight-swap version fence
                 self.last_step_version = self.params_version
                 fwd = self._fwd_verify if kind == "verify" \
@@ -618,42 +584,28 @@ class ShardedExecutor:
         return True
 
     # -- KV integrity hooks (serve.kv chaos + crc option) --------------------
-    def _cache_leaves(self) -> list:
-        """The device KV arrays inside the flax cache collection, in
-        flatten order: every ``[max_batch, L, H_kv, D]`` slotted leaf —
-        or, for a paged executor, every ``[pool_blocks, block_size,
-        H_kv, D]`` pool leaf — (cache_k and cache_v of each layer)."""
-        leaves = jax.tree_util.tree_leaves(self.cache)
-        if self.paged:
-            return [l for l in leaves
-                    if getattr(l, "ndim", 0) == 4
-                    and l.shape[0] == self.kv_pool_blocks
-                    and l.shape[1] == self.kv_block_size]
-        return [l for l in leaves
-                if getattr(l, "ndim", 0) == 4
-                and l.shape[0] == self.max_batch]
+    def _is_pool(self, leaf) -> bool:
+        """Is this leaf of the cache collection a KV pool,
+        ``[pool_blocks, block_size, H_kv, D]``?"""
+        return getattr(leaf, "ndim", 0) == 4 and leaf.shape[:2] == (
+            self.kv_pool_blocks, self.kv_block_size)
 
-    def kv_slot_bytes(self, slot: int, start: int,
-                      stop: int) -> list:
-        """Host bytes of positions ``[start, stop)`` of ``slot``'s row
-        in each cache leaf (leaf order) — what the per-slot crc ledger
-        (SlotKVCache.crc_update/crc_check) streams over. Decode reads
-        one position; the verify-on-read pass re-reads the whole valid
-        prefix once per retiring request. Reads under the step lock:
-        off CPU the step DONATES the cache, so a reader on another
-        thread (the migration endpoint) must never hold leaves across
-        a step."""
-        with self._swap_lock:
-            return [np.asarray(l[slot, start:stop]).tobytes()
-                    for l in self._cache_leaves()]
+    def _cache_leaves(self) -> list:
+        """The device KV pools inside the flax cache collection, in
+        flatten order (cache_k and cache_v of each layer)."""
+        return [l for l in jax.tree_util.tree_leaves(self.cache)
+                if self._is_pool(l)]
 
     def kv_block_bytes(self, block: int, start: int,
                        stop: int) -> list:
-        """Paged sibling of :meth:`kv_slot_bytes`: host bytes of
-        positions ``[start, stop)`` of pool block ``block`` in each
-        cache leaf — what the per-BLOCK crc ledger
-        (BlockPool.crc_stream/crc_check) runs over. Under the step
-        lock, like :meth:`kv_slot_bytes`."""
+        """Host bytes of positions ``[start, stop)`` of pool block
+        ``block`` in each cache leaf (leaf order) — what the per-BLOCK
+        crc ledger (BlockPool.crc_stream/crc_check) runs over. Decode
+        reads one position; the verify-on-read pass re-reads each
+        block's written prefix once per retiring request. Reads under
+        the step lock: off CPU the step DONATES the cache, so a reader
+        on another thread (the migration endpoint) must never hold
+        leaves across a step."""
         with self._swap_lock:
             return [np.asarray(l[block, start:stop]).tobytes()
                     for l in self._cache_leaves()]
@@ -664,8 +616,6 @@ class ShardedExecutor:
         sharing (serve/prefix.py). One precompiled program; call once
         from warmup so the first divergent prompt never meets a
         compile."""
-        if not self.paged:
-            raise RuntimeError("copy_kv_block is paged-only")
         with self._swap_lock:   # never tear a step in flight
             self.cache = self._copy_block(
                 self.cache, jnp.asarray(src, jnp.int32),
@@ -687,17 +637,12 @@ class ShardedExecutor:
         validated against the leaf dtype/shape before anything lands,
         and the write runs under the swap lock so it can never tear a
         step in flight."""
-        if not self.paged:
-            raise RuntimeError("install_kv_blocks is paged-only")
         if not blocks:
             return
         bs = self.kv_block_size
         with self._swap_lock:
             leaves, treedef = jax.tree_util.tree_flatten(self.cache)
-            idxs = [i for i, l in enumerate(leaves)
-                    if getattr(l, "ndim", 0) == 4
-                    and l.shape[0] == self.kv_pool_blocks
-                    and l.shape[1] == bs]
+            idxs = [i for i, l in enumerate(leaves) if self._is_pool(l)]
             if any(len(lb) != len(idxs) for lb in block_leaf_bytes):
                 raise ValueError(
                     f"install_kv_blocks: payload leaf counts "
@@ -727,38 +672,16 @@ class ShardedExecutor:
                 leaves[i] = leaf.at[ids].set(jnp.asarray(stacked))
             self.cache = jax.tree_util.tree_unflatten(treedef, leaves)
 
-    def corrupt_kv_slot(self, slot: int, length: int) -> None:
-        """Flip one deterministically chosen bit inside ``slot``'s
-        valid cache prefix — the chaos ``serve.kv`` fault body. Real
-        device bytes change, so detection must come from the crc
-        ledger, not from bookkeeping."""
-        from ..chaos import inject as _chaos
-        with self._swap_lock:   # never tear a step in flight
-            leaves, treedef = jax.tree_util.tree_flatten(self.cache)
-            idx = next(i for i, l in enumerate(leaves)
-                       if getattr(l, "ndim", 0) == 4
-                       and l.shape[0] == self.max_batch)
-            row = np.array(leaves[idx][slot, :length])
-            flipped = np.frombuffer(
-                _chaos.corrupt_copy(row.tobytes()),
-                dtype=row.dtype).reshape(row.shape)
-            leaves[idx] = leaves[idx].at[slot, :length].set(
-                jnp.asarray(flipped))
-            self.cache = jax.tree_util.tree_unflatten(treedef, leaves)
-
     def corrupt_kv_block(self, block: int, length: int) -> None:
-        """Paged ``serve.kv`` fault body: flip one bit inside the first
-        ``length`` positions of pool block ``block`` — real device
-        bytes, caught only by the per-block crc ledger."""
+        """The chaos ``serve.kv`` fault body: flip one deterministically
+        chosen bit inside the first ``length`` positions of pool block
+        ``block`` — real device bytes change, so detection must come
+        from the per-block crc ledger, not from bookkeeping."""
         from ..chaos import inject as _chaos
-        if not self.paged:
-            raise RuntimeError("corrupt_kv_block is paged-only")
         with self._swap_lock:
             leaves, treedef = jax.tree_util.tree_flatten(self.cache)
             idx = next(i for i, l in enumerate(leaves)
-                       if getattr(l, "ndim", 0) == 4
-                       and l.shape[0] == self.kv_pool_blocks
-                       and l.shape[1] == self.kv_block_size)
+                       if self._is_pool(l))
             row = np.array(leaves[idx][block, :length])
             flipped = np.frombuffer(
                 _chaos.corrupt_copy(row.tobytes()),
@@ -791,9 +714,8 @@ class ShardedExecutor:
         args = [self.params, self.cache, jnp.zeros((B, 1), jnp.int32), zi,
                 jnp.zeros((B,), bool), zi,
                 jnp.asarray(s["temperature"]), jnp.asarray(s["top_p"]),
-                jnp.asarray(s["seed"]), jnp.asarray(s["ctr"])]
-        if self.paged:
-            args.append(jnp.full((B, self.blocks_per_seq), -1, jnp.int32))
+                jnp.asarray(s["seed"]), jnp.asarray(s["ctr"]),
+                jnp.full((B, self.blocks_per_seq), -1, jnp.int32)]
         return self._fwd_token.lower(*args).as_text()
 
     def jit_cache_size(self) -> int:

@@ -286,7 +286,7 @@ def aggregate_healthz(replicas_info: Dict[int, dict], *,
 
     ``replicas_info[rid]`` supplies ``state``/``up``/``draining``/
     ``queue_depth``/``weights_version``/``restarts``/``queue_free``
-    and, when paged, ``kv_blocks_total``/``kv_blocks_in_use`` plus the
+    and, while up, ``kv_blocks_total``/``kv_blocks_in_use`` plus the
     prefix cache's ``prefix_tokens_resident``/
     ``prefix_tokens_evictable`` TOKEN counts (the fleet KV tier's and
     the autoscale signals' shared definition of cacheable capacity —
@@ -602,10 +602,10 @@ class FleetRouter:
     def _candidates(self, exclude: Optional[int] = None) -> List[Replica]:
         """Healthy replicas, least-loaded first — load is waiting PLUS
         in-flight, so a replica that drains its queue into the batch
-        instantly doesn't look idle. The in-flight unit is whatever
-        actually limits the replica's capacity: live KV slots when
-        slotted, BLOCKS in use (tokens resident, row-normalized) when
-        paged — see ``ContinuousBatcher.load``. Ties break to the
+        instantly doesn't look idle. The in-flight unit is what
+        actually limits the replica's capacity: KV BLOCKS in use
+        (tokens resident, row-normalized) — see
+        ``ContinuousBatcher.load``. Ties break to the
         lowest id (deterministic)."""
         out = [r for r in self.replicas.values()
                if r.state == "up" and r.id != exclude
@@ -991,7 +991,7 @@ class FleetRouter:
                 "restarts": rep.restarts,
                 "queue_free": max(rep.max_queue - depth, 0),
             }
-            if up and getattr(b, "paged", False):
+            if up:
                 info["kv_blocks_total"] = b.kv.pool.num_blocks
                 info["kv_blocks_in_use"] = b.kv.pool.in_use()
                 if b.prefix is not None:

@@ -154,17 +154,15 @@ def make_server(batcher, host: str = "127.0.0.1",
                     "draining": draining,
                     "occupancy": round(batcher.kv.occupancy(), 3),
                     "tokens_per_s": round(ex.tokens_per_s(), 1),
-                    "iterations": batcher.iterations}
-            if getattr(batcher, "paged", False):
-                # paged occupancy above is tokens-resident (pool
-                # blocks); surface the raw block counts and the prefix
-                # cache's sharing yield next to it
-                info["kv_blocks_in_use"] = batcher.kv.pool.in_use()
-                info["kv_blocks_total"] = batcher.kv.pool.num_blocks
-                if batcher.prefix is not None:
-                    info["prefix_hits"] = batcher.prefix.hits
-                    info["prefix_tokens_saved"] = \
-                        batcher.prefix.tokens_saved
+                    "iterations": batcher.iterations,
+                    # the occupancy above is tokens-resident (pool
+                    # blocks); the raw block counts and the prefix
+                    # cache's sharing yield sit next to it
+                    "kv_blocks_in_use": batcher.kv.pool.in_use(),
+                    "kv_blocks_total": batcher.kv.pool.num_blocks}
+            if batcher.prefix is not None:
+                info["prefix_hits"] = batcher.prefix.hits
+                info["prefix_tokens_saved"] = batcher.prefix.tokens_saved
             info.update(queue.counters())
             self._reply(200 if up else 503, info)
 

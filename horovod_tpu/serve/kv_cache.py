@@ -1,33 +1,33 @@
-"""KV-cache memory managers: slotted rows and paged blocks.
+"""KV-cache memory manager: one storage format, the block pool.
 
 Orca/vLLM-style continuous batching needs per-sequence key/value state
 that outlives any single forward call and can be handed to a *different*
-sequence the moment its owner retires. Two storage layouts live here:
+sequence the moment its owner retires. The device arrays are a pool
+``[num_blocks, block_size, H_kv, D]`` and each sequence owns an ordered
+*block table* of pool indices (`write_kv_paged`, `paged_attention`,
+`BlockPool`, `PagedKVCache`). Virtual position ``p`` of a sequence
+lives at ``pool[table[p // bs], p % bs]``; attention gathers the table
+and applies a positional validity mask, so occupancy is bounded by
+**tokens resident** (blocks actually allocated), not ``rows x
+max_len``. Blocks are refcounted, which is what lets the radix prefix
+cache (serve/prefix.py) share read-only prompt-prefix runs across
+sequences.
 
-1. **Slotted** (`write_kv`, `cached_attention`, `SlotKVCache`): the
-   original layout — one ``[num_slots, max_len, H_kv, D]`` row per
-   in-flight sequence, written in place at per-row offsets and read
-   back under a per-row validity mask. Simple, but occupancy is
-   ``slots x max_len`` regardless of how many tokens are resident.
-2. **Paged** (`write_kv_paged`, `paged_attention`, `BlockPool`,
-   `PagedKVCache`): vLLM-style block storage — the device arrays are a
-   pool ``[num_blocks, block_size, H_kv, D]`` and each sequence owns an
-   ordered *block table* of pool indices. Virtual position ``p`` of a
-   sequence lives at ``pool[table[p // bs], p % bs]``; attention
-   gathers the table and applies the same positional validity mask, so
-   occupancy is bounded by **tokens resident** (blocks actually
-   allocated), not ``slots x max_len``. Blocks are refcounted, which is
-   what lets the radix prefix cache (serve/prefix.py) share read-only
-   prompt-prefix runs across sequences.
+A cache with one fixed ``max_len`` row a sequence is the special case
+of a pool as large as the worst case read through a table that never
+changes: what a model config without pool sizes gets (the executor
+works the size out, serve/executor.py) and how the speculative drafter
+reads its cache (serve/batcher.py).
 
 Shapes never depend on which rows/blocks are live — liveness is data
 (masks, tables, positions), so jit compiles each program exactly once
 (the no-recompile contract, docs/serving.md).
 
 The device arrays themselves live in the model's flax ``"cache"``
-collection (models/gpt.py, models/llama.py decode paths) and are
-threaded through the executor (serve/executor.py); this module holds no
-jax arrays of its own.
+collection (`pool_attention`, called by the decode paths of
+models/gpt.py and models/llama.py) and are threaded through the
+executor (serve/executor.py); this module holds no jax arrays of its
+own.
 """
 from __future__ import annotations
 
@@ -38,45 +38,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+#: tokens a pool block holds where a decode-mode model config names no
+#: size of its own (the GPT-2 serve cell's)
+KV_BLOCK_SIZE = 16
+
 #: additive mask for invalid key positions — large-negative rather than
-#: -inf so fully-masked garbage rows (inactive slots) still softmax to
+#: -inf so fully-masked garbage rows (inactive rows) still softmax to
 #: finite numbers instead of NaN
 _MASK_VALUE = -1e30
-
-
-def write_kv(cache_k: jax.Array, cache_v: jax.Array, k_new: jax.Array,
-             v_new: jax.Array, positions: jax.Array,
-             update_mask: jax.Array):
-    """Write `T` new K/V vectors per row at that row's offset.
-
-    cache_k/cache_v: [B, max_len, H_kv, D]; k_new/v_new: [B, T, H_kv, D];
-    positions: [B] int32 write offsets; update_mask: [B] bool — rows with
-    False keep their cache untouched (slots owned by OTHER sequences
-    during a prefill of newly admitted ones, or free slots).
-    Returns the updated (cache_k, cache_v).
-    """
-    def upd(c, u, p):
-        return jax.lax.dynamic_update_slice(c, u.astype(c.dtype), (p, 0, 0))
-
-    nk = jax.vmap(upd)(cache_k, k_new, positions)
-    nv = jax.vmap(upd)(cache_v, v_new, positions)
-    m = update_mask[:, None, None, None]
-    return jnp.where(m, nk, cache_k), jnp.where(m, nv, cache_v)
-
-
-def cached_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
-                     positions: jax.Array) -> jax.Array:
-    """Causal attention of `T` query tokens over each row's cache prefix.
-
-    q: [B, T, H, D]; cache_k/cache_v: [B, max_len, H_kv, D] (GQA: kv
-    heads are broadcast locally, H % H_kv == 0); positions: [B] — query
-    token t of row i sits at absolute position positions[i] + t and may
-    attend cache entries [0, positions[i] + t]. Call AFTER write_kv so a
-    token attends to itself. Softmax runs in f32 with a large-negative
-    additive mask; stale bytes past the valid prefix (slot-reuse
-    leftovers) are unreachable by construction.
-    """
-    return masked_attention(q, cache_k, cache_v, positions)
 
 
 def masked_attention(q: jax.Array, keys: jax.Array, vals: jax.Array,
@@ -90,10 +59,10 @@ def masked_attention(q: jax.Array, keys: jax.Array, vals: jax.Array,
     ``[0, positions[b] + t]`` only (with a ``window``, the newest
     ``window`` of them); f32 score math, divide-after-dot
     ``1/sqrt(D)`` scaling, large-negative additive masking, output cast
-    back to ``q.dtype``. `cached_attention` (slotted), `paged_attention`
-    (the gathered-pool XLA path) and the models' decode attention all
-    delegate here, and the fused Pallas kernels
-    (ops/pallas_paged.py) mirror this math operation-for-operation —
+    back to ``q.dtype``. `paged_attention` (the gathered-pool XLA path)
+    and through it the models' decode attention delegate here, and the
+    fused Pallas kernels (ops/pallas_paged.py) mirror this math
+    operation-for-operation —
     it is the bit-exactness ORACLE the interpret-mode parity suite
     asserts against (tests/test_serve_kernels.py).
 
@@ -131,10 +100,6 @@ def masked_attention(q: jax.Array, keys: jax.Array, vals: jax.Array,
     return out.astype(q.dtype)
 
 
-#: back-compat alias (pre-PR-12 private name)
-_masked_attention = masked_attention
-
-
 # -- paged (block) storage ---------------------------------------------------
 
 def write_kv_paged(pool_k: jax.Array, pool_v: jax.Array, k_new: jax.Array,
@@ -148,9 +113,8 @@ def write_kv_paged(pool_k: jax.Array, pool_v: jax.Array, k_new: jax.Array,
     ``(block_tables[b, p // bs], p % bs)``; block_tables:
     [B, blocks_per_seq] int32, -1 for unassigned entries. Writes whose
     row mask is False, whose virtual position runs past the table, or
-    whose table entry is -1 are DROPPED (never land anywhere) — the
-    paged analog of the slotted update_mask discipline, which is what
-    keeps bucket-padding garbage out of other sequences' blocks.
+    whose table entry is -1 are DROPPED (never land anywhere), which is
+    what keeps bucket-padding garbage out of other sequences' blocks.
     Returns the updated (pool_k, pool_v).
     """
     NB, BS = pool_k.shape[0], pool_k.shape[1]
@@ -183,7 +147,7 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
 
     Gathers each row's blocks into a contiguous
     ``[B, blocks_per_seq * block_size, H_kv, D]`` view and applies the
-    same positional validity mask as the slotted read. Unassigned table
+    positional validity mask of `masked_attention`. Unassigned table
     entries (-1) are sanitized to block 0; whatever they gather is
     unreachable — a sequence's valid prefix never extends past its
     assigned blocks. ``window``: each query sees its newest ``window``
@@ -197,145 +161,47 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     return masked_attention(q, keys, vals, positions, window)
 
 
+def pool_attention(module, cfg, q: jax.Array, k: jax.Array, v: jax.Array,
+                   positions: jax.Array, update_mask: jax.Array,
+                   block_tables: jax.Array) -> jax.Array:
+    """One layer's decode attention over its block pool — the body
+    models/gpt.py and models/llama.py share: the layer's K and V pools
+    (``[kv_pool_blocks, kv_block_size, H_kv, D]``, in the ``"cache"``
+    collection of the flax ``module`` that calls this), the ``T`` new
+    tokens written through the tables, then each row's attention over
+    its blocks by the kernel the executor resolved
+    (``cfg.decode_kernel``: the fused Pallas kernel, or
+    `paged_attention`). q: [B, T, H, D]; k/v: [B, T, H_kv, D]."""
+    if block_tables is None:
+        raise ValueError(
+            "decode needs per-row `block_tables` "
+            "(see horovod_tpu/serve/executor.py)")
+    if cfg.kv_pool_blocks < 1:
+        raise ValueError(
+            "kv_pool_blocks is not set: name it in the model config, "
+            "or build the model's ShardedExecutor first (it sizes the "
+            "pool for max_batch x max_len)")
+    pool = (cfg.kv_pool_blocks, cfg.kv_block_size) + k.shape[2:]
+    ck = module.variable("cache", "k", jnp.zeros, pool, cfg.dtype)
+    cv = module.variable("cache", "v", jnp.zeros, pool, cfg.dtype)
+    ck.value, cv.value = write_kv_paged(
+        ck.value, cv.value, k, v, positions, update_mask, block_tables)
+    if cfg.decode_kernel == "pallas":
+        from ..ops.pallas_paged import paged_attention_fused
+        return paged_attention_fused(q, ck.value, cv.value, block_tables,
+                                     positions)
+    return paged_attention(q, ck.value, cv.value, block_tables, positions)
+
+
 def pool_blocks_for(max_batch: int, max_len: int, block_size: int,
                     fraction: float = 0.5) -> int:
-    """A sane device pool size: ``fraction`` of the slotted layout's
+    """A sane device pool size: ``fraction`` of the
     ``max_batch x max_len`` worst case (the whole point of paging is to
     provision for tokens actually resident), floored so every row can
     hold at least one block plus headroom for a shared prefix run."""
     worst = max_batch * -(-max_len // block_size)
     want = int(worst * fraction)
     return max(want, 2 * max_batch, -(-max_len // block_size) + max_batch)
-
-
-def paged_model_kwargs(max_batch: int, max_len: int, *, config=None,
-                       fraction: float = 0.5) -> dict:
-    """The HOROVOD_SERVE_KV_BLOCK knob's one consumer: model-config
-    kwargs for the serving layout the environment asks for — ``{}``
-    when the knob is 0 (slotted), else ``kv_block_size`` plus a
-    :func:`pool_blocks_for`-provisioned ``kv_pool_blocks``. The model
-    config stays authoritative (the pool shape is static and compiles
-    into every serving program); this is the one place the env knob
-    becomes device-array shapes::
-
-        cfg = GPTConfig(decode=True, **kw,
-                        **paged_model_kwargs(max_batch, max_len))
-    """
-    if config is None:
-        from ..core.config import Config
-        config = Config.from_env()
-    bs = int(config.serve_kv_block)
-    if bs <= 0:
-        return {}
-    return {"kv_block_size": bs,
-            "kv_pool_blocks": pool_blocks_for(max_batch, max_len, bs,
-                                              fraction)}
-
-
-class SlotKVCache:
-    """Host-side slot manager: free list + per-slot length accounting.
-
-    One instance per batcher; `num_slots` equals the executor's fixed
-    decode batch (HOROVOD_SERVE_MAX_BATCH). Occupancy / reuse counters
-    feed the SERVE timeline row and the /healthz payload.
-    """
-
-    def __init__(self, num_slots: int, max_len: int):
-        if num_slots < 1:
-            raise ValueError(f"num_slots must be >= 1; got {num_slots}")
-        if max_len < 1:
-            raise ValueError(f"max_len must be >= 1; got {max_len}")
-        self.num_slots = num_slots
-        self.max_len = max_len
-        # LIFO reuse: the most recently freed slot is re-issued first,
-        # keeping the hot rows hot
-        self._free: List[int] = list(range(num_slots))[::-1]
-        #: tokens written into each slot's cache row (the valid prefix)
-        self.lengths = np.zeros(num_slots, dtype=np.int32)
-        self.active = np.zeros(num_slots, dtype=bool)
-        #: times each slot has been (re)allocated — the reuse ledger
-        self.generation = np.zeros(num_slots, dtype=np.int64)
-        self.allocs = 0
-        self.frees = 0
-        self.peak_live = 0
-        #: per-slot streamed crc32 of the cache bytes written so far,
-        #: one running value PER CACHE LEAF (k/v x layer — write order
-        #: within one leaf is positional, so streaming holds per leaf
-        #: but not across leaves). Populated only when the batcher runs
-        #: with kv_crc enabled; the chaos serve.kv corrupt fault is
-        #: what this must catch (docs/serving.md).
-        self._crc: Dict[int, List[int]] = {}
-        #: per-slot high-water mark of positions the ledger covers —
-        #: what lets verify-on-read know how far to re-read when the
-        #: speculative verify step wrote past the accepted prefix
-        self._crc_filled: Dict[int, int] = {}
-
-    # -- per-slot integrity (crc-on-write / verify-on-read option) ----------
-    def crc_filled(self, slot: int) -> int:
-        return self._crc_filled.get(slot, 0)
-
-    def crc_update(self, slot: int, leaf_bytes: Sequence[bytes],
-                   new_filled: Optional[int] = None) -> None:
-        """Fold the bytes just written to ``slot`` (one entry per cache
-        leaf, in leaf order) into the slot's running crc32s. The caller
-        guarantees the bytes extend the stream contiguously;
-        ``new_filled`` records the covered prefix length."""
-        cur = self._crc.get(slot)
-        if cur is None:
-            cur = self._crc[slot] = [0] * len(leaf_bytes)
-        for i, raw in enumerate(leaf_bytes):
-            cur[i] = zlib.crc32(raw, cur[i])
-        if new_filled is not None:
-            self._crc_filled[slot] = new_filled
-
-    def crc_reset(self, slot: int, leaf_bytes: Sequence[bytes],
-                  filled: int) -> None:
-        """Recompute the ledger from a full re-read of positions
-        [0, filled) — the speculative-rollback path (an overwrite below
-        the high-water mark breaks the append-only stream)."""
-        self._crc[slot] = [zlib.crc32(raw) for raw in leaf_bytes]
-        self._crc_filled[slot] = filled
-
-    def crc_check(self, slot: int, leaf_bytes: Sequence[bytes]) -> bool:
-        """Verify a full re-read of ``slot``'s valid prefix (one entry
-        per cache leaf) against the streamed write-side crc32s. True
-        when every leaf matches; a slot never written checks clean."""
-        cur = self._crc.get(slot)
-        if cur is None:
-            return True
-        return len(cur) == len(leaf_bytes) and all(
-            zlib.crc32(raw) == c for raw, c in zip(leaf_bytes, cur))
-
-    def alloc(self) -> Optional[int]:
-        """Claim a free slot (None when all are live). The new owner's
-        length starts at 0; stale cache bytes need no clearing (masked
-        out by `cached_attention`)."""
-        if not self._free:
-            return None
-        slot = self._free.pop()
-        self.active[slot] = True
-        self.lengths[slot] = 0
-        self.generation[slot] += 1
-        self.allocs += 1
-        self._crc.pop(slot, None)   # the new owner's ledger starts empty
-        self._crc_filled.pop(slot, None)
-        self.peak_live = max(self.peak_live, self.live())
-        return slot
-
-    def free(self, slot: int) -> None:
-        if not self.active[slot]:
-            raise ValueError(f"slot {slot} is not live")
-        self.active[slot] = False
-        self.lengths[slot] = 0
-        self._free.append(slot)
-        self.frees += 1
-
-    def live(self) -> int:
-        return self.num_slots - len(self._free)
-
-    def occupancy(self) -> float:
-        """Live slots / total slots — the batch-occupancy counter."""
-        return self.live() / self.num_slots
 
 
 class BlockPool:
@@ -347,8 +213,7 @@ class BlockPool:
     only when the last reference drops, so a shared system-prompt run
     can never be handed to a new owner while anyone still reads it.
 
-    Also owns the per-BLOCK crc ledger (the PR 8 per-slot ledger moved
-    to block granularity): one running crc32 per cache leaf per block
+    Also owns the per-BLOCK crc ledger: one running crc32 per cache leaf per block
     over the block's written prefix (``filled`` positions). Keyed by
     pool index, so a shared block carries ONE ledger entry no matter
     how many sequences reference it, and verify-on-read of a sequence
@@ -362,7 +227,7 @@ class BlockPool:
             raise ValueError(f"block_size must be >= 1; got {block_size}")
         self.num_blocks = num_blocks
         self.block_size = block_size
-        # LIFO reuse, same rationale as SlotKVCache
+        # LIFO reuse: the most recently freed block is re-issued first
         self._free: List[int] = list(range(num_blocks))[::-1]
         self.refcount = np.zeros(num_blocks, dtype=np.int32)
         self.allocs = 0
@@ -467,7 +332,7 @@ class PagedKVCache:
     sequence can never hit an empty pool mid-decode: the admission gate
     (`can_admit`) only opens when free + evictable blocks cover every
     outstanding reservation plus the newcomer. Peak bytes resident
-    still track blocks actually allocated — tokens, not slots x
+    still track blocks actually allocated — tokens, not rows x
     max_len.
 
     ``evictor`` (set by the batcher) is asked to release prefix-cache
@@ -603,7 +468,3 @@ class PagedKVCache:
         block-occupancy gauge exports (NOT a row count: rows are free,
         memory is not)."""
         return self.pool.occupancy()
-
-    @property
-    def num_slots(self) -> int:   # row-capacity view (fleet/http compat)
-        return self.num_rows

@@ -7,8 +7,7 @@ GPT, drives CLOSED-LOOP synthetic traffic at a fixed offered load
 (``clients`` concurrent requesters, each with at most one request
 outstanding), and fires a seeded serve-profile chaos plan at it —
 one replica crashed mid-decode, a second partitioned from the router,
-a KV block corrupted (a slot when running the slotted layout), one
-replica slowed past the suspect threshold, one admission dropped at
+a KV block corrupted, one replica slowed past the suspect threshold, one admission dropped at
 the queue door — while a training-side
 :class:`~horovod_tpu.redist.stream.WeightPublisher` pushes a fresh
 weight version mid-incident. The verdict (a JSON-able dict,
@@ -22,8 +21,7 @@ invariant holds) asserts:
   <= 1 on every handle; late ghost answers are counted as suppressed
   duplicates, not deliveries);
 * **KV containment** — the injected cache corruption was caught by the
-  crc ledger (per-BLOCK when paged, per-slot when slotted;
-  ``detected >= injected > 0``): a corrupted sequence re-prefills or
+  per-BLOCK crc ledger (``detected >= injected > 0``): a corrupted sequence re-prefills or
   fails cleanly, never returns garbage;
 * **bounded failover** — the crashed replica was ejected within
   ``2 x suspect_s`` of the crash (detection in O(heartbeat), not
@@ -471,7 +469,7 @@ def run_disagg_soak(out_dir: Optional[str] = None, *,
             events.append(dict(ev, kind=kind))
 
     srv = StoreServer()
-    built = tiny_gpt_builder(seed=seed, paged=True, draft=spec_k > 0)
+    built = tiny_gpt_builder(seed=seed, draft=spec_k > 0)
     pub = WeightPublisher(channel, kv_addr="127.0.0.1",
                           kv_port=srv.port, resume_timeout=0.05)
     pub.publish(built["params"])              # version 1, pre-incident
@@ -508,8 +506,7 @@ def run_disagg_soak(out_dir: Optional[str] = None, *,
     try:
         worker = {
             "builder": "horovod_tpu.serve.worker:tiny_gpt_builder",
-            "builder_kwargs": {"seed": seed, "paged": True,
-                               "draft": spec_k > 0},
+            "builder_kwargs": {"seed": seed, "draft": spec_k > 0},
             "buckets": [8], "max_queue": max(32, 4 * clients),
             "deadline_ms": deadline_ms,
             "kv_crc": True if kv_crc is None else kv_crc,
@@ -785,7 +782,6 @@ def run_fleet_soak(out_dir: Optional[str] = None, *,
                    max_new_tokens: int = 8,
                    deadline_ms: float = 20000.0,
                    spec_k: int = 0,
-                   paged: bool = True,
                    kv_crc: Optional[bool] = None,
                    prefix_cache: Optional[bool] = None,
                    spawn_timeout_s: float = 120.0) -> dict:
@@ -831,8 +827,7 @@ def run_fleet_soak(out_dir: Optional[str] = None, *,
     # the publisher derives the SAME params every worker builds
     # (deterministic per seed) — v1 lands before any worker spawns, so
     # every startup passes the weight gate against a live channel
-    built = tiny_gpt_builder(seed=seed, paged=paged,
-                             draft=spec_k > 0)
+    built = tiny_gpt_builder(seed=seed, draft=spec_k > 0)
     pub = WeightPublisher(channel, kv_addr="127.0.0.1",
                           kv_port=srv.port, resume_timeout=0.05)
     pub.publish(built["params"])              # version 1, pre-incident
@@ -841,13 +836,12 @@ def run_fleet_soak(out_dir: Optional[str] = None, *,
         replicas, kv_addr="127.0.0.1", kv_port=srv.port,
         worker={
             "builder": "horovod_tpu.serve.worker:tiny_gpt_builder",
-            "builder_kwargs": {"seed": seed, "paged": paged,
-                               "draft": spec_k > 0},
+            "builder_kwargs": {"seed": seed, "draft": spec_k > 0},
             "buckets": [8], "max_queue": max(32, 4 * clients),
             "deadline_ms": deadline_ms,
             "kv_crc": True if kv_crc is None else kv_crc,
             "spec_k": spec_k,
-            "prefix_cache": paged if prefix_cache is None
+            "prefix_cache": True if prefix_cache is None
             else prefix_cache},
         channel=channel, ns=f"soak{seed}", interval_s=interval_s,
         suspect_s=suspect_s, chaos_plan=resolved,
@@ -911,7 +905,7 @@ def run_fleet_soak(out_dir: Optional[str] = None, *,
             min_duration_s=min_duration_s,
             max_duration_s=max_duration_s,
             max_new_tokens=max_new_tokens, deadline_ms=deadline_ms,
-            spec_k=spec_k, paged=paged)
+            spec_k=spec_k)
     finally:
         _teardown()
 
@@ -921,7 +915,7 @@ def _fleet_soak_body(router, resolved, events, records, ev_lock,
                      stop, teardown, *, replicas, clients, suspect_s,
                      slo_p99_ms, slo_error_rate, recovery_window_s,
                      min_duration_s, max_duration_s, max_new_tokens,
-                     deadline_ms, spec_k, paged) -> dict:
+                     deadline_ms, spec_k) -> dict:
     """The guarded body of :func:`run_fleet_soak` — every exit path
     runs the caller's teardown (worker processes must never outlive
     the soak)."""
@@ -1080,7 +1074,7 @@ def _fleet_soak_body(router, resolved, events, records, ev_lock,
     verdict.update({
         "seed": resolved.seed, "replicas": replicas,
         "clients": clients, "processes": True,
-        "paged": bool(paged), "spec_k": int(spec_k),
+        "spec_k": int(spec_k),
         "suspect_s": suspect_s,
         "wall_s": round(time.monotonic() - t_start, 2),
         "plan": json.loads(resolved.to_json()),
@@ -1113,7 +1107,6 @@ def run_serve_soak(out_dir: Optional[str] = None, *,
                    max_new_tokens: int = 8,
                    deadline_ms: float = 20000.0,
                    kv_crc: Optional[bool] = None,
-                   paged: bool = True,
                    prefix_cache: Optional[bool] = None,
                    spec_k: int = 3,
                    sigterm_drain: bool = False) -> dict:
@@ -1136,7 +1129,7 @@ def run_serve_soak(out_dir: Optional[str] = None, *,
     if kv_crc is None:
         kv_crc = True   # the corrupt invariant NEEDS the crc ledger
     if prefix_cache is None:
-        prefix_cache = paged   # paged-only feature
+        prefix_cache = True
     resolved = _resolve_plan(plan, seed, replicas, steps)
 
     # -- tiny decode-mode model: identical params on every replica.
@@ -1149,8 +1142,8 @@ def run_serve_soak(out_dir: Optional[str] = None, *,
     kw = dict(vocab_size=64, num_layers=2, num_heads=2, head_dim=8,
               max_seq_len=48, dtype=jnp.float32,
               attention_impl="reference")
-    paged_kw = dict(kv_block_size=4, kv_pool_blocks=32) if paged else {}
-    model = GPT(GPTConfig(decode=True, **kw, **paged_kw))
+    model = GPT(GPTConfig(decode=True, **kw, kv_block_size=4,
+                          kv_pool_blocks=32))
     params = GPT(GPTConfig(**kw)).init(
         jax.random.PRNGKey(seed), jnp.zeros((2, 8), jnp.int32))["params"]
     # the drafter shares the target's params (a perfectly distilled
@@ -1344,13 +1337,13 @@ def run_serve_soak(out_dir: Optional[str] = None, *,
     verdict.update({
         "seed": resolved.seed, "replicas": replicas,
         "clients": clients, "kv_crc": bool(kv_crc),
-        "paged": bool(paged), "prefix_cache": bool(prefix_cache),
+        "prefix_cache": bool(prefix_cache),
         "spec_k": int(spec_k),
         "prefix_hits": prefix_hits,
         "prefix_tokens_saved": prefix_saved,
         # target steps per generated token since the LAST rebuild of
-        # each surviving batcher — informational; the bench gate is
-        # where the < 0.7 bound is asserted
+        # each surviving batcher — informational; the < 0.7 bound is
+        # asserted by tests/test_serve_paged.py
         "target_steps_per_token": (
             round(spec_steps / spec_tokens, 3) if spec_tokens else None),
         "suspect_s": suspect_s,
@@ -1565,7 +1558,7 @@ def run_autoscale_soak(out_dir: Optional[str] = None, *,
             events.append(dict(ev, kind=kind))
 
     srv = StoreServer()
-    built = tiny_gpt_builder(seed=seed, paged=True)
+    built = tiny_gpt_builder(seed=seed)
     pub = WeightPublisher(channel, kv_addr="127.0.0.1",
                           kv_port=srv.port, resume_timeout=0.05)
     pub.publish(built["params"])              # version 1, pre-burst
@@ -1606,7 +1599,7 @@ def run_autoscale_soak(out_dir: Optional[str] = None, *,
     try:
         worker = {
             "builder": "horovod_tpu.serve.worker:tiny_gpt_builder",
-            "builder_kwargs": {"seed": seed, "paged": True},
+            "builder_kwargs": {"seed": seed},
             "buckets": [32], "max_queue": 8,
             "deadline_ms": deadline_ms, "kv_crc": True}
         router = DisaggRouter(

@@ -68,7 +68,7 @@ DEDUPE_CAP = 4096
 REPLY_GRACE_S = 30.0
 
 
-def tiny_gpt_builder(seed: int = 0, paged: bool = True,
+def tiny_gpt_builder(seed: int = 0,
                      vocab_size: int = 64, num_layers: int = 2,
                      num_heads: int = 2, head_dim: int = 8,
                      max_seq_len: int = 48, max_batch: int = 4,
@@ -87,9 +87,8 @@ def tiny_gpt_builder(seed: int = 0, paged: bool = True,
               num_heads=num_heads, head_dim=head_dim,
               max_seq_len=max_seq_len, dtype=jnp.float32,
               attention_impl="reference")
-    paged_kw = dict(kv_block_size=kv_block_size,
-                    kv_pool_blocks=kv_pool_blocks) if paged else {}
-    model = GPT(GPTConfig(decode=True, **kw, **paged_kw))
+    model = GPT(GPTConfig(decode=True, **kw, kv_block_size=kv_block_size,
+                          kv_pool_blocks=kv_pool_blocks))
     params = GPT(GPTConfig(**kw)).init(
         jax.random.PRNGKey(seed), jnp.zeros((2, 8), jnp.int32))["params"]
     draft_model = GPT(GPTConfig(decode=True, **kw)) if draft else None
@@ -522,29 +521,29 @@ class ReplicaEndpoint:
                 "weights_version": b.executor.params_version,
                 "dedupe_hits": self.dedupe_hits,
                 "kv_corruptions_injected": b.kv_corruptions_injected,
-                "kv_corruptions_detected": b.kv_corruptions_detected}
-        if getattr(b, "paged", False):
-            info["kv_blocks_in_use"] = b.kv.pool.in_use()
-            info["kv_blocks_total"] = b.kv.pool.num_blocks
-            info["kv_block_size"] = b.kv.pool.block_size
-            # blocks held ONLY by the prefix cache (refcount-zero
-            # runs): resident but reclaimable on demand — load signals
-            # must not read cache residency as capacity pressure
-            info["kv_blocks_evictable"] = (
-                b.prefix.evictable_blocks()
-                if getattr(b, "prefix", None) is not None else 0)
-            if getattr(b, "prefix", None) is not None:
-                # TOKEN counts — the fleet-wide cacheable-capacity
-                # definition the index and autoscale signals share
-                info["prefix_tokens_resident"] = \
-                    b.prefix.resident_tokens()
-                info["prefix_tokens_evictable"] = \
-                    b.prefix.evictable_tokens()
-            if getattr(b, "kvtier", None) is not None:
-                # fleet-index event feed piggybacks the healthz reply
-                # (the heartbeat channel the router already polls)
-                info["kvtier_events"] = b.kvtier.drain_events()
-                info["kvtier"] = b.kvtier.stats()
+                "kv_corruptions_detected": b.kv_corruptions_detected,
+                "kv_blocks_in_use": b.kv.pool.in_use(),
+                "kv_blocks_total": b.kv.pool.num_blocks,
+                "kv_block_size": b.kv.pool.block_size,
+                # blocks held ONLY by the prefix cache (refcount-zero
+                # runs): resident but reclaimable on demand — load
+                # signals must not read cache residency as capacity
+                # pressure
+                "kv_blocks_evictable": (
+                    b.prefix.evictable_blocks()
+                    if getattr(b, "prefix", None) is not None else 0)}
+        if getattr(b, "prefix", None) is not None:
+            # TOKEN counts — the fleet-wide cacheable-capacity
+            # definition the index and autoscale signals share
+            info["prefix_tokens_resident"] = \
+                b.prefix.resident_tokens()
+            info["prefix_tokens_evictable"] = \
+                b.prefix.evictable_tokens()
+        if getattr(b, "kvtier", None) is not None:
+            # fleet-index event feed piggybacks the healthz reply
+            # (the heartbeat channel the router already polls)
+            info["kvtier_events"] = b.kvtier.drain_events()
+            info["kvtier"] = b.kvtier.stats()
         # disaggregated-serving evidence (serve/disagg.py healthz +
         # the disagg soak verdict read these per pool)
         info["migrations_in"] = b.migrations_in

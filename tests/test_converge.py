@@ -155,7 +155,11 @@ class TestHarness:
                  Cell("int8", "adasum", "direct"),
                  Cell("none", "adasum", "rs_ag"),     # rejected
                  Cell("none", "sum", "rhd")]          # runnable on np8
-        v = run_matrix(["gpt_tiny"], steps=6, lr=0.5, cells=cells)
+        # 8 steps at lr 1.0: the reference ends at 0.886 of its initial
+        # loss and Adasum at 0.885, inside `converged` (<= 0.9) with
+        # room; 6 steps at lr 0.5 stopped at 0.911, and lr 2.0 leaves
+        # Adasum at 0.907
+        v = run_matrix(["gpt_tiny"], steps=8, lr=1.0, cells=cells)
         cells_out = v["models"]["gpt_tiny"]
         assert set(cells_out) == {c.name for c in cells}
         assert v["world"] == hvd.size()

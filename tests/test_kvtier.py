@@ -587,7 +587,7 @@ class TestHealthzTokens:
                 "prefix_tokens_evictable": 8},
             2: {"state": "up", "up": True, "draining": False,
                 "queue_depth": 0, "weights_version": 1, "restarts": 0,
-                "queue_free": 4},   # slotted replica: no prefix cache
+                "queue_free": 4},   # a replica with no prefix cache
         }
         out = aggregate_healthz(info, draining=False,
                                 retry_after_ms=100.0)

@@ -371,14 +371,14 @@ def test_serve_http_metrics_endpoint(hvd):
     hvd.synchronize(hvd.allreduce_async(
         np.ones((8, 4), np.float32), hvd.Sum, name="serve_m_ar"))
 
-    cfg = GPTConfig(vocab_size=64, num_layers=1, num_heads=2, head_dim=8,
-                    max_seq_len=32, decode=True, dtype=jnp.float32,
-                    attention_impl="reference")
-    model = GPT(cfg)
-    toks = jnp.zeros((2, 4), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), toks,
-                        positions=jnp.zeros((2,), jnp.int32),
-                        update_mask=jnp.zeros((2,), bool))["params"]
+    kw = dict(vocab_size=64, num_layers=1, num_heads=2, head_dim=8,
+              max_seq_len=32, dtype=jnp.float32,
+              attention_impl="reference")
+    model = GPT(GPTConfig(decode=True, **kw))
+    # the cache is a collection of its own: the training-mode model
+    # makes the same parameter tree
+    params = GPT(GPTConfig(**kw)).init(
+        jax.random.PRNGKey(0), jnp.zeros((2, 4), jnp.int32))["params"]
     ex = ShardedExecutor(model, params, max_batch=2, max_len=32)
     q = AdmissionQueue(max_queue=8)
     b = ContinuousBatcher(ex, q, buckets=(8, 16))

@@ -2,8 +2,9 @@
 
 The acceptance bars of the serving subsystem (docs/serving.md):
 
-* KV-slot reuse decodes EXACTLY like a straight-line full-forward
-  oracle (greedy), across admission waves that recycle slots;
+* KV row and block reuse decodes EXACTLY like a straight-line
+  full-forward oracle (greedy), across admission waves that recycle
+  rows and their blocks;
 * batch churn (iteration-level join/leave) never grows the jit cache —
   the fixed-bucket no-recompile contract;
 * overload sheds load with a structured retry-after rejection while
@@ -28,8 +29,9 @@ import jax.numpy as jnp
 from horovod_tpu.core.config import Config
 from horovod_tpu.models.gpt import GPT, GPTConfig
 from horovod_tpu.models.llama import Llama, LlamaConfig
-from horovod_tpu.serve import (AdmissionQueue, ContinuousBatcher, Rejected,
-                               ShardedExecutor, SlotKVCache)
+from horovod_tpu.serve import (AdmissionQueue, BlockPool,
+                               ContinuousBatcher, PagedKVCache, Rejected,
+                               ShardedExecutor)
 from horovod_tpu.trace import get_recorder
 
 _KW = dict(vocab_size=64, num_layers=2, num_heads=2, head_dim=8,
@@ -78,42 +80,48 @@ def _stack(gpt, max_batch=4, max_queue=16, buckets=(8, 16),
     return ex, q, b
 
 
-class TestSlotManager:
+class TestRowManager:
     def test_alloc_free_reuse_accounting(self):
-        kv = SlotKVCache(2, 16)
-        a, b = kv.alloc(), kv.alloc()
+        kv = PagedKVCache(2, 4, BlockPool(8, 4))
+        a, b = kv.alloc_row(1), kv.alloc_row(1)
         assert {a, b} == {0, 1}
-        assert kv.alloc() is None          # full
-        assert kv.occupancy() == 1.0
-        kv.free(b)
-        assert kv.alloc() == b             # LIFO reuse
+        assert kv.alloc_row(1) is None     # every row live
+        assert kv.live() == 2 and not kv.can_admit(0)
+        held = kv.ensure(b, 5)             # two blocks of four
+        assert len(held) == 2 and kv.pool.in_use() == 2
+        kv.free_row(b)
+        assert kv.pool.in_use() == 0       # its blocks went with it
+        assert kv.alloc_row(1) == b        # LIFO reuse
         assert kv.generation[b] == 2       # the reuse ledger
         assert kv.allocs == 3 and kv.frees == 1
-        kv.free(a)
+        kv.free_row(a)
         with pytest.raises(ValueError):    # double free
-            kv.free(a)
+            kv.free_row(a)
 
     def test_lengths_reset_on_alloc(self):
-        kv = SlotKVCache(1, 16)
-        s = kv.alloc()
-        kv.lengths[s] = 9
-        kv.free(s)
-        assert kv.lengths[kv.alloc()] == 0
+        kv = PagedKVCache(1, 4, BlockPool(4, 4))
+        r = kv.alloc_row(1)
+        kv.lengths[r] = 9
+        kv.free_row(r)
+        r = kv.alloc_row(1)
+        assert kv.lengths[r] == 0 and kv.blocks[r] == []
 
 
 class TestDecodeCorrectness:
     def test_slot_reuse_matches_straight_line_oracle(self, gpt):
-        """Two admission waves over 4 slots: the second wave reuses
-        slots still holding the first wave's stale KV bytes; every
-        request must still decode exactly like the full-forward
-        oracle."""
+        """Two admission waves over 4 rows: the second wave reuses
+        rows, and pool blocks still holding the first wave's stale KV
+        bytes; every request must still decode exactly like the
+        full-forward oracle."""
         ex, q, b = _stack(gpt)
         rng = np.random.RandomState(1)
         prompts = [list(rng.randint(0, 64, rng.randint(2, 9)))
-                   for _ in range(8)]  # 8 requests > 4 slots => reuse
+                   for _ in range(8)]  # 8 requests > 4 rows => reuse
         handles = [q.submit(p, max_new_tokens=6) for p in prompts]
         b.run()
-        assert b.kv.generation.sum() >= 5  # slots actually recycled
+        assert b.kv.generation.sum() >= 5  # rows actually recycled
+        # and blocks: more handed out than were ever in use at once
+        assert b.kv.pool.allocs > b.kv.pool.peak_in_use
         for p, h in zip(prompts, handles):
             assert h.status == "ok"
             assert h.tokens == gpt.oracle(p, 6)
@@ -145,6 +153,53 @@ class TestDecodeCorrectness:
             seq, want = list(p), []
             for _ in range(4):
                 padded = np.zeros((1, 32), np.int32)
+                padded[0, :len(seq)] = seq
+                nxt = int(onext(params, jnp.asarray(padded),
+                                jnp.asarray(len(seq) - 1)))
+                want.append(nxt)
+                seq.append(nxt)
+            assert h.status == "ok" and h.tokens == want
+
+    @pytest.mark.parametrize("family", ["gpt", "llama"])
+    def test_decode_true_alone_means_a_worst_case_pool(self, family):
+        """`decode=True` without pool sizes is no other layout: blocks
+        of the one default size, and the executor works the pool out as
+        every row at max_len (40 here: a partial last block). Rows,
+        blocks and all, the stream is the straight-line oracle's."""
+        kw = dict(vocab_size=64, num_layers=2, num_heads=4, head_dim=8,
+                  max_seq_len=40, dtype=jnp.float32,
+                  attention_impl="reference")
+        if family == "llama":
+            kw["num_kv_heads"] = 2
+        Model, Cfg = {"gpt": (GPT, GPTConfig),
+                      "llama": (Llama, LlamaConfig)}[family]
+        train, dec = Model(Cfg(**kw)), Model(Cfg(decode=True, **kw))
+        assert (dec.cfg.kv_block_size, dec.cfg.kv_pool_blocks) == (16, 0)
+        params = train.init(jax.random.PRNGKey(0),
+                            jnp.zeros((2, 8), jnp.int32))["params"]
+        ex = ShardedExecutor(dec, params, max_batch=3, max_len=40)
+        assert ex.blocks_per_seq == 3
+        assert ex.kv_pool_blocks == ex.max_batch * ex.blocks_per_seq == 9
+        assert dec.cfg.kv_pool_blocks == 0     # the caller's, as given
+        q = AdmissionQueue(max_queue=8)
+        b = ContinuousBatcher(ex, q, buckets=(8, 16))
+        assert b.kv.pool.num_blocks == 9 and b.kv.num_rows == 3
+        rng = np.random.RandomState(5)
+        prompts = [list(rng.randint(0, 64, rng.randint(3, 14)))
+                   for _ in range(5)]          # 5 requests > 3 rows
+        handles = [q.submit(p, max_new_tokens=24) for p in prompts]
+        b.run()
+        assert b.kv.pool.peak_in_use <= 9 and b.kv.pool.in_use() == 0
+
+        @jax.jit
+        def onext(p, padded, last):
+            return jnp.argmax(jnp.take(
+                train.apply({"params": p}, padded)[0], last, axis=0))
+
+        for p, h in zip(prompts, handles):
+            seq, want = list(p), []
+            for _ in range(24):
+                padded = np.zeros((1, 40), np.int32)
                 padded[0, :len(seq)] = seq
                 nxt = int(onext(params, jnp.asarray(padded),
                                 jnp.asarray(len(seq) - 1)))

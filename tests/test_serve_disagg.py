@@ -316,6 +316,41 @@ class TestMigrationIntegrity:
         assert outcome == "rejected" and detail is not None
         assert dec.migrate_rejects == 1
 
+    def test_every_replica_is_a_pool_to_hand_off_to(self, expool):
+        """A decode replica whose config names no pool size is still a
+        pool: the hand-off installs and continues bit-identically. The
+        one layout answer left is a block size that differs."""
+        def replica(tag, **kv):
+            ex = ShardedExecutor(
+                GPT(GPTConfig(decode=True, **_GPT_KW, **kv)),
+                expool("gpt", "target", 1).params, max_batch=4,
+                max_len=48, replica_id=tag)
+            b = ContinuousBatcher(
+                ex, AdmissionQueue(max_queue=16, replica_id=tag),
+                buckets=(8,), replica_id=tag, kv_crc=True,
+                prefix_cache=False)
+            b.warmup()
+            return b
+
+        prompt = [5, 9, 3, 17, 2]
+        plain = _batcher(expool, "gpt", tag=3)
+        want = plain.queue.submit(prompt, max_new_tokens=8)
+        plain.run()
+        dec = replica(4, kv_block_size=4)      # 4 rows x 12 blocks
+        assert dec.kv.pool.num_blocks == 48
+        got = _migrate_run(_batcher(expool, "gpt", tag=1), dec,
+                           prompt, 8)
+        assert got == want.tokens and dec.migrations_in == 1
+
+        _, _, header, payload = self._packet(expool, 1)
+        other = replica(5)                     # the default block of 16
+        ent = other.submit_migrated(
+            header, kv_migrate.unpack_blocks(header, payload))
+        other.run()
+        outcome, detail = ent["outcome"]
+        assert outcome == "incompatible" and "block size" in detail
+        assert other.migrations_in == 0 and not other._active
+
     def test_release_and_ttl_reap(self, expool):
         pre = _batcher(expool, "gpt", tag=1)
         h1 = pre.queue.submit([1, 2, 3], max_new_tokens=1,

@@ -12,7 +12,7 @@ The tier-1 bars of ISSUE 8 (docs/serving.md failover section):
   the router fails over, auto-restarts it and re-admits it (on the
   newest streamed weights when a stream is attached);
 * an injected serve.kv corruption flips REAL device cache bytes and the
-  per-slot crc catches it before any token reaches a client (re-prefill
+  per-block crc catches it before any token reaches a client (re-prefill
   yields the same tokens a clean run produces; "error" mode fails
   cleanly);
 * serve.admit drops and serve.route partitions are absorbed by
@@ -41,7 +41,7 @@ from horovod_tpu.chaos.plan import ChaosPlan, PlanError, random_plan
 from horovod_tpu.models.gpt import GPT, GPTConfig
 from horovod_tpu.serve import (AdmissionQueue, ContinuousBatcher,
                                FleetRouter, Rejected, Replica,
-                               ShardedExecutor, SlotKVCache)
+                               ShardedExecutor)
 
 _KW = dict(vocab_size=64, num_layers=2, num_heads=2, head_dim=8,
            max_seq_len=48, dtype=jnp.float32, attention_impl="reference")
@@ -73,8 +73,8 @@ def _executor(gpt, rid=None, max_batch=4):
 def expool(gpt):
     """Executors are the expensive part (one jit compile each), and
     REUSING one across batchers is exactly the fleet-restart contract
-    (stale cache rows are validity-masked, the crc ledger resets on
-    slot alloc) — so the suite exercises it constantly by pooling."""
+    (stale cache blocks are validity-masked, the crc ledger resets on
+    block alloc) — so the suite exercises it constantly by pooling."""
     cache = {}
 
     def get(rid=None, max_batch=4):
@@ -191,26 +191,10 @@ class TestAccrualTracker:
 
 
 # ---------------------------------------------------------------------------
-# per-slot KV crc
+# per-block KV crc
 # ---------------------------------------------------------------------------
 
 class TestKVCrc:
-    def test_streamed_crc_matches_full_read(self):
-        kv = SlotKVCache(2, 16)
-        s = kv.alloc()
-        kv.crc_update(s, [b"abc", b"123"])      # prefill: 2 leaves
-        kv.crc_update(s, [b"d", b"4"])          # decode step
-        kv.crc_update(s, [b"e", b"5"])
-        assert kv.crc_check(s, [b"abcde", b"12345"])
-        assert not kv.crc_check(s, [b"abcdX", b"12345"])
-        assert not kv.crc_check(s, [b"abcde"])  # leaf count mismatch
-        # never-written slots check clean; realloc resets the ledger
-        assert kv.crc_check(kv.alloc(), [b"anything", b"at all"])
-        kv.free(s)
-        s2 = kv.alloc()
-        assert s2 == s                          # LIFO reuse
-        assert kv.crc_check(s2, [b"", b""])
-
     def test_corrupt_detected_and_reprefilled(self, expool):
         """An injected serve.kv corruption flips real cache bytes; the
         crc catches it at retirement and the re-prefilled generation
@@ -254,7 +238,7 @@ class TestKVCrc:
         b.run()
         assert h.status == "error" and h.error == "kv_corrupt"
         assert h.tokens == []          # no garbage escapes
-        assert b.kv.live() == 0        # the slot went back to the pool
+        assert b.kv.live() == 0        # the row went back, with its blocks
 
     def test_kv_crc_config_knob(self, monkeypatch):
         from horovod_tpu.core.config import Config

@@ -43,16 +43,14 @@ def gpt_params():
         jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))["params"]
 
 
-def _stack(params, kernel, *, paged=True, spec=False, draft_params=None,
+def _stack(params, kernel, *, spec=False, draft_params=None,
            prefix=False, max_batch=4, buckets=(8, 16), timeline=None,
            num_layers=None):
     kw = dict(_KW)
     if num_layers is not None:
         kw["num_layers"] = num_layers
-    mcfg = GPTConfig(decode=True, **kw,
-                     kv_block_size=_BLOCK if paged else 0,
-                     kv_pool_blocks=_POOL if paged else 0,
-                     decode_kernel=kernel if paged else None)
+    mcfg = GPTConfig(decode=True, **kw, kv_block_size=_BLOCK,
+                     kv_pool_blocks=_POOL, decode_kernel=kernel)
     ex = ShardedExecutor(GPT(mcfg), params, max_batch=max_batch,
                          max_len=_KW["max_seq_len"], timeline=timeline)
     draft = None
@@ -146,21 +144,37 @@ class TestKernelParity:
                                      np.zeros(1, np.int32))
 
     def test_masked_attention_is_the_single_oracle(self):
-        """The dedupe contract: slotted, paged and the models' decode
+        """The dedupe contract: the pool read and the models' decode
         attention all route through ONE reference implementation."""
-        assert kvc._masked_attention is kvc.masked_attention
         import inspect
-        assert "masked_attention" in inspect.getsource(
-            kvc.cached_attention)
-        assert "masked_attention" in inspect.getsource(
+        assert "masked_attention(" in inspect.getsource(
             kvc.paged_attention)
+        assert "paged_attention(" in inspect.getsource(
+            kvc.pool_attention)
         # the models delegate to kv_cache for every decode read
         import horovod_tpu.models.gpt as gpt_mod
         import horovod_tpu.models.llama as llama_mod
         for mod in (gpt_mod, llama_mod):
             src = inspect.getsource(mod)
-            assert "kvc.paged_attention" in src
-            assert "kvc.cached_attention" in src
+            assert "kvc.pool_attention(" in src
+            assert "self.variable(" not in src   # the pool is kvc's
+
+    def test_the_block_pool_is_the_one_storage_format(self):
+        """The source contract: no second KV layout, selector or knob
+        under horovod_tpu/ (PR 32 took them out)."""
+        import pathlib
+        import re
+        import horovod_tpu
+        gone = re.compile(
+            r"SlotKVCache|cached_attention|_admit_slotted|"
+            r"paged_model_kwargs|serve_kv_block\b|\.paged\b")
+        root = pathlib.Path(horovod_tpu.__file__).parent
+        hits = [f"{f.relative_to(root)}:{i}: {line.strip()}"
+                for f in sorted(root.rglob("*.py"))
+                for i, line in enumerate(
+                    f.read_text().splitlines(), 1)
+                if gone.search(line)]
+        assert not hits, hits
 
 
 # ---------------------------------------------------------------------------
@@ -254,9 +268,10 @@ class TestServeKernelParityE2E:
         fam = obs_metrics.get_registry().get(
             "hvd_serve_step_ms", {"kind": "decode", "kernel": "pallas"})
         assert fam is not None
-        # slotted executors always resolve to the XLA oracle
-        ex2, _, _ = _stack(gpt_params, None, paged=False, num_layers=1)
-        assert ex2.kernel == "xla"
+        # a config that names no kernel resolves from the environment:
+        # auto, off the TPU, is the XLA oracle
+        ex2, _, _ = _stack(gpt_params, None, num_layers=1)
+        assert ex2.kernel == ex2.model.cfg.decode_kernel == "xla"
 
 
 # ---------------------------------------------------------------------------
@@ -282,9 +297,11 @@ class TestKernelKnob:
         with pytest.raises(ValueError, match="serve kernel"):
             pp.resolve_kernel("bogus")
 
-    def test_pallas_is_paged_only(self):
-        with pytest.raises(ValueError, match="paged-only"):
-            GPTConfig(decode=True, decode_kernel="pallas", **_KW)
+    def test_unknown_kernel_is_refused(self):
+        # every decode config is a pool, so the fused kernel has no
+        # layout left to refuse
+        assert GPTConfig(decode=True, decode_kernel="pallas",
+                         **_KW).decode_kernel == "pallas"
         with pytest.raises(ValueError, match="decode_kernel"):
             GPTConfig(decode=True, decode_kernel="triton", **_KW)
 
@@ -407,7 +424,7 @@ class TestSamplingSemantics:
         assert fam is not None and fam.count > 0
 
     def test_submit_validation_fail_fast(self, gpt_params):
-        _, q, _ = _stack(gpt_params, None, paged=False, num_layers=1)
+        _, q, _ = _stack(gpt_params, None, num_layers=1)
         with pytest.raises(ValueError, match="temperature"):
             q.submit([1, 2], temperature=-0.5)
         with pytest.raises(ValueError, match="top_p"):

@@ -4,8 +4,8 @@ The ISSUE 10 bars (docs/serving.md):
 
 * the block allocator never hands out an in-use block — alloc/free/
   refcount/eviction are airtight under reuse and sharing;
-* paged decode (block pool + block tables) emits EXACTLY the tokens of
-  the slotted/straight-line greedy oracle, for GPT and Llama-GQA,
+* decode over the block pool and block tables emits EXACTLY the tokens
+  of the straight-line greedy oracle, for GPT and Llama-GQA,
   across admission waves that recycle rows and blocks;
 * prefix-shared prefills (full-block reuse AND a copy-on-write
   divergence mid-block) stay bit-identical, and the shared source
@@ -58,12 +58,11 @@ def _disarm():
 
 @pytest.fixture(scope="module")
 def gpt():
-    """Tiny GPT in three flavors over ONE param set: training-mode
-    oracle, slotted decode, paged decode."""
+    """Tiny GPT over ONE param set: training-mode oracle and decode
+    over an explicit pool."""
     train = GPT(GPTConfig(**_KW))
     paged = GPT(GPTConfig(decode=True, **_KW, kv_block_size=_BS,
                           kv_pool_blocks=_POOL))
-    slotted = GPT(GPTConfig(decode=True, **_KW))
     params = train.init(jax.random.PRNGKey(0),
                         jnp.zeros((2, 8), jnp.int32))["params"]
     # a DIFFERENT drafter (disagrees with the target almost always —
@@ -89,7 +88,7 @@ def gpt():
                 break
         return out
 
-    return SimpleNamespace(paged=paged, slotted=slotted, params=params,
+    return SimpleNamespace(paged=paged, params=params,
                            draft_params=draft_params, oracle=oracle)
 
 
@@ -108,9 +107,12 @@ def _stack(gpt, *, max_batch=4, max_queue=32, buckets=(16,),
     return ex, q, b
 
 
-def _draft_ex(gpt, params, max_batch=4):
-    return ShardedExecutor(gpt.slotted, params, max_batch=max_batch,
-                           max_len=_KW["max_seq_len"], role="draft")
+def _draft_ex(gpt, params, max_batch=4, max_len=_KW["max_seq_len"]):
+    """An ordinary executor on the default pool (sized by the executor
+    for its own max_batch, hence a model of its own)."""
+    return ShardedExecutor(GPT(GPTConfig(decode=True, **_KW)), params,
+                           max_batch=max_batch, max_len=max_len,
+                           role="draft")
 
 
 # ---------------------------------------------------------------------------
@@ -480,6 +482,26 @@ class TestSpeculative:
         for w, h in zip(want, handles):
             assert h.status == "ok" and h.tokens == w
 
+    def test_drafter_reads_its_pool_through_the_identity_table(self, gpt):
+        """The drafter is an ordinary executor: the batcher hands it one
+        constant table, row r owning blocks [r * n, (r + 1) * n). A
+        drafter context of 44 leaves its last block of 16 partial;
+        sequences grow past it (those rows fall back to plain decode)
+        and the stream stays the target's greedy one."""
+        draft = _draft_ex(gpt, gpt.params, max_len=44)
+        assert (draft.blocks_per_seq, draft.kv_pool_blocks) == (3, 12)
+        ex, q, b = _stack(gpt, draft=draft, spec_k=3, prefix=False)
+        assert b._draft_tables.tolist() == [
+            [0, 1, 2], [3, 4, 5], [6, 7, 8], [9, 10, 11]]
+        rng = np.random.RandomState(12)
+        prompts = [list(rng.randint(0, 64, rng.randint(4, 12)))
+                   for _ in range(6)]
+        handles = [q.submit(p, max_new_tokens=36) for p in prompts]
+        b.run()
+        for p, h in zip(prompts, handles):
+            assert h.status == "ok" and h.tokens == gpt.oracle(p, 36)
+        assert b.gen_steps < b.gen_tokens      # speculation did run
+
     def test_spec_with_prefix_and_llama_gqa_target(self):
         """The ISSUE pairing: GPT drafter proposing, Llama-GQA target
         verifying — paged + prefix-shared + speculative all on, output
@@ -784,23 +806,19 @@ class TestPagedConfigKnobs:
     def test_defaults(self):
         c = Config()
         c.validate()
-        assert c.serve_kv_block == 0
+        assert not hasattr(c, "serve_kv_block")   # the model config's
+        assert GPTConfig(decode=True, **_KW).kv_block_size == 16
         assert c.serve_prefix_cache is True
         assert c.serve_spec_k == 3
 
     def test_env_parsing(self, monkeypatch):
-        monkeypatch.setenv("HOROVOD_SERVE_KV_BLOCK", "16")
         monkeypatch.setenv("HOROVOD_SERVE_PREFIX_CACHE", "0")
         monkeypatch.setenv("HOROVOD_SERVE_SPEC_K", "5")
         c = Config.from_env()
-        assert c.serve_kv_block == 16
         assert c.serve_prefix_cache is False
         assert c.serve_spec_k == 5
 
     @pytest.mark.parametrize("name,val", [
-        ("HOROVOD_SERVE_KV_BLOCK", "big"),
-        ("HOROVOD_SERVE_KV_BLOCK", "-1"),
-        ("HOROVOD_SERVE_KV_BLOCK", "8192"),
         ("HOROVOD_SERVE_SPEC_K", "-1"),
         ("HOROVOD_SERVE_SPEC_K", "k"),
         ("HOROVOD_SERVE_SPEC_K", "100"),
@@ -810,28 +828,18 @@ class TestPagedConfigKnobs:
         with pytest.raises(ValueError):
             Config.from_env()
 
-    def test_paged_model_kwargs_reads_env(self, monkeypatch):
-        """HOROVOD_SERVE_KV_BLOCK's consumer: the helper that turns the
-        env knob into model-config pool shapes."""
-        from horovod_tpu.serve import paged_model_kwargs
-        monkeypatch.delenv("HOROVOD_SERVE_KV_BLOCK", raising=False)
-        assert paged_model_kwargs(4, 48) == {}      # slotted default
-        monkeypatch.setenv("HOROVOD_SERVE_KV_BLOCK", "4")
-        kw = paged_model_kwargs(4, 48)
-        assert kw["kv_block_size"] == 4
-        assert kw["kv_pool_blocks"] >= 12 + 4       # one max_len seq fits
-        model = GPT(GPTConfig(decode=True, **_KW, **kw))
-        assert model.cfg.kv_block_size == 4
-
     def test_model_config_validation(self):
-        with pytest.raises(ValueError):        # paged is decode-only
+        with pytest.raises(ValueError):        # a pool is decode-only
             GPTConfig(kv_block_size=4, kv_pool_blocks=8, **_KW)
-        with pytest.raises(ValueError):        # pool shape is static
-            GPTConfig(decode=True, kv_block_size=4, **_KW)
+        with pytest.raises(ValueError):        # a block holds a token
+            GPTConfig(decode=True, kv_block_size=0, **_KW)
         with pytest.raises(ValueError):
-            LlamaConfig(decode=True, kv_block_size=4,
+            LlamaConfig(decode=True, kv_pool_blocks=-1,
                         vocab_size=64, num_layers=1, num_heads=2,
                         head_dim=8, max_seq_len=32)
+        # no pool size is no longer another layout: the executor's call
+        assert GPTConfig(decode=True, kv_block_size=4,
+                         **_KW).kv_pool_blocks == 0
 
     def test_executor_rejects_undersized_pool(self, gpt):
         small = GPT(GPTConfig(decode=True, **_KW, kv_block_size=4,
@@ -839,24 +847,28 @@ class TestPagedConfigKnobs:
         with pytest.raises(ValueError):        # can't hold one max_len seq
             ShardedExecutor(small, gpt.params, max_batch=2, max_len=48)
 
-    def test_draft_executor_must_be_slotted_and_matched(self, gpt):
+    def test_draft_executor_must_hold_its_rows_and_match(self, gpt):
         ex = ShardedExecutor(gpt.paged, gpt.params, max_batch=2,
                              max_len=48)
         q = AdmissionQueue(max_queue=4)
-        paged_draft = ShardedExecutor(gpt.paged, gpt.params,
-                                      max_batch=2, max_len=48,
-                                      role="draft")
-        with pytest.raises(ValueError):
-            ContinuousBatcher(ex, q, buckets=(8,),
-                              draft_executor=paged_draft, spec_k=2,
-                              prefix_cache=False)
-        mismatched = ShardedExecutor(gpt.slotted, gpt.params,
-                                     max_batch=3, max_len=48,
-                                     role="draft")
-        with pytest.raises(ValueError):
-            ContinuousBatcher(ex, q, buckets=(8,),
-                              draft_executor=mismatched, spec_k=2,
-                              prefix_cache=False)
+
+        def refused(draft):
+            with pytest.raises(ValueError):
+                ContinuousBatcher(ex, q, buckets=(8,),
+                                  draft_executor=draft, spec_k=2,
+                                  prefix_cache=False)
+
+        # a pool too small for one fixed run a row (2 x 12 blocks of 4)
+        refused(ShardedExecutor(
+            GPT(GPTConfig(decode=True, **_KW, kv_block_size=4,
+                          kv_pool_blocks=16)),
+            gpt.params, max_batch=2, max_len=48, role="draft"))
+        refused(_draft_ex(gpt, gpt.params, max_batch=3))   # rows pair 1:1
+        refused(_draft_ex(gpt, gpt.params, max_batch=2,
+                          max_len=6))              # context < the bucket
+        ContinuousBatcher(ex, q, buckets=(8,), spec_k=2,
+                          draft_executor=_draft_ex(gpt, gpt.params, 2),
+                          prefix_cache=False)
 
 
 # ---------------------------------------------------------------------------

@@ -4,8 +4,8 @@ seeded serve-profile chaos plan under closed-loop traffic and print the
 JSON verdict (exit 0 iff every invariant held). The default
 configuration is the full serving tier — paged KV blocks + radix
 prefix cache + speculative decoding — so this soak is the regression
-harness for those paths; `--slotted` / `--no-prefix-cache` /
-`--spec-k 0` peel the layers back off.
+harness for those paths; `--no-prefix-cache` / `--spec-k 0` peel the
+layers back off.
 
     python tools/serve_soak.py --replicas 3 --clients 6 --seed 7
     python tools/serve_soak.py --plan my_serve_plan.json --out /tmp/s1
@@ -74,11 +74,8 @@ def main(argv=None) -> int:
     p.add_argument("--no-kv-crc", action="store_true",
                    help="disable the KV crc ledger (the corrupt "
                         "invariant will fail — for demonstration only)")
-    p.add_argument("--slotted", action="store_true",
-                   help="run the legacy slotted KV layout instead of "
-                        "the default paged block pool")
     p.add_argument("--no-prefix-cache", action="store_true",
-                   help="disable the radix prefix cache (paged only)")
+                   help="disable the radix prefix cache")
     p.add_argument("--spec-k", type=int, default=None,
                    help="speculative draft depth (0 disables the "
                         "drafter; default 3 in-process, 0 with "
@@ -198,7 +195,6 @@ def main(argv=None) -> int:
             max_duration_s=(150.0 if args.max_duration is None
                             else args.max_duration),
             spec_k=0 if args.spec_k is None else args.spec_k,
-            paged=not args.slotted,
             kv_crc=False if args.no_kv_crc else None,
             prefix_cache=False if args.no_prefix_cache else None,
             spawn_timeout_s=args.spawn_timeout)
@@ -220,7 +216,6 @@ def main(argv=None) -> int:
         max_duration_s=(45.0 if args.max_duration is None
                         else args.max_duration),
         kv_crc=False if args.no_kv_crc else None,
-        paged=not args.slotted,
         prefix_cache=False if args.no_prefix_cache else None,
         spec_k=3 if args.spec_k is None else args.spec_k,
         sigterm_drain=True)
