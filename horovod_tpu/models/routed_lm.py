@@ -181,13 +181,10 @@ class RoutedAttention(nn.Module):
                 0, 2, 1, 3)
             k = apply_rope(k.transpose(0, 2, 1, 3), angles).transpose(
                 0, 2, 1, 3)
-        pool = (cfg.kv_pool_blocks, cfg.kv_block_size, KV, D)
-        ck = self.variable("cache", "k", jnp.zeros, pool, cfg.dtype)
-        cv = self.variable("cache", "v", jnp.zeros, pool, cfg.dtype)
-        ck.value, cv.value = kvc.write_kv_paged(
-            ck.value, cv.value, k, v, positions, update_mask, block_tables)
+        pool_k, pool_v = kvc.write_kv_pools(
+            self, cfg, k, v, positions, update_mask, block_tables)
         if cfg.decode_kernel != "pallas":
-            o = kvc.paged_attention(q, ck.value, cv.value, block_tables,
+            o = kvc.paged_attention(q, pool_k, pool_v, block_tables,
                                     positions, window=self.window)
         elif T >= FLASH_MIN_TOKENS:
             # a prefill: the row's blocks gathered once ([B, L, KV, D],
@@ -196,8 +193,8 @@ class RoutedAttention(nn.Module):
             from ..ops.pallas_attention import flash_prefill
             tbl = jnp.maximum(block_tables, 0)
             L = tbl.shape[1] * cfg.kv_block_size
-            keys = ck.value[tbl].reshape(B, L, KV, D).transpose(0, 2, 1, 3)
-            vals = cv.value[tbl].reshape(B, L, KV, D).transpose(0, 2, 1, 3)
+            keys = pool_k[tbl].reshape(B, L, KV, D).transpose(0, 2, 1, 3)
+            vals = pool_v[tbl].reshape(B, L, KV, D).transpose(0, 2, 1, 3)
             o = flash_prefill(
                 q.transpose(0, 2, 1, 3), keys, vals, positions,
                 window=self.window,
@@ -205,7 +202,7 @@ class RoutedAttention(nn.Module):
                     0, 2, 1, 3)
         else:
             from ..ops.pallas_paged import paged_attention_fused
-            o = paged_attention_fused(q, ck.value, cv.value, block_tables,
+            o = paged_attention_fused(q, pool_k, pool_v, block_tables,
                                       positions, window=self.window)
         return Proj(cfg.embed_dim, cfg.dtype, cfg.param_dtype, name="wo")(
             o.reshape(B, T, H * D))

@@ -353,13 +353,26 @@ class ShardedExecutor:
         nbytes = [sum(int(np.prod(x.shape)) * d.itemsize
                       for x, d in zip(leaves, dts))
                   for dts in (self._given_dtypes, self._resident_dtypes)]
+        # and so are the shape and the layout the pools are HELD in and
+        # their bytes, as values and on the device: a pool the device
+        # lays out another way than row-major (`kv_cache.write_kv_pools`)
+        # is copied whole by every layer of every step, and pays in
+        # padding there
+        pools = self._cache_leaves()
         logger.info(
             "serve executor (replica=%s role=%s): decode kernel=%s "
             "pool=%dx%d backend=%s; resident bytes %d -> %d, %d of %d "
-            "leaves cast", replica_id, role, self.kernel,
+            "leaves cast; %d pools %s held %s, pool bytes %d -> %d on the "
+            "device", replica_id, role, self.kernel,
             self.kv_pool_blocks, self.kv_block_size,
             jax.default_backend(), *nbytes, len(self._cast_idx),
-            len(leaves))
+            len(leaves), len(pools),
+            "/".join(sorted({f"{x.dtype}{list(x.shape)}" for x in pools})),
+            "/".join(sorted({str(x.format.layout.major_to_minor)
+                             for x in pools})),
+            sum(x.nbytes for x in pools),
+            sum(x.addressable_shards[0].data.on_device_size_in_bytes()
+                for x in pools))
         # one-shot KERNEL instant: names the RESOLVED decode kernel so
         # a silent fallback to XLA on TPU is visible in the trace
         if self.timeline is not None:

@@ -24,10 +24,13 @@ Shapes never depend on which rows/blocks are live — liveness is data
 (the no-recompile contract, docs/serving.md).
 
 The device arrays themselves live in the model's flax ``"cache"``
-collection (`pool_attention`, called by the decode paths of
-models/gpt.py and models/llama.py) and are threaded through the
-executor (serve/executor.py); this module holds no jax arrays of its
-own.
+collection (`write_kv_pools`, called by `pool_attention` for the
+decode paths of models/gpt.py and models/llama.py and by
+models/routed_lm.py) and are threaded through the executor
+(serve/executor.py); this module holds no jax arrays of its own. They
+are HELD padded to whole device tiles (`held_pool_shape`), the shape
+the device lays out in the row-major order the scatter and the kernel
+read.
 """
 from __future__ import annotations
 
@@ -161,6 +164,62 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     return masked_attention(q, keys, vals, positions, window)
 
 
+#: a device tile: 128 lanes by 8 sublanes
+POOL_LANES, POOL_SUBLANES = 128, 8
+
+
+def held_pool_shape(pool_blocks: int, block_size: int, kv_heads: int,
+                    head_dim: int) -> Tuple[int, int, int, int]:
+    """The shape a ``[pool_blocks, block_size, kv_heads, head_dim]`` KV
+    pool is HELD in (see `write_kv_pools`): ``head_dim`` padded up to
+    whole lane tiles and ``kv_heads`` to whole sublane tiles (1, 2 and
+    4 heads have tiles of their own), which is what a TPU lays out
+    row-major: ``[.., 25, 64]`` is held as ``[.., 32, 128]``,
+    ``[.., 4, 128]`` as it is. Heads so narrow that whole lanes would
+    more than double them (no model's; the tests') are left alone."""
+    lanes = -(-head_dim // POOL_LANES) * POOL_LANES
+    if lanes > 2 * head_dim:
+        return pool_blocks, block_size, kv_heads, head_dim
+    rows = kv_heads if kv_heads in (1, 2, 4) else \
+        -(-kv_heads // POOL_SUBLANES) * POOL_SUBLANES
+    return pool_blocks, block_size, rows, lanes
+
+
+def write_kv_pools(module, cfg, k: jax.Array, v: jax.Array,
+                   positions: jax.Array, update_mask: jax.Array,
+                   block_tables: jax.Array):
+    """One layer's K and V pools (the ``"cache"`` collection of the flax
+    ``module`` that calls this) with the ``T`` new tokens written
+    through the tables -> ``(pool_k, pool_v)``, each
+    ``[kv_pool_blocks, kv_block_size, H_kv, D]``. k/v: [B, T, H_kv, D].
+
+    The pools are HELD in `held_pool_shape`, the new rows zero-padded
+    to it and the attention handed the ``[.., :H_kv, :D]`` corner. The
+    held shape is what decides a pool's layout on the device: a TPU
+    lays a ``[.., H_kv, 64]`` array out with the BLOCK index
+    minor-most (64 half-fills a lane tile), and every layer of every
+    step then copied K and V whole to the row-major order the scatter
+    and the paged kernel read, and back. Padded to whole tiles the
+    device's own layout IS that row-major order, byte for byte what a
+    row-major ``[.., H_kv, 64]`` pool is (which pads the same lanes and
+    sublanes), so taking the corner is a bitcast and no step copies a
+    pool. Naming the layout on the jitted steps instead
+    (`jax.experimental.layout.Format`) does the same in a freshly
+    compiled program, and is lost when the program comes back from
+    jax's persistent compilation cache. Where the shape already fills
+    whole tiles nothing is padded and the program is as it was."""
+    KV, D = k.shape[-2:]
+    pool = held_pool_shape(cfg.kv_pool_blocks, cfg.kv_block_size, KV, D)
+    ck = module.variable("cache", "k", jnp.zeros, pool, cfg.dtype)
+    cv = module.variable("cache", "v", jnp.zeros, pool, cfg.dtype)
+    if pool[2:] != (KV, D):
+        tiles = ((0, 0), (0, 0), (0, pool[2] - KV), (0, pool[3] - D))
+        k, v = jnp.pad(k, tiles), jnp.pad(v, tiles)
+    ck.value, cv.value = write_kv_paged(
+        ck.value, cv.value, k, v, positions, update_mask, block_tables)
+    return ck.value[:, :, :KV, :D], cv.value[:, :, :KV, :D]
+
+
 def pool_attention(module, cfg, q: jax.Array, k: jax.Array, v: jax.Array,
                    positions: jax.Array, update_mask: jax.Array,
                    block_tables: jax.Array) -> jax.Array:
@@ -181,16 +240,13 @@ def pool_attention(module, cfg, q: jax.Array, k: jax.Array, v: jax.Array,
             "kv_pool_blocks is not set: name it in the model config, "
             "or build the model's ShardedExecutor first (it sizes the "
             "pool for max_batch x max_len)")
-    pool = (cfg.kv_pool_blocks, cfg.kv_block_size) + k.shape[2:]
-    ck = module.variable("cache", "k", jnp.zeros, pool, cfg.dtype)
-    cv = module.variable("cache", "v", jnp.zeros, pool, cfg.dtype)
-    ck.value, cv.value = write_kv_paged(
-        ck.value, cv.value, k, v, positions, update_mask, block_tables)
+    pool_k, pool_v = write_kv_pools(module, cfg, k, v, positions,
+                                    update_mask, block_tables)
     if cfg.decode_kernel == "pallas":
         from ..ops.pallas_paged import paged_attention_fused
-        return paged_attention_fused(q, ck.value, cv.value, block_tables,
+        return paged_attention_fused(q, pool_k, pool_v, block_tables,
                                      positions)
-    return paged_attention(q, ck.value, cv.value, block_tables, positions)
+    return paged_attention(q, pool_k, pool_v, block_tables, positions)
 
 
 def pool_blocks_for(max_batch: int, max_len: int, block_size: int,

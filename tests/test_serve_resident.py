@@ -369,3 +369,123 @@ def test_a_committed_tree_gets_a_committed_cache():
     once = ex.jit_cache_size()
     _first_step(ex)
     assert ex.jit_cache_size() == once == 1
+
+
+# -- (f) the pools are held in the shape the device lays out row-major --------
+# (`kv_cache.write_kv_pools`), and whoever writes `executor.cache` hands
+# every pool back as it was held, so the next step neither compiles
+# again nor re-lays a pool out.
+
+@pytest.mark.parametrize("logical,held", [
+    ((160, 16, 25, 64), (160, 16, 32, 128)),        # GPT-2 XL
+    ((96, 16, 16, 64), (96, 16, 16, 128)),          # GPT-2 medium
+    ((96, 16, 12, 64), (96, 16, 16, 128)),          # GPT-2
+    ((64, 16, 4, 96), (64, 16, 4, 128)),
+    ((800, 128, 4, 128), (800, 128, 4, 128)),       # whole tiles: as it is
+    ((64, 16, 8, 256), (64, 16, 8, 256)),
+    ((64, 16, 5, 128), (64, 16, 8, 128)),
+    ((24, 4, 2, 8), (24, 4, 2, 8)),                 # the tests' heads
+    ((24, 4, 3, 16), (24, 4, 3, 16)),
+], ids=str)
+def test_held_pool_shape(logical, held):
+    from horovod_tpu.serve.kv_cache import held_pool_shape
+    assert held_pool_shape(*logical) == held
+
+
+def test_padded_pools_serve_the_tokens_of_the_whole_sequence():
+    """3 heads of 64 are held as 8 of 128 (the tests' other models are
+    too narrow to be padded): the executor's greedy tokens, prefill and
+    decode, are those of the model run over the whole sequence with no
+    cache at all."""
+    kw = dict(_KW, num_heads=3, head_dim=64)
+    params = _gpt_params(num_heads=3, head_dim=64)
+    ex = _gpt_executor(params, num_heads=3, head_dim=64)
+    assert {x.shape for x in ex._cache_leaves()} == {(24, 4, 8, 128)}
+    whole = jax.jit(GPT(GPTConfig(**kw)).apply)
+    seqs = [list(p) for p in _PROMPTS]
+    served = _serve(ex)
+    for step in served["prefill"] + served["decode"]:
+        for seq, tok in zip(seqs, step):
+            padded = np.zeros((1, _MAX_LEN), np.int32)
+            padded[0, :len(seq)] = seq
+            logits = whole({"params": params}, jnp.asarray(padded))
+            assert int(tok) == int(jnp.argmax(logits[0, len(seq) - 1]))
+            seq.append(int(tok))
+
+
+_SPARE = _ROWS * 12 - 1         # the last block of `_tables`: never reached
+
+
+def _idle_step(kind, T):
+    def writer(ex):             # every row masked: the pools pass through
+        ex.step(np.zeros((_ROWS, T), np.int32), np.zeros(_ROWS, np.int32),
+                np.zeros(_ROWS, bool), np.zeros(_ROWS, np.int32),
+                kind=kind, block_tables=_tables(ex))
+    return writer
+
+
+def _reinstall_first_blocks(ex):
+    firsts = [int(t[0]) for t in _tables(ex)]
+    size = ex.kv_block_size     # both prompts fill their first block
+    ex.install_kv_blocks(
+        firsts, [ex.kv_block_bytes(b, 0, size) for b in firsts],
+        [size] * len(firsts))
+
+
+_CACHE_WRITERS = {
+    "prefill": _idle_step("prefill", _BUCKET),
+    "decode": _idle_step("decode", 1),
+    "verify": _idle_step("verify", 3),
+    "copy_kv_block": lambda ex: ex.copy_kv_block(0, _SPARE),
+    "install_kv_blocks": _reinstall_first_blocks,
+    "corrupt_kv_block": lambda ex: ex.corrupt_kv_block(
+        _SPARE, ex.kv_block_size),
+}
+
+
+def _pools_held_row_major(ex):
+    pools = ex._cache_leaves()
+    return bool(pools) and all(
+        x.shape == (24, 4, 2, 8) and
+        x.format.layout.major_to_minor == (0, 1, 2, 3) for x in pools)
+
+
+@pytest.mark.parametrize("writer", list(_CACHE_WRITERS))
+def test_cache_writer_hands_the_pools_back_as_held(writer, monkeypatch):
+    from horovod_tpu.chaos import inject
+    # no chaos plan is armed here: the fault body flips the first bit
+    monkeypatch.setattr(inject, "corrupt_copy",
+                        lambda raw: bytes([raw[0] ^ 1]) + bytes(raw[1:]))
+    given = _gpt_params()
+    ex, twin = _gpt_executor(given), _gpt_executor(given)
+    assert _pools_held_row_major(ex)        # as the constructor made them
+    _, lengths = _prompt_batch()
+
+    def decode(e, nxt, i):
+        return e.step(nxt[:, None].astype(np.int32), lengths + i,
+                      np.ones(_ROWS, bool), np.zeros(_ROWS, np.int32),
+                      kind="decode", block_tables=_tables(e))
+    a, b = decode(ex, _first_step(ex), 0), decode(twin, _first_step(twin), 0)
+    before = ex.kv_block_bytes(_SPARE, 0, ex.kv_block_size)
+    _CACHE_WRITERS[writer](ex)              # the detour `twin` never sees
+    assert _pools_held_row_major(ex)
+    if writer == "corrupt_kv_block":        # and it did write
+        assert ex.kv_block_bytes(_SPARE, 0, ex.kv_block_size) != before
+    programs = ex.jit_cache_size()
+    for i in range(1, 4):
+        a, b = decode(ex, a, i), decode(twin, b, i)
+        np.testing.assert_array_equal(a, b)
+    assert ex.jit_cache_size() == programs
+    assert _pools_held_row_major(ex)
+
+
+def test_constructor_logs_the_pools_layout_and_bytes(caplog):
+    with caplog.at_level(logging.INFO, "horovod_tpu"):
+        ex = _gpt_executor(_gpt_params())
+    (line,) = [r.getMessage() for r in caplog.records
+               if r.getMessage().startswith("serve executor")]
+    logical = sum(x.nbytes for x in ex._cache_leaves())
+    # the CPU pads nothing: both counts are the values' bytes
+    assert logical == 4 * 24 * 4 * 2 * 8 * 2        # 4 pools of bfloat16
+    assert f"4 pools bfloat16[24, 4, 2, 8] held (0, 1, 2, 3), pool " \
+        f"bytes {logical} -> {logical} on the device" in line, line

@@ -17,6 +17,7 @@ kernels compute on the MXU, and whether the programs fit next to
 everything else in HBM, is the chip's to say (`chip_smoke.py`).
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -273,16 +274,20 @@ def test_mosaic_accepts_the_grouped_expert_matmul(v5e, tokens):
         == 2
 
 
-def test_decode_step_reads_no_float32_matmul_kernel(v5e, monkeypatch):
-    """The decode step of `ShardedExecutor` at GPT-2 XL widths (2
-    layers, 8 rows, the Pallas kernel), compiled with the tree the
-    executor HOLDS: the four `Dense` kernels of a block enter the
-    program in the compute dtype, so no step reads them in float32
-    (serve/executor.py, "Resident dtypes")."""
+# -- the serve executor's own step programs, at GPT-2 XL widths ---------------
+# 2 layers, 8 rows, 25 heads of 64, blocks of 16, the Pallas kernel: the
+# programs `ShardedExecutor` jits, compiled for a described chip as it
+# would run them there (the cache donated).
+
+_XL_ROWS, _XL_BLOCK, _XL_LEN = 8, 16, 640
+
+
+@pytest.fixture
+def xl_executor(v5e, monkeypatch):
     from horovod_tpu.models.gpt import GPT, GPTConfig
     from horovod_tpu.serve import ShardedExecutor
     kw = dict(vocab_size=256, num_layers=2, num_heads=25, head_dim=64,
-              max_seq_len=640)
+              max_seq_len=_XL_LEN)
     params = jax.jit(lambda k: GPT(GPTConfig(**kw)).init(
         k, jnp.zeros((1, 8), jnp.int32))["params"])(jax.random.PRNGKey(0))
     # the model asks the default backend (the CPU, here) whether to
@@ -290,28 +295,139 @@ def test_decode_step_reads_no_float32_matmul_kernel(v5e, monkeypatch):
     monkeypatch.setattr(
         pallas_paged, "paged_attention_fused", functools.partial(
             pallas_paged.paged_attention_fused, interpret=False))
-    rows, block = 8, 16
     ex = ShardedExecutor(
-        GPT(GPTConfig(decode=True, kv_block_size=block,
-                      kv_pool_blocks=pool_blocks_for(rows, 640, block),
+        GPT(GPTConfig(decode=True, kv_block_size=_XL_BLOCK,
+                      kv_pool_blocks=pool_blocks_for(_XL_ROWS, _XL_LEN,
+                                                     _XL_BLOCK),
                       decode_kernel="pallas", **kw)),
-        params, max_batch=rows, max_len=640)
-    sh = SingleDeviceSharding(v5e[0])
+        params, max_batch=_XL_ROWS, max_len=_XL_LEN)
+    # the same step function, donated as off the CPU
+    ex._fwd_token = jax.jit(ex._fwd_token.__wrapped__, donate_argnums=(1,))
+    ex.chip = SingleDeviceSharding(v5e[0])
+    return ex
+
+
+def _compile_step(ex, tokens):
+    """`ex._fwd_token` at ``[rows, tokens]``, compiled for the chip."""
+    sh, rows = ex.chip, ex.max_batch
 
     def on_chip(tree):
         return jax.tree_util.tree_map(
             lambda x: _sds(x.shape, x.dtype, sh), tree)
     i32 = functools.partial(_sds, dtype=jnp.int32, sharding=sh)
     f32 = _sds((rows,), jnp.float32, sh)
-    compiled = _aot_compile(ex._fwd_token, [
-        on_chip(ex.params), on_chip(ex.cache), i32((rows, 1)), i32((rows,)),
-        _sds((rows,), bool, sh), i32((rows,)), f32, f32,
+    return _aot_compile(ex._fwd_token, [
+        on_chip(ex.params), on_chip(ex.cache), i32((rows, tokens)),
+        i32((rows,)), _sds((rows,), bool, sh), i32((rows,)), f32, f32,
         _sds((rows,), jnp.uint32, sh), i32((rows,)),
         i32((rows, ex.blocks_per_seq))])
-    entry = next(line for line in compiled.as_text().splitlines()
-                 if "entry_computation_layout" in line)
+
+
+def _entry_layout(compiled) -> str:
+    return next(line for line in compiled.as_text().splitlines()
+                if "entry_computation_layout" in line)
+
+
+def test_decode_step_reads_no_float32_matmul_kernel(xl_executor):
+    """The decode step of `ShardedExecutor` at GPT-2 XL widths (2
+    layers, 8 rows, the Pallas kernel), compiled with the tree the
+    executor HOLDS: the four `Dense` kernels of a block enter the
+    program in the compute dtype, so no step reads them in float32
+    (serve/executor.py, "Resident dtypes")."""
+    compiled = _compile_step(xl_executor, 1)
+    entry = _entry_layout(compiled)
     for shape in ("[1600,4800]", "[1600,1600]", "[1600,6400]",
                   "[6400,1600]"):
         assert "f32" + shape not in entry, shape
         assert entry.count("bf16" + shape) == 2, shape       # one a layer
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+@pytest.mark.parametrize("tokens", [1, 128], ids=["decode", "prefill128"])
+def test_step_copies_no_pool(xl_executor, tokens):
+    """The pools are held in a shape the chip lays out row-major by
+    itself (`kv_cache.write_kv_pools`), the order the scatter and the
+    paged kernel read, so the step re-lays none of them out: no
+    ``copy`` of a pool, held or as the attention sees it (the chip's
+    layout for ``[160,16,25,64]`` costs six a layer), at most one
+    staged through another memory space (``copy-start``), every pool
+    row-major at the program's entry and the donated cache aliased
+    whole."""
+    ex = xl_executor
+    pools = ex._cache_leaves()
+    assert pools[0].shape == (160, 16, 32, 128)
+    compiled = _compile_step(ex, tokens)
+
+    def count(op, shape):   # instructions `op` whose (first) result is it
+        return sum(1 for line in compiled.as_text().splitlines()
+                   if f" {op}(" in line and
+                   re.search(r" = \(?" + re.escape(shape) + r"\{", line))
+    held = "bf16[160,16,32,128]"
+    assert count("copy", held) == 0
+    assert count("copy", "bf16[160,16,25,64]") == 0
+    assert count("copy-start", held) <= 1
+    layouts = re.findall(re.escape(held) + r"\{([\d,]+)",
+                         _entry_layout(compiled))
+    assert len(layouts) == 2 * len(pools)       # arguments and results
+    assert set(layouts) == {"3,2,1,0"}, layouts
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        x.nbytes for x in pools)
+
+
+# the two serving cells' pools: GPT-2 XL's (8 rows, 25 heads of 64, blocks
+# of 16) and the long-context cell's (`_LONG`), full and window layers
+_POOLS = {
+    "gpt2-xl": dict(rows=8, H=25, KV=25, D=64, block=16, entries=40,
+                    pool=160, window=None),
+    "long-full": dict(_LONG, window=None),
+    "long-window": _LONG,
+}
+
+
+@pytest.mark.parametrize("case", list(_POOLS))
+def test_chip_lays_a_held_pool_out_row_major(v5e, case):
+    """Scatter + paged kernel over one layer's pools in the shape they
+    are HELD in (`kv_cache.held_pool_shape`), donated, at both cells'
+    pool shapes: with no layout named the pools enter and leave
+    row-major and none is copied, and with the choice left to the
+    compiler (`Layout.AUTO`) it is row-major too. A compiler that
+    changes its mind fails here, not in a benchmark."""
+    from jax.experimental.layout import Format, Layout
+    from horovod_tpu.serve.kv_cache import held_pool_shape, write_kv_paged
+    c = _POOLS[case]
+    sh = SingleDeviceSharding(v5e[0])
+    KV, D = c["KV"], c["D"]
+    held = held_pool_shape(c["pool"], c["block"], KV, D)
+    # the long-context cell's pools are held as they are
+    assert held == ((160, 16, 32, 128) if case == "gpt2-xl"
+                    else (c["pool"], c["block"], KV, D))
+
+    def layer(pool_k, pool_v, q, k_new, v_new, positions, mask, tables):
+        pool_k, pool_v = write_kv_paged(pool_k, pool_v, k_new, v_new,
+                                        positions, mask, tables)
+        out = pallas_paged._paged_attention_call(
+            q, pool_k[:, :, :KV, :D], pool_v[:, :, :KV, :D], tables,
+            positions, interpret=False, window=c["window"])
+        return out, pool_k, pool_v
+
+    new = _sds((c["rows"], 1) + held[2:], jnp.bfloat16, sh)
+    rest = [_sds((c["rows"], 1, c["H"], D), jnp.bfloat16, sh), new, new,
+            _sds((c["rows"],), jnp.int32, sh), _sds((c["rows"],), bool, sh),
+            _sds((c["rows"], c["entries"]), jnp.int32, sh)]
+    pool = _sds(held, jnp.bfloat16, sh)
+    compiled = _aot_compile(jax.jit(layer, donate_argnums=(0, 1)),
+                            [pool, pool] + rest)
+    shape = "bf16[%s]" % ",".join(map(str, held))
+    text = compiled.as_text()
+    assert set(re.findall(re.escape(shape) + r"\{([\d,]+)",
+                          _entry_layout(compiled))) == {"3,2,1,0"}
+    assert not re.search(r" = " + re.escape(shape) + r"\{[^ ]* copy\(", text)
+    auto = Format(Layout.AUTO, sh)
+    chosen = _aot_compile(
+        jax.jit(layer, donate_argnums=(0, 1),
+                in_shardings=(auto, auto) + (None,) * 6,
+                out_shardings=(None, auto, auto)),
+        [_sds(held, jnp.bfloat16)] * 2 + rest)
+    formats = list(chosen.input_formats[0][:2]) + \
+        list(chosen.output_formats[1:])
+    assert [f.layout.major_to_minor for f in formats] == [(0, 1, 2, 3)] * 4
