@@ -139,6 +139,33 @@ class ContinuousBatcher:
         self.executor = executor
         self.queue = queue
         self.buckets = buckets
+        # a model whose sequences hold per-row state beside their
+        # blocks (models/sala_lm.py): what hands a row BLOCKS cannot
+        # hand it the state that goes with them. Refused here, by
+        # mechanism: a wrong answer is worse than a refusal
+        stateful = bool(getattr(executor, "per_row_state", False))
+        if stateful:
+            name = type(executor.model).__name__
+            if prefix_cache:
+                raise ValueError(
+                    f"prefix_cache=True with {name}: a radix hit hands a "
+                    f"row its prefix's blocks, and the layers with "
+                    f"recurrent state need the STATE at the hit's end, "
+                    f"which nothing snapshots (serve/prefix.py)")
+            if kv_tier:
+                raise ValueError(
+                    f"kv_tier=True with {name}: the tier demotes and "
+                    f"promotes prefix BLOCKS; the per-row state at a "
+                    f"prefix's end is not kept with them (serve/kvtier/)")
+            if (spec_k or 0) > 0 or \
+                    (draft_executor is not None and spec_k is None):
+                raise ValueError(
+                    f"speculative decoding with {name}: a rejected draft "
+                    f"is rolled back by moving a row's length, and a "
+                    f"recurrent state that has absorbed the draft cannot "
+                    f"be rolled back (no snapshot to return to)")
+            # the defaults that cannot apply are off, not an error
+            prefix_cache, kv_tier = False, False
         #: rows a prefill step holds. None: the packed
         #: ``[max_batch, bucket]`` step, every admitted row at the
         #: longest one's bucket. A model whose config names
@@ -329,6 +356,16 @@ class ContinuousBatcher:
             "hvd_serve_kv_blocks_in_use",
             "KV pool blocks currently allocated",
             rl or None)
+        #: per-row recurrent state the live rows hold (a model without
+        #: any registers no such series)
+        self._m_state = None
+        if stateful:
+            if replica_id is None:
+                R.unregister("hvd_serve_state_bytes")
+            self._m_state = R.gauge(
+                "hvd_serve_state_bytes",
+                "per-row recurrent state held by live rows, bytes (not "
+                "addressed by block tables)", rl or None)
         self._m_accept = R.histogram(
             "hvd_serve_spec_accept_rate",
             "speculative decode: fraction of draft tokens accepted per "
@@ -446,7 +483,8 @@ class ContinuousBatcher:
         for b in self.buckets:
             self.executor.step(
                 np.zeros((R, b), np.int32), zero[:R], off[:R], zero[:R],
-                kind="prefill", block_tables=tbl[:R])
+                kind="prefill", block_tables=tbl[:R],
+                **self._slots_arg(zero[:R]))
         self.executor.step(np.zeros((B, 1), np.int32), zero, off, zero,
                            kind="decode", block_tables=tbl)
         self.executor.copy_kv_block(0, 0)   # compile the CoW copy
@@ -461,6 +499,14 @@ class ContinuousBatcher:
             self.draft.step(np.zeros((B, 1), np.int32), zero, off, zero,
                             kind="decode",
                             block_tables=self._draft_tables)
+
+    def _slots_arg(self, slots) -> dict:
+        """``state_slots`` for a row-compact prefill step of a model
+        with per-row state: which batch slot each row of the step
+        stands for. Any other model's step takes no such argument."""
+        if not getattr(self.executor, "per_row_state", False):
+            return {}
+        return {"state_slots": np.asarray(slots, np.int32)}
 
     # -- chaos guards (one attribute read when disarmed) ---------------------
     def _fire_step_chaos(self) -> None:
@@ -715,6 +761,8 @@ class ContinuousBatcher:
         (capacity reservation, device writes, ledger seeding, version
         fence) runs on the scheduler thread at the top of the next
         iteration."""
+        from .kv_migrate import refuse_per_row_state
+        refuse_per_row_state(self.executor)
         from .queue import ServeHandle
         handle = ServeHandle(int(meta.get("rid", -1)))
         entry = {"meta": dict(meta), "blocks": blocks,
@@ -846,6 +894,8 @@ class ContinuousBatcher:
         occ = self.kv.occupancy()
         self._m_occupancy.set(occ)
         self._m_blocks.set(self.kv.pool.in_use())
+        if self._m_state is not None:
+            self._m_state.set(self.kv.live() * self.executor.state_row_bytes)
         if self.executor.timeline is None:
             return None
         return {"queue_depth": self.queue.depth(),
@@ -1164,11 +1214,13 @@ class ContinuousBatcher:
             mask = np.zeros(rows, bool)
             last_idx = np.zeros(rows, np.int32)
             tables = self.kv.table()
+            slots = {}
             if R is not None:
                 # each row's own table, in the step's row order
                 at = np.full(rows, -1)
                 at[[r for r, _ in wave]] = [a.slot for _, a in wave]
                 tables = np.where(at[:, None] >= 0, tables[at], -1)
+                slots = self._slots_arg(np.maximum(at, 0))
             for r, a in wave:
                 m = a.prefix_tokens
                 suffix = a.req.prompt[m:]
@@ -1182,7 +1234,7 @@ class ContinuousBatcher:
                 sample=self._sample_args(
                     [a.slot for _, a in wave],
                     rows=None if R is None else [r for r, _ in wave]),
-                block_tables=tables)
+                block_tables=tables, **slots)
             stale = stale or \
                 self.executor.last_step_version != expected_v
             t_wave = time.monotonic()   # one stamp for the wave's rows

@@ -70,6 +70,30 @@ from ..trace.spans import get_recorder as _trace_recorder
 logger = logging.getLogger("horovod_tpu")
 
 
+def _named_leaves(tree) -> list:
+    """``(name, leaf)`` of a flax collection in flatten order, the name
+    being the variable's own (the last dict key of its path)."""
+    return [(next(k.key for k in reversed(path) if hasattr(k, "key")), leaf)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+def _stat_totals(stats) -> Dict[str, Any]:
+    """A ``stats`` collection (one sown value a layer and counter) ->
+    each counter's sum over the layers, by the counter's name."""
+    totals: Dict[str, Any] = {}
+    for name, leaf in _named_leaves(stats):
+        totals[name] = totals.get(name, 0) + jnp.sum(leaf)
+    return totals
+
+
+#: the kinds of leaf a model's ``cache`` collection may hold
+#: (docs/serving.md): a K or V pool; a per-BLOCK leaf of another shape,
+#: which follows the block tables; a per-ROW leaf, which follows the
+#: batch slot. A model config names the leaves that are no K/V pool
+#: (``cache_leaves``: leaf name -> kind)
+KV_POOL, PER_BLOCK, PER_ROW = "kv", "block", "row"
+
+
 def _resident_dtypes(jaxpr, given: list) -> list:
     """The dtype each parameter leaf is HELD in, read off the traced
     forward: a leaf whose every use is a ``convert_element_type`` to one
@@ -144,6 +168,14 @@ class ShardedExecutor:
             # rows are free
             cfg.kv_pool_blocks = max_batch * self.blocks_per_seq
         self.kv_pool_blocks = int(cfg.kv_pool_blocks)
+        #: does a sequence of this model hold state that the block
+        #: tables do not address (a per-row leaf of the cache, below)?
+        #: Sized here as the pool is: a slot a batch row
+        self.per_row_state = bool(getattr(cfg, "per_row_state", False))
+        if self.per_row_state:
+            cfg.state_rows = max_batch
+        #: layers whose decode step attends to blocks it selects
+        self._select_layers = int(getattr(cfg, "block_select_layers", 0))
         if self.kv_pool_blocks < self.blocks_per_seq:
             raise ValueError(
                 f"kv_pool_blocks {self.kv_pool_blocks} cannot cover one "
@@ -233,30 +265,34 @@ class ShardedExecutor:
         emit_probs = role == "draft"
 
         def apply_model(params, cache, tokens, positions, mask, tables,
-                        logits_idx):
+                        logits_idx, slots=()):
+            # `slots` (a model with per-row state only): which batch
+            # slot's state each row of the step reads and writes
+            kw = {"state_slots": slots[0]} if slots else {}
             return self.model.apply(
                 {"params": params, "cache": cache}, tokens,
                 positions=positions, update_mask=mask,
                 logits_idx=logits_idx, mutable=["cache", "stats"],
-                block_tables=tables)
+                block_tables=tables, **kw)
 
         def with_stats(per_row, vout):
-            """A model that sows step counters (``stats`` collection:
-            experts that received a token, per layer) gets their sum
-            appended to the step's per-row int32 result, so the one
-            readback carries it; any other model's program is as it
-            was."""
+            """A model that sows step counters (``stats`` collection,
+            per layer: experts that received a token; blocks a sparse
+            layer attended) gets each counter's sum over the layers
+            appended to the step's per-row int32 result, in the order
+            of `_stat_names`, so the one readback carries them; any
+            other model's program is as it was."""
             if "stats" not in vout:
                 return per_row
-            total = sum(jnp.sum(x) for x in
-                        jax.tree_util.tree_leaves(vout["stats"]))
+            totals = _stat_totals(vout["stats"])
             return jnp.concatenate(
-                [per_row, total.astype(per_row.dtype)[None]])
+                [per_row] + [totals[n].astype(per_row.dtype)[None]
+                             for n in sorted(totals)])
 
         def fwd_token(params, cache, tokens, positions, mask, last_idx,
-                      temp, top_p, seed, ctr, tables):
+                      temp, top_p, seed, ctr, tables, *slots):
             logits, vout = apply_model(params, cache, tokens, positions,
-                                       mask, tables, last_idx)
+                                       mask, tables, last_idx, slots)
             tok, probs = sample_with_probs(
                 logits[:, 0], temp, top_p, seed, ctr, stream=stream)
             tok = with_stats(tok, vout)
@@ -295,9 +331,32 @@ class ShardedExecutor:
             params, S((max_batch, 1), jnp.int32), S((max_batch,), jnp.int32),
             S((max_batch,), bool),
             S((max_batch, self.blocks_per_seq), jnp.int32))
-        #: does the model sow step counters (see `with_stats`)? The
-        #: collection is there or it is not
-        self._has_stats = "stats" in collections
+        #: the step counters the model sows (see `with_stats`), by
+        #: name; the collection is there or it is not
+        self._stat_names = sorted(
+            {n for n, _ in _named_leaves(collections.get("stats", {}))})
+        #: the kind of each cache leaf, in flatten order
+        named = getattr(cfg, "cache_leaves", None) or {}
+        cache_leaves = _named_leaves(collections["cache"])
+        self._leaf_kinds = [named.get(n, KV_POOL) for n, _ in cache_leaves]
+        #: bytes of per-row state one batch slot holds on the device (0:
+        #: the model's sequences are their blocks and nothing else)
+        self.state_row_bytes = sum(
+            int(np.prod(leaf.shape[1:])) * jnp.dtype(leaf.dtype).itemsize
+            for kind, (_, leaf) in zip(self._leaf_kinds, cache_leaves)
+            if kind == PER_ROW)
+        for kind, (name, leaf) in zip(self._leaf_kinds, cache_leaves):
+            lead = {KV_POOL: (self.kv_pool_blocks, self.kv_block_size),
+                    PER_BLOCK: (self.kv_pool_blocks,),
+                    PER_ROW: (max_batch,)}.get(kind)
+            if lead is None or leaf.shape[:len(lead)] != lead or \
+                    (kind == KV_POOL and len(leaf.shape) != 4):
+                raise ValueError(
+                    f"cache leaf {name!r} {leaf.shape} is no {kind!r} "
+                    f"leaf of a {self.kv_pool_blocks}x"
+                    f"{self.kv_block_size} pool and {max_batch} rows: a "
+                    f"model names its leaves that are no K/V pool in "
+                    f"cfg.cache_leaves (docs/serving.md)")
         leaves, self._treedef = jax.tree_util.tree_flatten(params)
         # the zeros go where the parameters are: replicated over a mesh
         # (the Megatron serving layout), else COMMITTED to the device
@@ -337,9 +396,12 @@ class ShardedExecutor:
         # makes it an in-place pool write on TPU instead of a full
         # pool copy per CoW
         def copy_block(cache, src, dst):
-            return jax.tree_util.tree_map(
-                lambda leaf: leaf.at[dst].set(leaf[src])
-                if self._is_pool(leaf) else leaf, cache)
+            # every leaf that follows the block tables; a per-row leaf
+            # is no block's
+            leaves, treedef = jax.tree_util.tree_flatten(cache)
+            return jax.tree_util.tree_unflatten(treedef, [
+                leaf if kind == PER_ROW else leaf.at[dst].set(leaf[src])
+                for kind, leaf in zip(self._leaf_kinds, leaves)])
 
         self._copy_block = jax.jit(
             copy_block, donate_argnums=() if
@@ -395,7 +457,8 @@ class ShardedExecutor:
              stats: Optional[Dict[str, Any]] = None,
              block_tables: Optional[np.ndarray] = None,
              sample: Optional[Dict[str, np.ndarray]] = None,
-             draft_probs=None, n_draft: Optional[np.ndarray] = None):
+             draft_probs=None, n_draft: Optional[np.ndarray] = None,
+             state_slots: Optional[np.ndarray] = None):
         """Run one fixed-shape forward step.
 
         tokens [max_batch, T] int32; positions/last_idx [max_batch]
@@ -408,7 +471,10 @@ class ShardedExecutor:
         too) of that many rows: the pool is addressed through the
         tables alone, so a step's rows need not be the batch's (the
         batcher prefills a long prompt alone; each ``(rows, T)`` shape
-        is one compiled program, to be warmed like any other).
+        is one compiled program, to be warmed like any other). For a
+        model with per-row state ``state_slots`` [rows] says which
+        batch slot each row of such a step stands for (a masked-out
+        row's is ignored); a full-batch step's rows are the slots.
         `stats` (queue depth, occupancy, shed count — batcher-supplied)
         is folded into the SERVE event.
 
@@ -436,6 +502,13 @@ class ShardedExecutor:
             raise ValueError(
                 f"{kind} step of {B} rows on an executor of "
                 f"{self.max_batch}: only a prefill may be row-compact")
+        if self.per_row_state and state_slots is None:
+            if B != self.max_batch:
+                raise ValueError(
+                    f"a row-compact step of {B} rows of a model with "
+                    f"per-row state needs `state_slots`: which batch "
+                    f"slot's state each row reads and writes")
+            state_slots = np.arange(B)
         n_tok = int(np.sum(mask))
         rec = _trace_recorder()
         probs = None
@@ -470,6 +543,8 @@ class ShardedExecutor:
                          jnp.asarray(s["seed"], jnp.uint32),
                          jnp.asarray(s["ctr"], jnp.int32)] + tail
                 args.append(jnp.asarray(block_tables, jnp.int32))
+                if self.per_row_state and kind != "verify":
+                    args.append(jnp.asarray(state_slots, jnp.int32))
             with self._swap_lock:   # the weight-swap version fence
                 self.last_step_version = self.params_version
                 fwd = self._fwd_verify if kind == "verify" \
@@ -486,14 +561,25 @@ class ShardedExecutor:
                         nxt = np.asarray(out[0])
                         if self.role == "draft":
                             probs = out[1]
-            if self._has_stats:
-                # the model's step counter rode in on the readback as
-                # one more entry of the per-row result
+            if self._stat_names:
+                # the model's step counters rode in on the readback as
+                # further entries of the per-row result
+                n = len(self._stat_names)
                 if kind == "verify":
-                    nxt, hit = (nxt[0], nxt[1][:-1]), nxt[1][-1]
+                    nxt, sown = (nxt[0], nxt[1][:-n]), nxt[1][-n:]
                 else:
-                    nxt, hit = nxt[:-1], nxt[-1]
-                step_span.set(experts_hit=int(hit))
+                    nxt, sown = nxt[:-n], nxt[-n:]
+                step_span.set(**{name: int(v) for name, v in
+                                 zip(self._stat_names, sown)})
+            if kind == "decode" and self._select_layers:
+                # what the selecting layers COULD have read: each live
+                # row's cached blocks, the one being written included
+                live = np.asarray(positions)[np.asarray(mask, bool)]
+                step_span.set(blocks_cached=int(
+                    self._select_layers
+                    * np.sum(live // self.kv_block_size + 1)))
+            if kind == "decode" and self.per_row_state:
+                step_span.set(state_rows=n_tok)
         dt_ms = (time.perf_counter() - t0) * 1000.0
         self.steps += 1
         self._m_step_ms.get(kind, self._m_step_ms["decode"]).observe(dt_ms)
@@ -597,17 +683,14 @@ class ShardedExecutor:
         return True
 
     # -- KV integrity hooks (serve.kv chaos + crc option) --------------------
-    def _is_pool(self, leaf) -> bool:
-        """Is this leaf of the cache collection a KV pool,
-        ``[pool_blocks, block_size, H_kv, D]``?"""
-        return getattr(leaf, "ndim", 0) == 4 and leaf.shape[:2] == (
-            self.kv_pool_blocks, self.kv_block_size)
-
-    def _cache_leaves(self) -> list:
-        """The device KV pools inside the flax cache collection, in
-        flatten order (cache_k and cache_v of each layer)."""
-        return [l for l in jax.tree_util.tree_leaves(self.cache)
-                if self._is_pool(l)]
+    def _cache_leaves(self, kind: str = KV_POOL) -> list:
+        """The leaves of one kind inside the flax cache collection, in
+        flatten order; by default the device KV pools (cache_k and
+        cache_v of each layer). The integrity ledger and migration
+        cover the K/V pools only."""
+        return [l for k, l in zip(self._leaf_kinds,
+                                  jax.tree_util.tree_leaves(self.cache))
+                if k == kind]
 
     def kv_block_bytes(self, block: int, start: int,
                        stop: int) -> list:
@@ -653,9 +736,17 @@ class ShardedExecutor:
         if not blocks:
             return
         bs = self.kv_block_size
+        if set(self._leaf_kinds) != {KV_POOL}:
+            raise ValueError(
+                "install_kv_blocks: this model's cache holds leaves that "
+                "are no K/V pool (per-block leaves of another shape, "
+                "per-row state); a migrated sequence would arrive "
+                "without them. Migration of such state is not "
+                "implemented (docs/serving.md)")
         with self._swap_lock:
             leaves, treedef = jax.tree_util.tree_flatten(self.cache)
-            idxs = [i for i, l in enumerate(leaves) if self._is_pool(l)]
+            idxs = [i for i, k in enumerate(self._leaf_kinds)
+                    if k == KV_POOL]
             if any(len(lb) != len(idxs) for lb in block_leaf_bytes):
                 raise ValueError(
                     f"install_kv_blocks: payload leaf counts "
@@ -693,8 +784,7 @@ class ShardedExecutor:
         from ..chaos import inject as _chaos
         with self._swap_lock:
             leaves, treedef = jax.tree_util.tree_flatten(self.cache)
-            idx = next(i for i, l in enumerate(leaves)
-                       if self._is_pool(l))
+            idx = self._leaf_kinds.index(KV_POOL)
             row = np.array(leaves[idx][block, :length])
             flipped = np.frombuffer(
                 _chaos.corrupt_copy(row.tobytes()),

@@ -63,6 +63,19 @@ class MigrateCorrupt(RuntimeError):
     re-packs from the source of truth or re-prefills."""
 
 
+def refuse_per_row_state(executor) -> None:
+    """Migration moves a sequence as its KV BLOCKS. A model whose
+    sequences also hold per-row state (the recurrent state of
+    linear-attention layers, models/sala_lm.py) would arrive without it
+    and decode garbage: refused by name, on both ends."""
+    if getattr(executor, "per_row_state", False):
+        raise ValueError(
+            f"KV-block migration of {type(executor.model).__name__}: a "
+            f"sequence of this model holds per-row state that no block "
+            f"carries, and the packet has no place for it (a state "
+            f"snapshot beside the blocks is not implemented)")
+
+
 def pack_parked(batcher, rid: int, *, fid: str,
                 max_new_tokens: int,
                 deadline_ms: float) -> Optional[Tuple[dict, bytes]]:
@@ -84,6 +97,7 @@ def pack_parked(batcher, rid: int, *, fid: str,
     prefill replica raises :class:`MigrateCorrupt` here instead of
     migrating garbage.
     """
+    refuse_per_row_state(batcher.executor)
     # PIN the parked row for the whole read: the scheduler's TTL
     # reaper (or a racing release) must not free — and the pool
     # re-issue — these blocks mid-pack, or the crcs would be stamped

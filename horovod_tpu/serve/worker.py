@@ -594,6 +594,10 @@ class ReplicaWorker:
             max_batch=int(built.get("max_batch", 4)),
             max_len=int(built.get("max_len", 48)),
             replica_id=self.rid)
+        if cfg.get("pool") in ("prefill", "decode"):
+            # a disaggregated pool hands sequences over as KV blocks
+            from .kv_migrate import refuse_per_row_state
+            refuse_per_row_state(self.executor)
         draft = built.get("draft_model")
         self.draft_executor = None if draft is None else ShardedExecutor(
             draft, built["params"],

@@ -431,3 +431,83 @@ def test_chip_lays_a_held_pool_out_row_major(v5e, case):
     formats = list(chosen.input_formats[0][:2]) + \
         list(chosen.output_formats[1:])
     assert [f.layout.major_to_minor for f in formats] == [(0, 1, 2, 3)] * 4
+
+
+# -- the sparse + linear hybrid's decode step ---------------------------------
+# One lightning and one block-sparse layer at the published widths (4096
+# wide, 32 heads of 128, 2 KV heads, a feed-forward of 16,384; 16 rows, the
+# cell's pool of 4,608 blocks of 64 and tables of 576 entries), the
+# vocabulary cut to keep the shapes' bookkeeping light. Nothing is held:
+# the executor is built from the parameters' SHAPES.
+
+@pytest.fixture
+def sala_executor(v5e, monkeypatch):
+    from chipbench import manifest as mf
+    from horovod_tpu.ops import lightning
+    from horovod_tpu.serve import ShardedExecutor
+    family = mf.load_module("chipbench/families/sala.py")
+    config = mf.load_json("chipbench/configs/minicpm-sala-l12.json")
+    config.update(num_hidden_layers=2, vocab_size=512)
+    config["assumed"]["first_layer"] = 8        # lightning, then sparse
+    shape = family.Shape(config)
+    assert shape.mixers == ("lightning-attn", "minicpm4")
+    rows, max_len = 16, 36864
+    model = family.serve_model(
+        shape, config, kv_block=64,
+        kv_pool_blocks=pool_blocks_for(rows, max_len, 64),
+        decode_kernel="pallas")
+    params = jax.eval_shape(lambda k: family.program_params(shape, k),
+                            family.seed_key(0))
+    # the model asks the default backend (the CPU, here) whether to
+    # interpret its kernels: steer it from the test
+    monkeypatch.setattr(
+        pallas_paged, "paged_attention_fused", functools.partial(
+            pallas_paged.paged_attention_fused, interpret=False))
+    decode = lightning.lightning_decode
+    monkeypatch.setattr(
+        lightning, "lightning_decode",
+        lambda *a, interpret: decode(*a, interpret=False))
+    ex = ShardedExecutor(model, params, max_batch=rows, max_len=max_len)
+    ex._fwd_token = jax.jit(ex._fwd_token.__wrapped__, donate_argnums=(1,))
+    ex.chip = SingleDeviceSharding(v5e[0])
+    return ex
+
+
+def test_hybrid_decode_step_aliases_the_state_and_the_pools(sala_executor):
+    """The decode step compiles for the chip with both named kernels in
+    it, the donated cache aliased whole (pools, compressed keys, the
+    lightning state) and no copy of a pool, of the per-block leaf or of
+    the state: the selection reads the compressed keys through a gather,
+    the attended tables go to the paged kernel, the state is updated in
+    place."""
+    ex = sala_executor
+    sh, rows = ex.chip, ex.max_batch
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _sds(x.shape, x.dtype, sh), tree)
+    i32 = functools.partial(_sds, dtype=jnp.int32, sharding=sh)
+    f32 = _sds((rows,), jnp.float32, sh)
+    compiled = _aot_compile(ex._fwd_token, [
+        on_chip(ex.params), on_chip(ex.cache), i32((rows, 1)),
+        i32((rows,)), _sds((rows,), bool, sh), i32((rows,)), f32, f32,
+        _sds((rows,), jnp.uint32, sh), i32((rows,)),
+        i32((rows, ex.blocks_per_seq)), i32((rows,))])
+    text = compiled.as_text()
+    for kernel in ("lightning_decode", "_paged_attention_call"):
+        assert re.search(r"%" + kernel + r"[.\d]* = .*custom-call", text), \
+            kernel
+    leaves = jax.tree_util.tree_leaves(ex.cache)
+    assert sorted(ex._leaf_kinds) == ["block", "kv", "kv", "row"]
+    for leaf in leaves:
+        shape = "%s[%s]" % ({"bfloat16": "bf16", "float32": "f32"}[
+            str(leaf.dtype)], ",".join(map(str, leaf.shape)))
+        assert not re.search(
+            r" = " + re.escape(shape) + r"\{[^ ]* copy\(", text), shape
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        x.nbytes for x in leaves)
+    # a (row, KV group) pair is a row to the paged kernel: 32 of them,
+    # over the 128-entry attended table and not the 576-entry one
+    call = next(line for line in text.splitlines()
+                if re.search(r"%_paged_attention_call[.\d]* = ", line))
+    assert "s32[32,128]" in call and "s32[32,576]" not in call
