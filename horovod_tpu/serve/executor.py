@@ -38,6 +38,16 @@ Three decisions are frozen at build time so the jit cache stays flat:
   float32 head, a checkpoint already in its compute dtype) is held as
   given. The caller's tree is not consumed.
 
+**One transfer a step.** Everything a step receives as host data
+crosses to the device as ONE ``int32 [rows, T + words]`` buffer
+(`pack_step`): a row's tokens, its position, mask, emitting index (or
+draft count), sampling data as bit patterns, block table and, for a
+model with per-row state, state slot. The jitted step takes it apart
+with static slices and bitcasts (`unpack_step`), so the model and the
+samplers receive the values and dtypes they always did; the buffer's
+shape carries ``rows`` and ``T``, so it is the program's key as the
+token array's shape was (docs/serving.md has the table).
+
 Sharding rides the training stack unchanged: pass `mesh` plus the
 model's `PartitionRules` (parallel/tp.py) and parameters are placed with
 `shard_params`; jit/GSPMD then emits the same ICI collectives the
@@ -122,6 +132,65 @@ def _resident_dtypes(jaxpr, given: list) -> list:
             jnp.dtype(to).itemsize < jnp.dtype(dt).itemsize
         held.append(jnp.dtype(to) if narrower else dt)
     return held
+
+
+#: the words of a packed step row between its tokens and its block
+#: table, one int32 each: position, update mask (0/1), emitting index
+#: (a verify step: draft count), temperature and top_p (float32 bits),
+#: seed (uint32 bits), draw counter
+ROW_WORDS = 7
+
+
+def pack_step(tokens, positions, mask, idx, sample, tables,
+              blocks_per_seq: int, slots=None) -> np.ndarray:
+    """A step's host inputs as ONE ``int32 [rows, T + ROW_WORDS +
+    blocks_per_seq (+ 1)]`` array, row by row in the order
+    `unpack_step` reads: tokens, position, mask, ``idx`` (``last_idx``
+    of a token step, ``n_draft`` of a verify step), temperature, top_p,
+    seed, ctr, the block table, and the state slot where ``slots`` is
+    given. Float32 and uint32 fields travel as their bit patterns. An
+    array of another shape than the step's rows and the executor's
+    table width ask for is refused: numpy would broadcast it, and a
+    narrower table would shift what the step reads as ``T``."""
+    tokens = np.asarray(tokens)
+    tables = np.asarray(tables)
+    B, T = tokens.shape
+    cols = [np.asarray(positions), np.asarray(mask, bool), np.asarray(idx),
+            np.asarray(sample["temperature"], np.float32).view(np.int32),
+            np.asarray(sample["top_p"], np.float32).view(np.int32),
+            np.asarray(sample["seed"], np.uint32).view(np.int32),
+            np.asarray(sample["ctr"])]
+    per_row = cols if slots is None else cols + [np.asarray(slots)]
+    if tables.shape != (B, blocks_per_seq) or \
+            any(c.shape != (B,) for c in per_row):
+        raise ValueError(
+            f"a step of {B} rows needs [{B}] per-row arrays and "
+            f"[{B}, {blocks_per_seq}] block tables; got "
+            f"{[c.shape for c in per_row]} and {tables.shape}")
+    buf = np.empty((B, T + len(per_row) + blocks_per_seq), np.int32)
+    buf[:, :T] = tokens
+    for i, c in enumerate(cols):
+        buf[:, T + i] = c
+    buf[:, T + ROW_WORDS:T + ROW_WORDS + blocks_per_seq] = tables
+    if slots is not None:
+        buf[:, -1] = slots
+    return buf
+
+
+def unpack_step(packed, blocks_per_seq: int, slots: bool):
+    """`pack_step`'s array, inside the jitted step -> ``(tokens [B, T]
+    int32, positions, mask bool, idx, temperature float32, top_p
+    float32, seed uint32, ctr, tables [B, blocks_per_seq], slots or
+    None)``: static slices and bitcasts, every value as the host held
+    it. ``T`` is what the row's width leaves."""
+    T = packed.shape[1] - ROW_WORDS - blocks_per_seq - int(slots)
+    col = [packed[:, T + i] for i in range(ROW_WORDS)]
+    bits = jax.lax.bitcast_convert_type
+    return (packed[:, :T], col[0], col[1] != 0, col[2],
+            bits(col[3], jnp.float32), bits(col[4], jnp.float32),
+            bits(col[5], jnp.uint32), col[6],
+            packed[:, T + ROW_WORDS:T + ROW_WORDS + blocks_per_seq],
+            packed[:, -1] if slots else None)
 
 
 class ShardedExecutor:
@@ -289,10 +358,16 @@ class ShardedExecutor:
                 [per_row] + [totals[n].astype(per_row.dtype)[None]
                              for n in sorted(totals)])
 
-        def fwd_token(params, cache, tokens, positions, mask, last_idx,
-                      temp, top_p, seed, ctr, tables, *slots):
-            logits, vout = apply_model(params, cache, tokens, positions,
-                                       mask, tables, last_idx, slots)
+        def unpack(packed):
+            return unpack_step(packed, self.blocks_per_seq,
+                               self.per_row_state)
+
+        def fwd_token(params, cache, packed):
+            (tokens, positions, mask, last_idx, temp, top_p, seed, ctr,
+             tables, slots) = unpack(packed)
+            logits, vout = apply_model(
+                params, cache, tokens, positions, mask, tables, last_idx,
+                () if slots is None else (slots,))
             tok, probs = sample_with_probs(
                 logits[:, 0], temp, top_p, seed, ctr, stream=stream)
             tok = with_stats(tok, vout)
@@ -300,8 +375,9 @@ class ShardedExecutor:
                 return tok, probs, vout["cache"]
             return tok, vout["cache"]
 
-        def fwd_verify(params, cache, tokens, positions, mask, temp,
-                       top_p, seed, ctr, dprobs, n_draft, tables):
+        def fwd_verify(params, cache, packed, dprobs):
+            (tokens, positions, mask, n_draft, temp, top_p, seed, ctr,
+             tables, _) = unpack(packed)
             logits, vout = apply_model(params, cache, tokens, positions,
                                        mask, tables, None)
             emitted, n_acc = speculative_accept(
@@ -522,29 +598,32 @@ class ShardedExecutor:
         # (docs/tracing.md): which of them the device waits for is what
         # the serve cell's idle metrics read
         with rec.span("exec_step", **attrs) as step_span:
-            with rec.span("exec_upload"):
-                s = sample if sample is not None \
-                    else self._default_sample(B)
-                args = [jnp.asarray(tokens, jnp.int32),
-                        jnp.asarray(positions, jnp.int32),
-                        jnp.asarray(mask, bool)]
+            with rec.span("exec_upload") as upload:
+                # ONE transfer: every host array of the step in one
+                # buffer, placed uncommitted as each of them used to be
+                # (a mesh-placed executor's program replicates it)
+                idx = last_idx
                 if kind == "verify":
-                    if draft_probs is None:
-                        draft_probs = jnp.zeros(
-                            (B, T - 1, self.vocab_size), jnp.float32)
-                    tail = [draft_probs, jnp.asarray(
-                        n_draft if n_draft is not None
-                        else np.zeros(B, np.int32), jnp.int32)]
-                else:
-                    args.append(jnp.asarray(last_idx, jnp.int32))
-                    tail = []
-                args += [jnp.asarray(s["temperature"], jnp.float32),
-                         jnp.asarray(s["top_p"], jnp.float32),
-                         jnp.asarray(s["seed"], jnp.uint32),
-                         jnp.asarray(s["ctr"], jnp.int32)] + tail
-                args.append(jnp.asarray(block_tables, jnp.int32))
-                if self.per_row_state and kind != "verify":
-                    args.append(jnp.asarray(state_slots, jnp.int32))
+                    idx = n_draft if n_draft is not None \
+                        else np.zeros(B, np.int32)
+                host = pack_step(
+                    tokens, positions, mask, idx,
+                    sample if sample is not None
+                    else self._default_sample(B), block_tables,
+                    self.blocks_per_seq,
+                    state_slots if self.per_row_state else None)
+                args = [jax.device_put(host)]
+                if kind == "verify":
+                    # the drafter's distribution is on the device
+                    # already and stays there
+                    args.append(
+                        draft_probs if draft_probs is not None
+                        else jnp.zeros((B, T - 1, self.vocab_size),
+                                       jnp.float32))
+                upload.set(
+                    transfers=1 + (draft_probs is not None and not
+                                   isinstance(draft_probs, jax.Array)),
+                    bytes=host.nbytes)
             with self._swap_lock:   # the weight-swap version fence
                 self.last_step_version = self.params_version
                 fwd = self._fwd_verify if kind == "verify" \
@@ -812,14 +891,15 @@ class ShardedExecutor:
         kernel is a ``tpu_custom_call`` on TPU). Lowering only: nothing
         is compiled, run or donated."""
         B = self.max_batch
-        zi = jnp.zeros((B,), jnp.int32)
-        s = self._default_sample(B)
-        args = [self.params, self.cache, jnp.zeros((B, 1), jnp.int32), zi,
-                jnp.zeros((B,), bool), zi,
-                jnp.asarray(s["temperature"]), jnp.asarray(s["top_p"]),
-                jnp.asarray(s["seed"]), jnp.asarray(s["ctr"]),
-                jnp.full((B, self.blocks_per_seq), -1, jnp.int32)]
-        return self._fwd_token.lower(*args).as_text()
+        zi = np.zeros(B, np.int32)
+        packed = pack_step(
+            np.zeros((B, 1), np.int32), zi, np.zeros(B, bool), zi,
+            self._default_sample(B),
+            np.full((B, self.blocks_per_seq), -1, np.int32),
+            self.blocks_per_seq,
+            np.arange(B) if self.per_row_state else None)
+        return self._fwd_token.lower(
+            self.params, self.cache, packed).as_text()
 
     def jit_cache_size(self) -> int:
         """Compiled-program count across the step functions — the churn

@@ -5,6 +5,7 @@ and what is not, that the numbers are the ones the float32 tree gives,
 state from one trace of the model and no program that holds its
 forward. Single process, CPU; Pallas in interpret mode."""
 import logging
+import time
 from types import SimpleNamespace
 
 import jax
@@ -15,6 +16,7 @@ import pytest
 from horovod_tpu.models.gpt import GPT, GPTConfig
 from horovod_tpu.models.routed_lm import RoutedLM, RoutedLMConfig
 from horovod_tpu.serve import ShardedExecutor
+from horovod_tpu.trace.spans import get_recorder
 
 _KW = dict(vocab_size=64, num_layers=2, num_heads=2, head_dim=8,
            max_seq_len=48, attention_impl="reference")
@@ -489,3 +491,247 @@ def test_constructor_logs_the_pools_layout_and_bytes(caplog):
     assert logical == 4 * 24 * 4 * 2 * 8 * 2        # 4 pools of bfloat16
     assert f"4 pools bfloat16[24, 4, 2, 8] held (0, 1, 2, 3), pool " \
         f"bytes {logical} -> {logical} on the device" in line, line
+
+
+# -- (g) a step's host inputs cross in ONE transfer ---------------------------
+# (`executor.pack_step` / `unpack_step`): what the model and the
+# samplers receive inside the jitted step is what the host held, bit for
+# bit; the buffer's shape carries rows and T, so it keys the program.
+
+_B = 4      # rows of the packed-step executors
+#: per-row sampling data with awkward bit patterns
+_SAMPLE = {"temperature": np.array([0.7, 1e-6, -0.0, 0.0], np.float32),
+           "top_p": np.array([0.9, 1.0, 1e-6, 0.3], np.float32),
+           "seed": np.array([2 ** 31, 2 ** 32 - 1, 0, 7], np.uint32),
+           "ctr": np.array([0, 5, 2 ** 31 - 1, 3], np.int32)}
+_MASK = np.array([False, True, True, False])
+
+
+def _executor_of(arch):
+    """An executor of `_B` rows: GPT, or the model with per-row state."""
+    if arch == "gpt":
+        model = GPT(GPTConfig(decode_kernel="xla", **_PAGED, **_KW))
+        return ShardedExecutor(model, _gpt_params(), max_batch=_B,
+                               max_len=_MAX_LEN)
+    from horovod_tpu.models.sala_lm import SalaLM, SalaLMConfig
+    model = SalaLM(SalaLMConfig(
+        vocab_size=64, max_seq_len=_MAX_LEN, kv_block_size=4,
+        kv_pool_blocks=48, state_rows=_B, dtype=jnp.float32,
+        param_dtype=jnp.float32, decode_kernel="xla"))
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((_B, 1), jnp.int32),
+        positions=jnp.zeros((_B,), jnp.int32),
+        update_mask=jnp.zeros((_B,), bool),
+        block_tables=jnp.full((_B, 12), -1, jnp.int32))["params"]
+    return ShardedExecutor(model, params, max_batch=_B, max_len=_MAX_LEN)
+
+
+class _SpyModel:
+    """Stands in for `ex.model` when a step is traced: hands each input
+    of `apply` to the host, then calls the model."""
+
+    def __init__(self, model, seen):
+        self.model, self.seen = model, seen
+
+    def apply(self, variables, tokens, **kw):
+        named = {"tokens": tokens, "positions": kw["positions"],
+                 "mask": kw["update_mask"], "tables": kw["block_tables"]}
+        for opt in ("logits_idx", "state_slots"):
+            if kw.get(opt) is not None:
+                named[opt] = kw[opt]
+        jax.debug.callback(lambda **v: self.seen.update(v), **named)
+        return self.model.apply(variables, tokens, **kw)
+
+
+def _spied_executor(monkeypatch, arch, seen):
+    """An executor of `_B` rows whose traced steps report what reaches
+    `model.apply` and the two samplers."""
+    from horovod_tpu.ops import pallas_paged
+    sample, accept = (pallas_paged.sample_with_probs,
+                      pallas_paged.speculative_accept)
+
+    def spy_sample(logits, temp, top_p, seed, ctr, **kw):
+        jax.debug.callback(lambda **v: seen.update(v), temperature=temp,
+                           top_p=top_p, seed=seed, ctr=ctr)
+        return sample(logits, temp, top_p, seed, ctr, **kw)
+
+    def spy_accept(tokens, dprobs, logits, n_draft, temp, top_p, seed, ctr):
+        jax.debug.callback(lambda **v: seen.update(v), draft_tokens=tokens,
+                           n_draft=n_draft, temperature=temp, top_p=top_p,
+                           seed=seed, ctr=ctr)
+        return accept(tokens, dprobs, logits, n_draft, temp, top_p, seed,
+                      ctr)
+
+    monkeypatch.setattr(pallas_paged, "sample_with_probs", spy_sample)
+    monkeypatch.setattr(pallas_paged, "speculative_accept", spy_accept)
+    ex = _executor_of(arch)
+    ex.model = _SpyModel(ex.model, seen)
+    return ex
+
+
+def _same_bits(got, want, dtype):
+    got = np.asarray(got)
+    assert got.dtype == dtype and got.shape == np.shape(want)
+    want = np.asarray(want, dtype)
+    if dtype != bool:       # float fields by their bits: -0.0 is not 0.0
+        got, want = (x.view("u%d" % x.itemsize) for x in (got, want))
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("kind", ["decode", "row_compact_prefill", "verify"])
+def test_the_step_receives_what_the_host_held(kind, monkeypatch):
+    seen = {}
+    ex = _spied_executor(
+        monkeypatch, "sala" if kind == "row_compact_prefill" else "gpt",
+        seen)
+    n = ex.blocks_per_seq
+    tables = np.arange(_B * n, dtype=np.int32).reshape(_B, n)
+    tables[:, 5:] = -1
+    tables[0] = -1
+    positions = np.array([0, 7, 3, 0], np.int32)
+    rng = np.random.RandomState(0)
+    if kind == "decode":
+        want = dict(tokens=rng.randint(0, 64, (_B, 1)), positions=positions,
+                    mask=_MASK, logits_idx=np.zeros(_B, np.int32),
+                    tables=tables, **_SAMPLE)
+        ex.step(want["tokens"], positions, _MASK, want["logits_idx"],
+                kind="decode", block_tables=tables, sample=_SAMPLE)
+    elif kind == "row_compact_prefill":
+        rows = [1, 2]           # two rows of the batch's four, out of order
+        sample = {k: v[rows] for k, v in _SAMPLE.items()}
+        want = dict(tokens=rng.randint(0, 64, (2, _BUCKET)),
+                    positions=np.zeros(2, np.int32), mask=_MASK[rows],
+                    logits_idx=np.array([4, 6], np.int32),
+                    tables=tables[rows], state_slots=np.array([3, 1]),
+                    **sample)
+        ex.step(want["tokens"], want["positions"], want["mask"],
+                want["logits_idx"], kind="prefill", sample=sample,
+                block_tables=want["tables"],
+                state_slots=want["state_slots"])
+    else:
+        tokens = rng.randint(0, 64, (_B, 3))
+        n_draft = np.array([2, 0, 1, 2], np.int32)
+        want = dict(tokens=tokens, draft_tokens=tokens, positions=positions,
+                    mask=_MASK, tables=tables, n_draft=n_draft, **_SAMPLE)
+        ex.step(tokens, positions, _MASK, None, kind="verify",
+                block_tables=tables, sample=_SAMPLE, n_draft=n_draft,
+                draft_probs=jnp.full((_B, 2, 64), 1 / 64, jnp.float32))
+    jax.effects_barrier()
+    assert sorted(seen) == sorted(want)
+    dtypes = {"mask": bool, "temperature": np.float32,
+              "top_p": np.float32, "seed": np.uint32}
+    for name, value in want.items():
+        _same_bits(seen[name], value, dtypes.get(name, np.int32))
+
+
+def _nine_argument_step(ex):
+    """The step as it was before the packed buffer, kept here as the
+    reference: nine device arrays in, the sampled token out."""
+    from horovod_tpu.ops.pallas_paged import sample_with_probs
+
+    @jax.jit
+    def fwd(params, cache, tokens, positions, mask, last_idx, temp, top_p,
+            seed, ctr, tables):
+        logits, vout = ex.model.apply(
+            {"params": params, "cache": cache}, tokens,
+            positions=positions, update_mask=mask, logits_idx=last_idx,
+            mutable=["cache", "stats"], block_tables=tables)
+        tok, _ = sample_with_probs(logits[:, 0], temp, top_p, seed, ctr)
+        return tok, vout["cache"]
+    return fwd
+
+
+def test_sampled_token_streams_are_the_nine_argument_steps():
+    ex = _gpt_executor(_gpt_params())
+    ref, cache = _nine_argument_step(ex), jax.tree_util.tree_map(
+        jnp.zeros_like, ex.cache)
+    sample = {"temperature": np.array([0.8, 1.3], np.float32),
+              "top_p": np.array([0.95, 0.6], np.float32),
+              "seed": np.array([2 ** 31 + 11, 2 ** 32 - 1], np.uint32)}
+    tokens, lengths = _prompt_batch()
+    positions, last = np.zeros(_ROWS, np.int32), lengths - 1
+    mask, tables, kind = np.ones(_ROWS, bool), _tables(ex), "prefill"
+    streams = []
+    for i in range(1 + 2 * _DECODES):
+        s = dict(sample, ctr=np.full(_ROWS, i, np.int32))
+        got = ex.step(tokens, positions, mask, last, kind=kind,
+                      block_tables=tables, sample=s)
+        want, cache = ref(
+            ex.params, cache, jnp.asarray(tokens, jnp.int32),
+            jnp.asarray(positions, jnp.int32), jnp.asarray(mask, bool),
+            jnp.asarray(last, jnp.int32),
+            jnp.asarray(s["temperature"], jnp.float32),
+            jnp.asarray(s["top_p"], jnp.float32),
+            jnp.asarray(s["seed"], jnp.uint32),
+            jnp.asarray(s["ctr"], jnp.int32),
+            jnp.asarray(tables, jnp.int32))
+        np.testing.assert_array_equal(got, np.asarray(want))
+        streams.append(got)
+        tokens, positions, last, kind = (
+            got[:, None].astype(np.int32), lengths + i,
+            np.zeros(_ROWS, np.int32), "decode")
+    # sampled, not greedy: the rows' streams are no argmax run
+    assert len({tuple(np.stack(streams)[:, r]) for r in range(_ROWS)}) == 2
+
+
+def _upload_spans(since):
+    return [s for s in get_recorder().between(since, float("inf"))
+            if s.name == "exec_upload"]
+
+
+@pytest.mark.parametrize("arch", ["gpt", "sala"])
+def test_a_decode_step_makes_one_transfer(arch, monkeypatch):
+    ex = _executor_of(arch)
+    n = ex.blocks_per_seq
+    tables = np.arange(_B * n, dtype=np.int32).reshape(_B, n)
+    calls, put = [], jax.device_put
+    monkeypatch.setattr(jax, "device_put",
+                        lambda x, *a, **kw: calls.append(x) or put(x, *a, **kw))
+    t0 = time.monotonic()
+    with jax.transfer_guard_host_to_device("disallow"):
+        ex.step(np.zeros((_B, 1), np.int32), np.zeros(_B, np.int32),
+                _MASK, np.zeros(_B, np.int32), kind="decode",
+                block_tables=tables, sample=_SAMPLE)
+    (span,) = _upload_spans(t0)
+    words = _B * 1 + 7 * _B + _B * n + (_B if arch == "sala" else 0)
+    assert span.extra == {"transfers": 1, "bytes": 4 * words}
+    assert len(calls) == 1 and calls[0].nbytes == 4 * words
+
+
+def test_the_packed_shape_keys_the_program():
+    """A ``[1, 35]`` and a ``[2, 8]`` prefill pack into the same number
+    of words (12 table entries and 7 more a row) and are two programs;
+    a churn of values through one shape is one."""
+    ex = _gpt_executor(_gpt_params())
+    assert ex.blocks_per_seq == 12
+    t0 = time.monotonic()
+    for rows, T in ((1, 35), (2, 8)):
+        ex.step(np.zeros((rows, T), np.int32), np.zeros(rows, np.int32),
+                np.ones(rows, bool), np.full(rows, 4, np.int32),
+                kind="prefill", block_tables=_tables(ex)[:rows])
+    assert [s.extra["bytes"] for s in _upload_spans(t0)] == [4 * 54] * 2
+    assert ex.jit_cache_size() == 2
+    rng = np.random.RandomState(1)
+    for i in range(6):          # admissions come and go: values, not shapes
+        ex.step(rng.randint(0, 64, (_ROWS, 1)), rng.randint(0, 40, _ROWS),
+                rng.rand(_ROWS) < 0.5, np.zeros(_ROWS, np.int32),
+                kind="decode", block_tables=rng.randint(-1, 24, (_ROWS, 12)),
+                sample={"temperature": rng.rand(_ROWS).astype(np.float32),
+                        "top_p": np.ones(_ROWS, np.float32),
+                        "seed": rng.randint(0, 2 ** 32, _ROWS, np.uint32),
+                        "ctr": np.full(_ROWS, i, np.int32)})
+    assert ex.jit_cache_size() == 3
+
+
+def test_an_array_of_another_shape_is_refused():
+    ex = _gpt_executor(_gpt_params())
+    step = dict(tokens=np.zeros((_ROWS, 1), np.int32),
+                positions=np.zeros(_ROWS, np.int32),
+                mask=np.ones(_ROWS, bool),
+                last_idx=np.zeros(_ROWS, np.int32), kind="decode",
+                block_tables=_tables(ex))
+    for wrong in (dict(positions=np.zeros(1, np.int32)),
+                  # one entry short: T would be read one token longer
+                  dict(block_tables=_tables(ex)[:, :-1])):
+        with pytest.raises(ValueError, match=r"a step of 2 rows"):
+            ex.step(**dict(step, **wrong))

@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from horovod_tpu.ops import pallas_paged
 from horovod_tpu.ops.pallas_attention import flash_attention
 from horovod_tpu.ops.pallas_ce import fused_softmax_cross_entropy
+from horovod_tpu.serve.executor import ROW_WORDS
 from horovod_tpu.serve.kv_cache import pool_blocks_for
 
 #: (heads, kv_heads, head_dim): GPT-2-small, then the GQA shape
@@ -314,13 +315,11 @@ def _compile_step(ex, tokens):
     def on_chip(tree):
         return jax.tree_util.tree_map(
             lambda x: _sds(x.shape, x.dtype, sh), tree)
-    i32 = functools.partial(_sds, dtype=jnp.int32, sharding=sh)
-    f32 = _sds((rows,), jnp.float32, sh)
+    # the step's one packed input (`executor.pack_step`)
     return _aot_compile(ex._fwd_token, [
-        on_chip(ex.params), on_chip(ex.cache), i32((rows, tokens)),
-        i32((rows,)), _sds((rows,), bool, sh), i32((rows,)), f32, f32,
-        _sds((rows,), jnp.uint32, sh), i32((rows,)),
-        i32((rows, ex.blocks_per_seq))])
+        on_chip(ex.params), on_chip(ex.cache),
+        _sds((rows, tokens + ROW_WORDS + ex.blocks_per_seq), jnp.int32,
+             sh)])
 
 
 def _entry_layout(compiled) -> str:
@@ -486,13 +485,11 @@ def test_hybrid_decode_step_aliases_the_state_and_the_pools(sala_executor):
     def on_chip(tree):
         return jax.tree_util.tree_map(
             lambda x: _sds(x.shape, x.dtype, sh), tree)
-    i32 = functools.partial(_sds, dtype=jnp.int32, sharding=sh)
-    f32 = _sds((rows,), jnp.float32, sh)
+    # the step's one packed input, a state slot at each row's end
     compiled = _aot_compile(ex._fwd_token, [
-        on_chip(ex.params), on_chip(ex.cache), i32((rows, 1)),
-        i32((rows,)), _sds((rows,), bool, sh), i32((rows,)), f32, f32,
-        _sds((rows,), jnp.uint32, sh), i32((rows,)),
-        i32((rows, ex.blocks_per_seq)), i32((rows,))])
+        on_chip(ex.params), on_chip(ex.cache),
+        _sds((rows, 1 + ROW_WORDS + ex.blocks_per_seq + 1), jnp.int32,
+             sh)])
     text = compiled.as_text()
     for kernel in ("lightning_decode", "_paged_attention_call"):
         assert re.search(r"%" + kernel + r"[.\d]* = .*custom-call", text), \
