@@ -179,11 +179,11 @@ def _prefill_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, **kw):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "window", "block_q", "block_k", "interpret"))
+    "window", "block_q", "block_k", "out_dtype", "interpret"))
 def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
                   q_start: jax.Array, *, window: Optional[int] = None,
                   block_q: int = 512, block_k: int = 512,
-                  interpret: bool = False) -> jax.Array:
+                  out_dtype=None, interpret: bool = False) -> jax.Array:
     """Causal attention of a serving prefill: row ``b``'s ``Sq`` queries
     ``[B, H, Sq, D]`` sit at key positions ``q_start[b] + i`` of its
     ``[B, H_kv, Skv, D]`` keys (a cached prefix first, then the fresh
@@ -191,7 +191,9 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
     ``window``, only the newest ``window`` of them; key blocks wholly
     behind the window or past the diagonal are skipped. Forward only,
     operands to the MXU in the inputs' dtype with float32 accumulation
-    and softmax. Keys past a row's real length must be finite (they are
+    and softmax; the output in ``out_dtype`` (the queries' where none is
+    named: differential attention subtracts two outputs and wants them
+    unrounded). Keys past a row's real length must be finite (they are
     masked, not skipped)."""
     from jax.experimental.pallas import tpu as pltpu
     qq, kk, vv, scale, block_q, block_k, Sq, Skv, pad_q = _prepare(
@@ -226,7 +228,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
             ],
             out_specs=pl.BlockSpec((1, 1, block_q, D),
                                    lambda b, h, qi, st: (b, h, qi, 0))),
-        out_shape=_sds(q, (B, H, Sq_p, D), q.dtype),
+        out_shape=_sds(q, (B, H, Sq_p, D), out_dtype or q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=max(16 << 20, need + need // 4)),

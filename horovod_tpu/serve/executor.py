@@ -245,6 +245,13 @@ class ShardedExecutor:
             cfg.state_rows = max_batch
         #: layers whose decode step attends to blocks it selects
         self._select_layers = int(getattr(cfg, "block_select_layers", 0))
+        #: bytes of cached VALUES a token of context and a row cost, for
+        #: a model that says so (one whose cache does not grow by every
+        #: attending layer's K and V a token: models/sambay_lm.py); its
+        #: decode steps carry what their live rows hold
+        self._cache_cost = (
+            (int(cfg.cache_token_bytes), int(cfg.cache_row_bytes))
+            if hasattr(cfg, "cache_token_bytes") else None)
         if self.kv_pool_blocks < self.blocks_per_seq:
             raise ValueError(
                 f"kv_pool_blocks {self.kv_pool_blocks} cannot cover one "
@@ -659,6 +666,17 @@ class ShardedExecutor:
                     * np.sum(live // self.kv_block_size + 1)))
             if kind == "decode" and self.per_row_state:
                 step_span.set(state_rows=n_tok)
+            if kind == "decode" and self._cache_cost:
+                # known without a readback: the contexts the live rows
+                # have after this step, the pool blocks their tables
+                # name, and what a row holds whatever its context
+                on = np.asarray(mask, bool)
+                per_token, per_row = self._cache_cost
+                held = int(np.sum(np.asarray(block_tables)[on] >= 0))
+                step_span.set(
+                    context_tokens=int(np.sum(np.asarray(positions)[on] + 1)),
+                    cache_bytes_held=held * self.kv_block_size * per_token
+                    + n_tok * per_row)
         dt_ms = (time.perf_counter() - t0) * 1000.0
         self.steps += 1
         self._m_step_ms.get(kind, self._m_step_ms["decode"]).observe(dt_ms)

@@ -508,3 +508,116 @@ def test_hybrid_decode_step_aliases_the_state_and_the_pools(sala_executor):
     call = next(line for line in text.splitlines()
                 if re.search(r"%_paged_attention_call[.\d]* = ", line))
     assert "s32[32,128]" in call and "s32[32,576]" not in call
+
+
+# One period of each half of the SambaY stack at the published widths
+# (2,560 wide, 40 query and 20 KV heads of 64, a feed-forward of 10,240,
+# Mamba states of 16 x 5,120 and 4 x 5,120, a window of 512; 16 rows, the
+# cell's pool of 1,024 blocks of 64 and tables of 128 entries): 8 layers
+# hold three Mamba and two window layers, the full layer, a memory unit
+# and a cross layer; the vocabulary cut to keep the bookkeeping light.
+
+@pytest.fixture
+def sambay_executor(v5e, monkeypatch):
+    from chipbench import manifest as mf
+    from horovod_tpu.ops import selective_scan
+    from horovod_tpu.serve import ShardedExecutor
+    family = mf.load_module("chipbench/families/sambay.py")
+    config = mf.load_json("chipbench/configs/phi4-mini-flash.json")
+    config.update(num_hidden_layers=8, vocab_size=512)
+    shape = family.Shape(config)
+    rows, max_len = 16, 8192
+    model = family.serve_model(
+        shape, config, kv_block=64,
+        kv_pool_blocks=pool_blocks_for(rows, max_len, 64),
+        decode_kernel="pallas")
+    params = jax.eval_shape(lambda k: family.program_params(shape, k),
+                            family.seed_key(0))
+    # the model asks the default backend (the CPU, here) whether to
+    # interpret its kernels: steer it from the test
+    monkeypatch.setattr(
+        pallas_paged, "paged_attention_fused", functools.partial(
+            pallas_paged.paged_attention_fused, interpret=False))
+    decode = selective_scan.ssm_decode
+    monkeypatch.setattr(
+        selective_scan, "ssm_decode",
+        lambda *a, interpret: decode(*a, interpret=False))
+    ex = ShardedExecutor(model, params, max_batch=rows, max_len=max_len)
+    ex._fwd_token = jax.jit(ex._fwd_token.__wrapped__, donate_argnums=(1,))
+    ex.chip = SingleDeviceSharding(v5e[0])
+    return ex
+
+
+def test_sambay_decode_step_aliases_states_rings_and_the_one_pool(
+        sambay_executor):
+    """The decode step compiles for the chip with both named kernels in
+    it, the donated cache aliased whole and no copy of a conv state, an
+    SSM state, a ring or the pool; the cross layer reads the full layer's
+    pool (one pool pair in the cache, four paged calls: two rings, the
+    pool twice); and the 128-entry call asks for the VMEM the sizes
+    give."""
+    ex = sambay_executor
+    sh, rows = ex.chip, ex.max_batch
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _sds(x.shape, x.dtype, sh), tree)
+    compiled = _aot_compile(ex._fwd_token, [
+        on_chip(ex.params), on_chip(ex.cache),
+        _sds((rows, 1 + ROW_WORDS + ex.blocks_per_seq + 1), jnp.int32,
+             sh)])
+    text = compiled.as_text()
+    for kernel in ("ssm_decode", "_paged_attention_call"):
+        assert re.search(r"%" + kernel + r"[.\d]* = .*custom-call", text), \
+            kernel
+    leaves = jax.tree_util.tree_leaves(ex.cache)
+    assert sorted(ex._leaf_kinds) == ["kv"] * 2 + ["row"] * 10
+    assert sorted({(str(x.dtype), x.shape) for x in leaves}) == [
+        ("bfloat16", (16, 8, 64, 16, 128)),        # a ring: 512 tokens
+        ("bfloat16", (1024, 64, 16, 128)),         # the pool, pair-heads
+        ("float32", (16, 4, 5120)), ("float32", (16, 16, 5120))]
+    for leaf in leaves:
+        shape = "%s[%s]" % ({"bfloat16": "bf16", "float32": "f32"}[
+            str(leaf.dtype)], ",".join(map(str, leaf.shape)))
+        assert not re.search(
+            r" = " + re.escape(shape) + r"\{[^ ]* copy\(", text), shape
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        x.nbytes for x in leaves)
+    calls = [line for line in text.splitlines()
+             if re.search(r"%_paged_attention_call[.\d]* = ", line)]
+    assert sorted("s32[16,128]" in c for c in calls) == [False, False,
+                                                         True, True]
+    assert all("s32[16,8]" in c for c in calls if "s32[16,128]" not in c)
+    # 10 pair-heads' whole table assembled in VMEM, as the issue reckons:
+    # 42 MB of K and V, 66 MB asked for
+    assert pallas_paged._vmem_limit_bytes(4, 10, 128, 64, 128, 2) \
+        == 66_396_160
+
+
+def test_sambay_prefill_step_compiles_with_its_three_kernels(
+        sambay_executor, monkeypatch):
+    """The longest bucket, one row: the scan kernel, the flash forward
+    of the window layers and the last token's paged reads."""
+    from horovod_tpu.ops import pallas_attention, selective_scan
+    prefill, flash = selective_scan.ssm_prefill, \
+        pallas_attention.flash_prefill
+    monkeypatch.setattr(
+        selective_scan, "ssm_prefill",
+        lambda *a, interpret: prefill(*a, interpret=False))
+    monkeypatch.setattr(
+        pallas_attention, "flash_prefill",
+        lambda *a, interpret, **kw: flash(*a, interpret=False, **kw))
+    ex = sambay_executor
+    sh = ex.chip
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _sds(x.shape, x.dtype, sh), tree)
+    compiled = _aot_compile(ex._fwd_token, [
+        on_chip(ex.params), on_chip(ex.cache),
+        _sds((1, 4928 + ROW_WORDS + ex.blocks_per_seq + 1), jnp.int32, sh)])
+    text = compiled.as_text()
+    for kernel in ("ssm_prefill", "flash_prefill", "_paged_attention_call"):
+        assert re.search(r"%" + kernel + r"[.\d]* = .*custom-call", text), \
+            kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
