@@ -15,6 +15,8 @@ Public API mirrors `import horovod.torch as hvd`:
     h = hvd.allreduce_async(stacked_grads)    # async (fused by the engine)
     out = hvd.synchronize(h)
 """
+import time as _time
+_T_IMPORT = _time.monotonic()       # where the `hvd_import` span starts
 
 # The runtime lock-order witness must arm BEFORE any horovod_tpu
 # module creates a lock, so it comes first (no-op unless
@@ -81,3 +83,7 @@ from . import redist                                           # noqa: F401
 from .redist import redistribute                               # noqa: F401
 
 __version__ = "0.2.0"
+
+# the import itself, into the process's span ring (docs/tracing.md)
+from .trace.spans import get_recorder as _get_recorder          # noqa: E402
+_get_recorder().record_local("hvd_import", _T_IMPORT, _time.monotonic())

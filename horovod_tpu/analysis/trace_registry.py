@@ -2,7 +2,8 @@
 
 The tracing plane's equivalent of the knob and metric registries: the
 span names processes record (``SpanRecorder.record_local`` /
-``record_process`` / ``span``, ``TraceAssembler.span``) and the
+``record_process`` / ``record_reentrant`` / ``span``,
+``TraceAssembler.span``) and the
 leg labels the
 router's ``hvd_trace_leg_ms{leg,pool}`` histograms carry are declared
 ONCE, in ``trace/spans.py``'s :data:`~horovod_tpu.trace.spans.
@@ -49,10 +50,10 @@ _DOCS = "docs/tracing.md"
 
 #: recording-call shapes: dotted-name suffix -> the positions the
 #: span-name argument may take. ``TraceAssembler.span`` takes
-#: (ctx, name, ...); ``record_process``, ``record_local`` and
-#: ``SpanRecorder.span`` take (name, ...).
+#: (ctx, name, ...); ``record_process``, ``record_local``,
+#: ``record_reentrant`` and ``SpanRecorder.span`` take (name, ...).
 _RECORD_CALLS = {"span": (0, 1), "record_process": (0,),
-                 "record_local": (0,)}
+                 "record_local": (0,), "record_reentrant": (0,)}
 
 
 def _declared(sf: SourceFile) -> Tuple[Dict[str, Optional[str]],

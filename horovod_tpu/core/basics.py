@@ -27,6 +27,8 @@ from typing import List, Optional, Sequence
 
 import jax
 
+from ..trace import stalls
+from ..trace.spans import get_recorder
 from .config import Config
 from .mesh import build_global_mesh, build_hierarchical_mesh, global_devices
 from .process_sets import ProcessSet, ProcessSetTable, global_process_set
@@ -175,9 +177,12 @@ def init(comm: Optional[Sequence[int]] = None,
     subset (the reference accepts an mpi4py comm or rank list). `process_sets`
     pre-registers subgroup sets, like hvd.init(process_sets=[...]).
     """
+    rec = get_recorder()
+    t0 = rec.now()
     with _state.lock:
         if _state.initialized:
             return
+        stalls.install()
         cfg = Config.from_env()
         _state.config = cfg
         _maybe_init_distributed(cfg)
@@ -246,6 +251,7 @@ def init(comm: Optional[Sequence[int]] = None,
 
     logger.debug("horovod_tpu initialized: %d devices, platform=%s",
                  len(_state.devices), _state.devices[0].platform)
+    rec.record_local("hvd_init", t0, rec.now())
 
 
 def _configure_logging(cfg: Config) -> None:

@@ -657,18 +657,6 @@ class ShardedExecutor:
                     nxt, sown = nxt[:-n], nxt[-n:]
                 step_span.set(**{name: int(v) for name, v in
                                  zip(self._stat_names, sown)})
-            if kind == "decode":
-                # how far the paged kernel's walk goes (host arithmetic
-                # on the step's positions, no readback): each live row's
-                # entries up to its newest key, against the whole table
-                # an assembly of the row would cover
-                on = np.asarray(mask, bool)
-                reach = -(-(np.asarray(positions)[on] + T)
-                          // self.kv_block_size)
-                step_span.set(
-                    walk_entries=int(np.sum(np.minimum(
-                        reach, self.blocks_per_seq))),
-                    table_entries=n_tok * self.blocks_per_seq)
             if kind == "decode" and self._select_layers:
                 # what the selecting layers COULD have read: each live
                 # row's cached blocks, the one being written included

@@ -12,6 +12,9 @@ decode worker under failovers and autoscaling (docs/tracing.md):
     spans.py     THE span/leg registry (machine-checked against
                  docs/tracing.md by tools/check.py --pass
                  trace-registry) + the bounded per-process SpanRecorder
+    stalls.py    host stalls into the local ring: jax's trace / lower /
+                 compile-or-fetch events and long GC pauses, under the
+                 span open on the thread that paid for them
     clock.py     per-worker clock offsets from heartbeat round trips
                  (minimum-delay filter; no clock protocol)
     collect.py   router-side TraceAssembler: leg attribution into

@@ -43,7 +43,8 @@ from collections import OrderedDict, deque
 from typing import Dict, List, Optional
 
 __all__ = ["SPAN_LEGS", "SPAN_NAMES", "LEGS", "Span", "SpanRecorder",
-           "get_recorder", "configure_recorder", "wall_base", "to_wall"]
+           "get_recorder", "configure_recorder", "wall_base", "to_wall",
+           "wall_span_ended_now"]
 
 
 def wall_base() -> float:
@@ -58,6 +59,16 @@ def to_wall(t_mono: float) -> float:
     """A monotonic stamp on the wall clock (for a caller that hands a
     stamp to a wall-clock API, e.g. ``TraceAssembler.span``)."""
     return t_mono + wall_base()
+
+
+def wall_span_ended_now(t0_wall: float, t1_wall: float) -> tuple:
+    """``(t0, t1)`` on the monotonic clock of a span stamped on the wall
+    clock that has just ended (jax's compile events reach a listener as
+    they end): it ends now and lasts what the wall clock says. No base
+    is sampled, so a thread switched out between two clock reads cannot
+    shift it out of the span it lies in."""
+    t1 = time.monotonic()
+    return t1 - (t1_wall - t0_wall), t1
 
 #: span name -> the latency leg it attributes to (None = overhead /
 #: bookkeeping spans that are merged into the timeline but excluded
@@ -87,6 +98,13 @@ SPAN_LEGS: "OrderedDict[str, Optional[str]]" = OrderedDict([
     ("exec_upload",     None),        # host -> device input arrays
     ("exec_dispatch",   None),        # the jitted call returning
     ("exec_readback",   None),        # device -> host result (the fence)
+    # -- local, never shipped: host work that stalls whatever is open ------
+    ("jit_trace",       None),        # jax traced a function to a jaxpr
+    ("jit_lower",       None),        # jaxpr -> MLIR module
+    ("jit_compile",     None),        # XLA compile or persistent-cache fetch
+    ("gc_pause",        None),        # a gen-2 or >= 1 ms collection
+    ("hvd_import",      None),        # import horovod_tpu
+    ("hvd_init",        None),        # hvd.init()
 ])
 
 #: every declared span name, in declaration order
@@ -98,9 +116,9 @@ LEGS = ("queue", "prefill", "migrate", "decode")
 
 #: the local ring holds this many times ``HOROVOD_TRACE_RING`` spans
 #: (the knob bounds what a worker holds for a router that never
-#: collects; it scales both): 32,768 at the default, three 45 s windows
-#: of the benchmark's serve cell (about 10,000 spans each, PERF.md
-#: section 6), 10 MB when full
+#: collects; it scales both): 32,768 at the default, 10 MB when full; a
+#: traced run of the benchmark's serve cells holds 10,000-24,000 spans
+#: when its readers run (PERF.md section 6)
 RING_FACTOR = 8
 
 #: attributes that stay in the process: `to_wire` leaves them out, so a
@@ -201,20 +219,22 @@ class _OpenSpan:
         stack = rec._stack()
         self.parent = stack[-1].id if stack else None
         self.id = rec._next_id()
-        stack.append(self)
         ann = _annotation()
         if ann is not None:
             self._ann = ann("hvd/" + self.name)
             self._ann.__enter__()
         self.t0 = time.monotonic()
+        # on the stack only inside [t0, t1]: whatever is recorded under
+        # it meanwhile (a collection, a compile) lies inside it
+        stack.append(self)
         return self
 
     def __exit__(self, *exc) -> None:
+        rec = self._rec
+        rec._stack().pop()
         t1 = time.monotonic()
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        rec = self._rec
-        rec._stack().pop()
         rec._keep(Span("", self.id, self.parent, self.name, self.t0, t1,
                        pool=rec.pool, replica=rec.replica, gen=rec.gen,
                        extra=self.attrs))
@@ -229,7 +249,8 @@ class SpanRecorder:
     in :meth:`drain`.
 
     **The ring** holds the last ``ring`` spans this process recorded
-    (:meth:`span`, :meth:`record_local`, :meth:`record_process`),
+    (:meth:`span`, :meth:`record_local`, :meth:`record_process`,
+    :meth:`record_reentrant`),
     oldest evicted first (:attr:`evicted` counts them). It is read,
     never drained: :meth:`between`, :meth:`oldest`. ``ring`` is
     :data:`RING_FACTOR` times ``capacity`` unless given.
@@ -263,6 +284,9 @@ class SpanRecorder:
         self._lock = threading.Lock()
         self._by_trace: "OrderedDict[str, List[Span]]" = OrderedDict()
         self._ring: "deque[Span]" = deque()
+        #: spans recorded where this recorder's lock may be held by the
+        #: same thread (`record_reentrant`), until a holder takes them in
+        self._parked: "deque[Span]" = deque(maxlen=self.ring)
         #: process-level spans waiting for the next drain to ship them
         self._unshipped: "deque[Span]" = deque(maxlen=64)
         self._ids = itertools.count(1)
@@ -297,12 +321,27 @@ class SpanRecorder:
     def _next_id(self) -> str:
         return format(next(self._ids), "x")
 
+    def current(self) -> Optional[str]:
+        """Id of the innermost span open on this thread, None if none."""
+        stack = self._stack()
+        return stack[-1].id if stack else None
+
+    def _push(self, sp: Span) -> None:
+        """(lock held) one span into the ring, the oldest out if full."""
+        if len(self._ring) >= self.ring:
+            self._ring.popleft()
+            self.evicted += 1
+        self._ring.append(sp)
+
+    def _settle(self) -> None:
+        """(lock held) parked spans join the ring."""
+        while self._parked:
+            self._push(self._parked.popleft())
+
     def _keep(self, sp: Span) -> None:
         with self._lock:
-            if len(self._ring) >= self.ring:
-                self._ring.popleft()
-                self.evicted += 1
-            self._ring.append(sp)
+            self._settle()
+            self._push(sp)
 
     def span(self, name: str, **attrs) -> _OpenSpan:
         """``with rec.span("sched_admit", depth=3) as sp:`` — a span
@@ -340,10 +379,29 @@ class SpanRecorder:
             self._file(sp)
         return sp
 
+    def record_reentrant(self, name: str, t0: float, t1: float, *,
+                         parent: Optional[str] = None, **attrs) -> None:
+        """:meth:`record_local` for a caller that may have interrupted
+        this recorder inside its own lock: a ``gc.callbacks`` hook runs
+        wherever a collection starts. Where the lock is taken the span
+        waits, lock-free, for the next call that holds it."""
+        sp = Span("", self._next_id(), parent, name, t0, t1,
+                  pool=self.pool, replica=self.replica, gen=self.gen,
+                  extra=attrs or None)
+        if self._lock.acquire(blocking=False):
+            try:
+                self._settle()
+                self._push(sp)
+            finally:
+                self._lock.release()
+        else:
+            self._parked.append(sp)
+
     def between(self, lo: float, hi: float) -> List[Span]:
         """The ring's spans that overlap ``[lo, hi]`` (monotonic
         seconds), in the order they ended. Drains nothing."""
         with self._lock:
+            self._settle()
             return [s for s in self._ring if s.t1 >= lo and s.t0 <= hi]
 
     def oldest(self) -> Optional[float]:
@@ -352,6 +410,7 @@ class SpanRecorder:
         :attr:`evicted` > 0 a range that starts before it has lost
         spans; with nothing evicted it has not."""
         with self._lock:
+            self._settle()
             return self._ring[0].t1 if self._ring else None
 
     # -- by trace: what the wire ships --------------------------------------
@@ -402,7 +461,8 @@ _recorder_lock = threading.Lock()
 
 def get_recorder() -> SpanRecorder:
     """The process-global recorder (lazily created with the configured
-    ring capacity — ``HOROVOD_TRACE_RING``)."""
+    ring capacity — ``HOROVOD_TRACE_RING``). Creating it installs the
+    host-stall hooks (trace/stalls.py)."""
     global _recorder
     if _recorder is None:
         with _recorder_lock:
@@ -414,6 +474,8 @@ def get_recorder() -> SpanRecorder:
                 except Exception:  # noqa: BLE001 — a malformed env
                     pass           # must not break the recording path
                 _recorder = SpanRecorder(cap)
+                from . import stalls
+                stalls.install()
     return _recorder
 
 

@@ -973,21 +973,6 @@ class TestLocalSpans:
         iterations = [s for s in r.spans if s.name == "sched_iteration"]
         assert len(iterations) == r.b.iterations
 
-    def test_decode_steps_say_how_far_the_walk_went(self, r):
-        """Every decode `exec_step` carries `walk_entries` (each live
-        row's table entries up to its newest key) and `table_entries`
-        (live rows x the table's width); no other kind of step does."""
-        steps = [s.extra for s in r.spans if s.name == "exec_step"]
-        # the target's tables, or a drafter's
-        widths = {ex.blocks_per_seq for ex in (r.ex, r.draft) if ex}
-        decode = [e for e in steps if e["kind"] == "decode"]
-        assert any(e["rows"] for e in decode)
-        for e in decode:
-            assert e["table_entries"] in {e["rows"] * w for w in widths}
-            assert e["rows"] <= e["walk_entries"] <= e["table_entries"]
-        assert not any("walk_entries" in e or "table_entries" in e
-                       for e in steps if e["kind"] != "decode")
-
     def test_children_lie_inside_their_parents(self, r):
         by_id = {s.span: s for s in r.spans}
         children = [s for s in r.spans if s.parent in by_id]
@@ -1042,27 +1027,3 @@ class TestLocalSpans:
                 if s.name == "request"]
         assert len(root) == 1 and root[0].extra["status"] == "expired"
 
-
-def test_walk_counters_are_hand_arithmetic():
-    """A decode step of four rows, one out of it, blocks of 4 over a
-    16-entry table: rows at positions 0, 17 and 30 reach entries 1, 5
-    and 8 (ceil((pos + 1) / 4)); the row out of the step counts for
-    nothing."""
-    from horovod_tpu.models.gpt import GPT, GPTConfig
-    kw = dict(vocab_size=64, num_layers=1, num_heads=2, head_dim=8,
-              max_seq_len=64)
-    params = GPT(GPTConfig(**kw)).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
-    ex = ShardedExecutor(GPT(GPTConfig(decode=True, kv_block_size=4, **kw)),
-                         params, max_batch=4, max_len=64)
-    assert ex.blocks_per_seq == 16
-    rec = get_recorder()
-    t0 = rec.now()
-    ex.step(np.zeros((4, 1), np.int32), np.array([0, 5, 17, 30], np.int32),
-            np.array([True, False, True, True]), np.zeros(4, np.int32),
-            kind="decode",
-            block_tables=np.zeros((4, ex.blocks_per_seq), np.int32))
-    (span,) = [s for s in rec.between(t0, rec.now())
-               if s.name == "exec_step"]
-    assert span.extra["walk_entries"] == 1 + 5 + 8
-    assert span.extra["table_entries"] == 3 * 16
