@@ -34,8 +34,7 @@ under a capacity, training only):
   blocks (`ops/pallas_attention.py flash_prefill`: the cached prefix,
   then the fresh tokens; blocks behind a window skipped), never with a
   dense ``[tokens, context]`` score tensor. ``prefill_rows`` tells the
-  batcher to prefill that many rows a step instead of packing
-  ``[max_batch, bucket]`` (`serve/batcher.py`).
+  batcher how many rows a prefill step holds (`serve/batcher.py`).
 
 Serving only (``decode=True``, paged): the model is driven by
 `serve.ShardedExecutor` like `GPT` and `Llama`. Each layer sows the

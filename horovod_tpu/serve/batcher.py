@@ -166,18 +166,15 @@ class ContinuousBatcher:
                     f"be rolled back (no snapshot to return to)")
             # the defaults that cannot apply are off, not an error
             prefix_cache, kv_tier = False, False
-        #: rows a prefill step holds. None: the packed
-        #: ``[max_batch, bucket]`` step, every admitted row at the
-        #: longest one's bucket. A model whose config names
-        #: ``prefill_rows`` (long prompts: models/routed_lm.py) is
-        #: prefilled row-compact instead, ``[prefill_rows, bucket]`` a
-        #: step with each step at its own rows' bucket, so a prefill's
-        #: work follows the prompt tokens admitted and not
-        #: ``max_batch x`` the longest: the rows of such a step are
-        #: addressed through their block tables
-        rows = getattr(executor.model.cfg, "prefill_rows", None)
-        self.prefill_rows: Optional[int] = (
-            min(int(rows), executor.max_batch) if rows else None)
+        #: rows a prefill step holds: admitted sequences are prefilled
+        #: row-compact, ``[prefill_rows, bucket]`` a step with each step
+        #: at its own rows' bucket, so a prefill's work follows the
+        #: prompt tokens admitted and not ``max_batch x`` the longest
+        #: (the rows of such a step are addressed through their block
+        #: tables). 1 unless the model's config names another count
+        self.prefill_rows = min(
+            int(getattr(executor.model.cfg, "prefill_rows", 1)),
+            executor.max_batch)
         self.eos_id = eos_id
         #: fleet identity (None = standalone): labels the metric
         #: series and addresses chaos serve.step / serve.kv faults
@@ -469,17 +466,17 @@ class ContinuousBatcher:
     # -- shape warmup --------------------------------------------------------
     def warmup(self) -> None:
         """Compile every shape the scheduler can launch — decode, one
-        prefill per bucket, the speculative verify ([max_batch,
-        spec_k+1]) and draft shapes, and the CoW block copy — with
-        all-False masks (state untouched). Run once at startup so
-        overload/churn never meets a compile; the draft/verify shapes
-        joining this set is what keeps the jit cache flat when
-        speculation is on."""
+        ``[prefill_rows, b]`` prefill per bucket, the speculative
+        verify ([max_batch, spec_k+1]) and draft shapes, and the CoW
+        block copy — with all-False masks (state untouched). Run once
+        at startup so overload/churn never meets a compile; the
+        draft/verify shapes joining this set is what keeps the jit
+        cache flat when speculation is on."""
         B = self.executor.max_batch
         zero = np.zeros(B, np.int32)
         off = np.zeros(B, bool)
         tbl = np.full((B, self.executor.blocks_per_seq), -1, np.int32)
-        R = self.prefill_rows or B      # a prefill step's rows
+        R = self.prefill_rows
         for b in self.buckets:
             self.executor.step(
                 np.zeros((R, b), np.int32), zero[:R], off[:R], zero[:R],
@@ -501,7 +498,7 @@ class ContinuousBatcher:
                             block_tables=self._draft_tables)
 
     def _slots_arg(self, slots) -> dict:
-        """``state_slots`` for a row-compact prefill step of a model
+        """``state_slots`` for a prefill step of a model
         with per-row state: which batch slot each row of the step
         stands for. Any other model's step takes no such argument."""
         if not getattr(self.executor, "per_row_state", False):
@@ -921,8 +918,8 @@ class ContinuousBatcher:
         (``rng_ctr + ctr_offset``). Rows not listed stay at the greedy
         defaults (temperature 0) and are masked out anyway. `rows`
         (parallel to `slots`): where each slot's sequence sits in a
-        row-compact prefill step of `prefill_rows` rows; None = in its
-        own batch slot."""
+        prefill step of `prefill_rows` rows; None = in its own batch
+        slot."""
         B = self.executor.max_batch if rows is None else self.prefill_rows
         s = {"temperature": np.zeros(B, np.float32),
              "top_p": np.ones(B, np.float32),
@@ -1162,7 +1159,6 @@ class ContinuousBatcher:
 
     # -- prefill -------------------------------------------------------------
     def _prefill(self, admitted: List[_Active]) -> None:
-        B = self.executor.max_batch
         t_p0 = time.monotonic()   # queue_wait ends / prefill begins
         hit_rows: List[_Active] = []
         # materialize each admission plan: shared full blocks join
@@ -1186,19 +1182,14 @@ class ContinuousBatcher:
             if a.prefix_tokens:
                 hit_rows.append(a)
             self.kv.ensure(row, len(a.req.prompt))
+        # R sequences a step, longest first so that a step's rows share
+        # a bucket as nearly as they can; row = the sequence's place in
+        # its step
         R = self.prefill_rows
-        if R is None:
-            # ONE packed prefill, row = the sequence's batch slot
-            waves = [[(a.slot, a) for a in admitted]]
-        else:
-            # row-compact: R sequences a step, longest first so that a
-            # step's rows share a bucket as nearly as they can; row =
-            # the sequence's place in its step
-            order = sorted(admitted, key=lambda a: a.prefix_tokens
-                           - len(a.req.prompt))
-            waves = [list(enumerate(order[i:i + R]))
-                     for i in range(0, len(order), R)]
-        rows = B if R is None else R
+        order = sorted(admitted, key=lambda a: a.prefix_tokens
+                       - len(a.req.prompt))
+        waves = [list(enumerate(order[i:i + R]))
+                 for i in range(0, len(order), R)]
         expected_v = self._prefix_version
         #: slot -> (its first token, when its wave's step ended)
         first: Dict[int, tuple] = {}
@@ -1209,18 +1200,14 @@ class ContinuousBatcher:
             # cache missed or is off)
             bucket = self._bucket_for(max(
                 len(a.req.prompt) - a.prefix_tokens for _, a in wave))
-            tokens = np.zeros((rows, bucket), np.int32)
-            positions = np.zeros(rows, np.int32)
-            mask = np.zeros(rows, bool)
-            last_idx = np.zeros(rows, np.int32)
-            tables = self.kv.table()
-            slots = {}
-            if R is not None:
-                # each row's own table, in the step's row order
-                at = np.full(rows, -1)
-                at[[r for r, _ in wave]] = [a.slot for _, a in wave]
-                tables = np.where(at[:, None] >= 0, tables[at], -1)
-                slots = self._slots_arg(np.maximum(at, 0))
+            tokens = np.zeros((R, bucket), np.int32)
+            positions = np.zeros(R, np.int32)
+            mask = np.zeros(R, bool)
+            last_idx = np.zeros(R, np.int32)
+            # each row's own table, in the step's row order
+            at = np.full(R, -1)
+            at[[r for r, _ in wave]] = [a.slot for _, a in wave]
+            tables = np.where(at[:, None] >= 0, self.kv.table()[at], -1)
             for r, a in wave:
                 m = a.prefix_tokens
                 suffix = a.req.prompt[m:]
@@ -1231,10 +1218,10 @@ class ContinuousBatcher:
             nxt = self.executor.step(
                 tokens, positions, mask, last_idx, kind="prefill",
                 stats=self._stats(),
-                sample=self._sample_args(
-                    [a.slot for _, a in wave],
-                    rows=None if R is None else [r for r, _ in wave]),
-                block_tables=tables, **slots)
+                sample=self._sample_args([a.slot for _, a in wave],
+                                         rows=[r for r, _ in wave]),
+                block_tables=tables,
+                **self._slots_arg(np.maximum(at, 0)))
             stale = stale or \
                 self.executor.last_step_version != expected_v
             t_wave = time.monotonic()   # one stamp for the wave's rows

@@ -4,11 +4,12 @@ Drives a decode-mode model (models/gpt.py or models/llama.py with
 ``cfg.decode=True``) at **fixed shapes**: every call is
 ``[max_batch, T]`` tokens with per-row positions, an update mask and a
 per-row last-token index, where T is 1 (decode) or one of the configured
-prefill buckets. Because batch membership is carried in *data* (mask,
-positions) rather than *shape*, sequences can join and leave at
-iteration granularity without ever invalidating the jit cache — the
-no-recompile contract the continuous batcher (serve/batcher.py) is
-built on.
+prefill buckets (a prefill may hold fewer rows: the batcher runs
+``[prefill_rows, bucket]``). Because batch membership is carried in
+*data* (mask, positions) rather than *shape*, sequences can join and
+leave at iteration granularity without ever invalidating the jit cache
+— the no-recompile contract the continuous batcher (serve/batcher.py)
+is built on.
 
 Three decisions are frozen at build time so the jit cache stays flat:
 
@@ -553,7 +554,8 @@ class ShardedExecutor:
         ``rows`` < max_batch and every per-row array (the block tables
         too) of that many rows: the pool is addressed through the
         tables alone, so a step's rows need not be the batch's (the
-        batcher prefills a long prompt alone; each ``(rows, T)`` shape
+        batcher prefills ``prefill_rows`` prompts a step, by default
+        one; each ``(rows, T)`` shape
         is one compiled program, to be warmed like any other). For a
         model with per-row state ``state_slots`` [rows] says which
         batch slot each row of such a step stands for (a masked-out
@@ -598,9 +600,12 @@ class ShardedExecutor:
         attrs = {"kind": kind, "rows": n_tok}
         if kind == "prefill":
             # prompt tokens this step ingests (each row's up to its
-            # emitting position; the rest of the bucket is padding)
+            # emitting position; the rest of the bucket is padding) and
+            # the token positions it computes, padding and masked rows
+            # included: tokens / token_slots is the step's useful share
             attrs["tokens"] = int(np.sum(
                 (np.asarray(last_idx) + 1)[np.asarray(mask, bool)]))
+            attrs["token_slots"] = B * T
         # the step and its three legs land in the process's span ring
         # (docs/tracing.md): which of them the device waits for is what
         # the serve cell's idle metrics read

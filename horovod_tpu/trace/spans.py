@@ -78,7 +78,7 @@ SPAN_LEGS: "OrderedDict[str, Optional[str]]" = OrderedDict([
     ("request",         None),        # root: admission -> resolution
     ("dispatch",        "queue"),     # router pick + enqueue -> ack
     ("queue_wait",      "queue"),     # worker admission -> prefill start
-    ("prefill",         "prefill"),   # packed prefill step -> first token
+    ("prefill",         "prefill"),   # wave's prefill start -> first token
     ("park",            "migrate"),   # parked (hold_kv) -> migrate pack
     ("migrate_push",    "migrate"),   # pack + push + install ack (sender)
     ("migrate_install", "migrate"),   # arrival crc -> device install
@@ -92,7 +92,7 @@ SPAN_LEGS: "OrderedDict[str, Optional[str]]" = OrderedDict([
     ("sched_iteration", None),        # one ContinuousBatcher.step
     ("sched_retire",    None),        # resolve + free finished rows
     ("sched_admit",     None),        # queue pop + block planning
-    ("sched_prefill",   None),        # one packed prefill wave
+    ("sched_prefill",   None),        # one prefill wave
     ("sched_decode",    None),        # one decode (or speculative) wave
     ("exec_step",       None),        # one ShardedExecutor.step
     ("exec_upload",     None),        # host -> device input arrays

@@ -286,6 +286,117 @@ class TestPagedDecode:
 
 
 # ---------------------------------------------------------------------------
+# prefill: one row a step for a model that names no `prefill_rows`
+# ---------------------------------------------------------------------------
+
+def _llama_paged():
+    kw = dict(vocab_size=64, num_layers=2, num_heads=4, num_kv_heads=2,
+              head_dim=8, max_seq_len=_KW["max_seq_len"], dtype=jnp.float32,
+              attention_impl="reference")
+    params = Llama(LlamaConfig(**kw)).init(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))["params"]
+    return Llama(LlamaConfig(decode=True, **kw, kv_block_size=_BS,
+                             kv_pool_blocks=_POOL)), params
+
+
+def _prefill_steps(rec):
+    """The prefill `exec_step` spans of `rec` that held a live row (a
+    warm-up step holds none)."""
+    return [s for s in rec.between(0.0, float("inf"))
+            if s.name == "exec_step" and s.extra["kind"] == "prefill"
+            and s.extra["rows"]]
+
+
+class TestRowCompactPrefill:
+    @pytest.mark.parametrize("model", ["gpt", "llama"])
+    def test_a_model_that_names_no_rows_prefills_one_a_step(self, gpt,
+                                                            model):
+        dec, params = (gpt.paged, gpt.params) if model == "gpt" \
+            else _llama_paged()
+        assert getattr(dec.cfg, "prefill_rows", None) is None
+        ex = ShardedExecutor(dec, params, max_batch=4,
+                             max_len=_KW["max_seq_len"])
+        b = ContinuousBatcher(ex, AdmissionQueue(max_queue=4),
+                              buckets=(16,))
+        assert b.prefill_rows == 1
+
+    @pytest.mark.parametrize("prefix", [False, True])
+    def test_a_wave_is_one_step_a_sequence_at_its_own_bucket(self, gpt,
+                                                             prefix):
+        """Three sequences admitted in one wave across both buckets:
+        three prefill steps of one row, each ingesting its own prompt
+        and computing its own bucket's positions, not the longest's."""
+        ex, q, b = _stack(gpt, buckets=(8, 16), prefix=prefix)
+        rec, old = SpanRecorder(), spans_mod._recorder
+        spans_mod._recorder = rec
+        try:
+            rng = np.random.RandomState(8)
+            prompts = [list(rng.randint(0, 64, n)) for n in (3, 13, 7)]
+            handles = [q.submit(p, max_new_tokens=3) for p in prompts]
+            b.step()                    # admit, prefill, one decode
+            steps = _prefill_steps(rec)
+            b.run()
+        finally:
+            spans_mod._recorder = old
+        assert sorted((s.extra["rows"], s.extra["tokens"],
+                       s.extra["token_slots"]) for s in steps) == \
+            [(1, 3, 8), (1, 7, 8), (1, 13, 16)]
+        decode = [s for s in rec.between(0.0, float("inf"))
+                  if s.name == "exec_step" and s.extra["kind"] == "decode"]
+        assert decode and all("token_slots" not in s.extra for s in decode)
+        for p, h in zip(prompts, handles):
+            assert h.status == "ok" and h.tokens == gpt.oracle(p, 3)
+
+    @pytest.mark.parametrize("kernel", ["xla", "pallas"])
+    def test_a_mixed_wave_serves_each_as_if_alone(self, gpt, kernel):
+        """One wave holding both buckets, a whole-block prefix hit and a
+        copy-on-write partial block, with the crc ledger on: greedy
+        tokens equal to each sequence served alone."""
+        dec = GPT(GPTConfig(decode=True, **_KW, kv_block_size=_BS,
+                            kv_pool_blocks=_POOL, decode_kernel=kernel))
+        ex = ShardedExecutor(dec, gpt.params, max_batch=4,
+                             max_len=_KW["max_seq_len"])
+        q = AdmissionQueue(max_queue=8)
+        b = ContinuousBatcher(ex, q, buckets=(8, 16), prefix_cache=True,
+                              kv_crc=True, spec_k=0)
+        b.warmup()
+        base = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8]   # 3 full blocks
+        q.submit(base, max_new_tokens=2)
+        b.run()                                      # publish
+        rng = np.random.RandomState(9)
+        prompts = [base[:8] + [7, 7, 7],             # 2 blocks shared
+                   base[:10] + [60, 61],             # CoW inside block 3
+                   list(rng.randint(0, 64, 14)),     # bucket 16
+                   list(rng.randint(0, 64, 3))]      # bucket 8
+        rec, old = SpanRecorder(), spans_mod._recorder
+        spans_mod._recorder = rec
+        try:
+            hits, saved = b.prefix.hits, b.prefix.tokens_saved
+            handles = [q.submit(p, max_new_tokens=5) for p in prompts]
+            b.step()
+            steps = _prefill_steps(rec)
+            b.run()
+        finally:
+            spans_mod._recorder = old
+        assert [s.extra["rows"] for s in steps] == [1, 1, 1, 1]
+        assert sorted(s.extra["token_slots"] for s in steps) == \
+            [8, 8, 8, 16]
+        assert b.prefix.hits - hits == 2
+        assert b.prefix.tokens_saved - saved == 8 + 10
+        assert (b.kv_corruptions_detected, b.kv_reprefills) == (0, 0)
+        for p, h in zip(prompts, handles):
+            assert h.status == "ok" and h.tokens == gpt.oracle(p, 5)
+
+    @pytest.mark.parametrize("buckets", [(16,), (8, 16), (8, 16, 32)])
+    def test_warmup_leaves_a_program_a_bucket_and_decode(self, gpt,
+                                                         buckets):
+        ex, q, b = _stack(gpt, buckets=buckets)
+        assert ex.signatures == {("prefill", t) for t in buckets} | \
+            {("decode", 1)}
+        assert ex.jit_cache_size() == 1 + len(buckets)
+
+
+# ---------------------------------------------------------------------------
 # radix prefix cache
 # ---------------------------------------------------------------------------
 
