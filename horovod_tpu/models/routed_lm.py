@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..ops.bf16_split import split_einsum
 from ..parallel import ep
 from .llama import RMSNorm, apply_rope
 
@@ -139,15 +140,21 @@ class RoutedLMConfig:
 
 class Proj(nn.Module):
     """A bias-free projection back into the residual stream: operands in
-    the compute dtype, the float32 accumulator handed over unrounded."""
+    the compute dtype, the float32 accumulator handed over unrounded.
+    ``exact``: ``x`` is not rounded to the compute dtype but multiplied
+    as bfloat16 terms that sum to it (`ops/bf16_split.py`)."""
     features: int
     dtype: Any
     param_dtype: Any
+    exact: bool = False
 
     @nn.compact
     def __call__(self, x):
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (x.shape[-1], self.features), self.param_dtype)
+        if self.exact:
+            return split_einsum("...k,kn->...n", x,
+                                kernel.astype(self.dtype))
         return jnp.dot(x.astype(self.dtype), kernel.astype(self.dtype),
                        preferred_element_type=jnp.float32)
 

@@ -76,13 +76,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_ref,
                 scale: float, causal: bool, block_q: int, block_k: int,
                 seq_q: int, seq_q_p: int, seq_k: int, seq_k_p: int,
                 window: Optional[int] = None, q_start=None,
-                mxu_dtype=jnp.float32):
-    """`window`, `q_start` and `mxu_dtype` are the serving prefill's
-    (:func:`flash_prefill`); left at their defaults the kernel is the
-    training forward, operation for operation."""
+                mxu_dtype=jnp.float32, precision=None):
+    """`window`, `q_start`, `mxu_dtype` and `precision` (the products')
+    are the serving prefill's (:func:`flash_prefill`); left at their
+    defaults the kernel is the training forward, operation for
+    operation."""
     qi = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32) * scale       # [bq, D]
-    bq, d = q.shape
+    bq = q.shape[0]
     q = q.astype(mxu_dtype)
     # position among the keys of this block's first query
     q_base = qi * block_q if q_start is None else q_start + qi * block_q
@@ -106,7 +107,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_ref,
         v = v_ref[0, 0, pl.dslice(kb * block_k, block_k), :].astype(
             mxu_dtype)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())), precision=precision,
             preferred_element_type=jnp.float32)       # [bq, bk]
         mask = _pos_mask(q_base, kb * block_k, bq, block_k,
                          causal=causal, seq_q=seq_q, seq_q_p=seq_q_p,
@@ -121,10 +122,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_ref,
         l_new = l * corr + p.sum(axis=-1)
         o_new = o * corr[:, None] + jax.lax.dot_general(
             p.astype(mxu_dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=precision, preferred_element_type=jnp.float32)
         return o_new, m_new, l_new
 
-    o0 = jnp.zeros((bq, d), jnp.float32)
+    # the values' width (a serving prefill's may be narrower than q's)
+    o0 = jnp.zeros((bq, v_ref.shape[-1]), jnp.float32)
     m0 = jnp.full((bq,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
     o, m, l = jax.lax.fori_loop(kb0, nkb, body, (o0, m0, l0))
@@ -179,11 +181,12 @@ def _prefill_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, **kw):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "window", "block_q", "block_k", "out_dtype", "interpret"))
+    "window", "block_q", "block_k", "out_dtype", "interpret", "scale"))
 def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
                   q_start: jax.Array, *, window: Optional[int] = None,
                   block_q: int = 512, block_k: int = 512,
-                  out_dtype=None, interpret: bool = False) -> jax.Array:
+                  out_dtype=None, interpret: bool = False,
+                  scale: Optional[float] = None) -> jax.Array:
     """Causal attention of a serving prefill: row ``b``'s ``Sq`` queries
     ``[B, H, Sq, D]`` sit at key positions ``q_start[b] + i`` of its
     ``[B, H_kv, Skv, D]`` keys (a cached prefix first, then the fresh
@@ -191,28 +194,36 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
     ``window``, only the newest ``window`` of them; key blocks wholly
     behind the window or past the diagonal are skipped. Forward only,
     operands to the MXU in the inputs' dtype with float32 accumulation
-    and softmax; the output in ``out_dtype`` (the queries' where none is
-    named: differential attention subtracts two outputs and wants them
-    unrounded). Keys past a row's real length must be finite (they are
-    masked, not skipped)."""
+    and softmax (float32 inputs multiplied at `Precision.HIGHEST`, so
+    nothing is rounded to bfloat16); the output ``[B, H, Sq, Dv]`` in
+    ``out_dtype`` (the
+    queries' where none is named: differential attention subtracts two
+    outputs and wants them unrounded). The values ``[B, H_kv, Skv, Dv]``
+    may be narrower than the keys (latent attention's 128 beside keys of
+    192); ``scale`` multiplies the scores (``1/sqrt(D)`` where None).
+    Keys past a row's real length must be finite (they are masked, not
+    skipped)."""
     from jax.experimental.pallas import tpu as pltpu
     qq, kk, vv, scale, block_q, block_k, Sq, Skv, pad_q = _prepare(
-        q, k, v, None, block_q, block_k)
+        q, k, v, scale, block_q, block_k)
     B, H, Sq_p, D = qq.shape
     KV, Skv_p = kk.shape[1], kk.shape[2]
+    Dv = vv.shape[3]
     G = H // KV
     kernel = functools.partial(
         _prefill_kernel, scale=scale, causal=True, block_q=block_q,
         # padded query rows are cut off below, not masked: a query's
         # position is not its index here
         block_k=block_k, seq_q=Sq_p, seq_q_p=Sq_p, seq_k=Skv,
-        seq_k_p=Skv_p, window=window, mxu_dtype=q.dtype)
+        seq_k_p=Skv_p, window=window, mxu_dtype=q.dtype,
+        precision=jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
+        else None)
     # K and V of one kv head stay whole in VMEM (double-buffered), as in
     # the training forward; at a 12,800-key context that is past the
     # compiler's default scoped limit
-    tile = -(-D // 128) * 128
-    need = 4 * Skv_p * tile * k.dtype.itemsize \
-        + 6 * block_q * block_k * 4 + 8 * block_q * tile * 4
+    tile, vtile = (-(-n // 128) * 128 for n in (D, Dv))
+    need = 2 * Skv_p * (tile + vtile) * k.dtype.itemsize \
+        + 6 * block_q * block_k * 4 + 4 * block_q * (tile + vtile) * 4
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -223,12 +234,12 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
                              lambda b, h, qi, st: (b, h, qi, 0)),
                 pl.BlockSpec((1, 1, Skv_p, D),
                              lambda b, h, qi, st: (b, h // G, 0, 0)),
-                pl.BlockSpec((1, 1, Skv_p, D),
+                pl.BlockSpec((1, 1, Skv_p, Dv),
                              lambda b, h, qi, st: (b, h // G, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, block_q, D),
+            out_specs=pl.BlockSpec((1, 1, block_q, Dv),
                                    lambda b, h, qi, st: (b, h, qi, 0))),
-        out_shape=_sds(q, (B, H, Sq_p, D), out_dtype or q.dtype),
+        out_shape=_sds(q, (B, H, Sq_p, Dv), out_dtype or q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=max(16 << 20, need + need // 4)),

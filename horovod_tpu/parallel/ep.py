@@ -10,12 +10,14 @@ primitive the reference exposes as hvd.alltoall (torch/mpi_ops.py:960).
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, reduce
 from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..ops import bf16_split
 
 
 def topk_route(logits: jax.Array, num_experts: int, capacity: int,
@@ -143,12 +145,42 @@ def moe_reference(x, router_w, expert_fn, all_expert_params,
 # one grouped matmul over the sorted rows, so the work is tokens x k and an
 # expert that received no token is never read.
 
-def topk_dropless(logits: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
-    """The ``k`` largest router logits of each token and their softmax
-    OVER THE CHOSEN (float32): ``(experts [T, k] int32, weights [T, k])``.
-    Ties go to the lower expert id (`lax.top_k`)."""
-    top, experts = lax.top_k(logits.astype(jnp.float32), k)
-    return experts.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+def topk_dropless(logits: jax.Array, k: int, *, scoring: str = "softmax",
+                  bias: jax.Array = None,
+                  scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+    """Each token's ``k`` experts and their weights (float32): ``(experts
+    [T, k] int32, weights [T, k])``. Ties go to the lower expert id
+    (`lax.top_k`).
+
+    ``scoring="softmax"``: the ``k`` largest logits and their softmax
+    OVER THE CHOSEN. ``scoring="sigmoid"`` (DeepSeek-V3's ``noaux_tc``
+    with one group): scores ``s = sigmoid(logits)``; the experts are the
+    ``k`` largest of ``s + bias`` (a selection bias that moves WHICH
+    experts are chosen and not how much they weigh); the weights are
+    their ``s``, divided by their sum (``+ 1e-20``), times ``scale``.
+    The other arguments are the sigmoid's."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        top, experts = lax.top_k(logits, k)
+        return experts.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+    if scoring != "sigmoid":
+        raise ValueError(f"scoring must be softmax | sigmoid; got "
+                         f"{scoring!r}")
+    scores = jax.nn.sigmoid(logits)
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, experts = lax.top_k(choice, k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * scale
+
+
+#: the gated experts' activations, by the name `routed_experts` takes:
+#: ReGLU and SwiGLU
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+
+#: sorted rows an exact product multiplies at once (a prefill's 80,000
+#: pairs would hold three float32 outputs of every row at once)
+EXACT_ROWS = 4096
 
 
 def _tile(n: int, cap: int) -> int:
@@ -163,14 +195,15 @@ def _tile(n: int, cap: int) -> int:
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
-                   *, impl: str, out_dtype) -> jax.Array:
+                   *, impl: str, out_dtype, row_tile: int = None) -> jax.Array:
     """``lhs[rows of group g] @ rhs[g]`` for every group: lhs ``[M, K]``
     sorted by group, rhs ``[G, K, N]``, group_sizes ``[G]`` int32. Rows
     past the groups' total are undefined. `impl`: ``"gmm"`` (jax's
     Pallas grouped matmul for TPU, which visits only the (row tile,
     group) pairs that exist: an empty group costs nothing),
     ``"gmm_interpret"`` (the same kernel interpreted, for tests) or
-    ``"ragged_dot"`` (`lax.ragged_dot`, any backend)."""
+    ``"ragged_dot"`` (`lax.ragged_dot`, any backend). `row_tile`: the
+    kernel's rows a tile (``min(M, 512)`` where None)."""
     if impl == "ragged_dot":
         return lax.ragged_dot(lhs, rhs, group_sizes,
                               preferred_element_type=out_dtype)
@@ -179,15 +212,16 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                          f"got {impl!r}")
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     M, K = lhs.shape
-    tiling = (min(M, 512), _tile(K, 1280), _tile(rhs.shape[2], 512))
+    tiling = (row_tile or min(M, 512), _tile(K, 1280),
+              _tile(rhs.shape[2], 512))
     return gmm(lhs, rhs, group_sizes, preferred_element_type=out_dtype,
                tiling=tiling, interpret=impl == "gmm_interpret")
 
 
 def routed_experts(x: jax.Array, experts: jax.Array, weights: jax.Array,
                    valid: jax.Array, w_in: jax.Array, w_out: jax.Array, *,
-                   impl: str = None, out_dtype=None
-                   ) -> Tuple[jax.Array, jax.Array]:
+                   impl: str = None, out_dtype=None, activation: str = "relu",
+                   exact: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Gated experts over dropless routes.
 
     x ``[T, d]``; experts / weights ``[T, k]`` (:func:`topk_dropless`);
@@ -195,23 +229,35 @@ def routed_experts(x: jax.Array, experts: jax.Array, weights: jax.Array,
     cost no work and read no expert); w_in ``[E, d, 2f]`` (each
     expert's gate then up projection, side by side) and w_out
     ``[E, f, d]``. Returns ``(y [T, d], hit)``: ``y[t] = sum_j
-    weights[t, j] * (relu(x[t] @ gate_e) * (x[t] @ up_e)) @ down_e`` for
-    ``e = experts[t, j]``, zero for invalid tokens, accumulated in
-    float32 and returned in `out_dtype` (x's where None); ``hit`` the
-    number of experts that received a token (int32 scalar)."""
+    weights[t, j] * (act(x[t] @ gate_e) * (x[t] @ up_e)) @ down_e`` for
+    ``e = experts[t, j]`` (``act`` the `activation`: ``relu``, or
+    ``silu`` for SwiGLU experts), zero for invalid tokens, accumulated
+    in float32 and returned in `out_dtype` (x's where None); ``hit`` the
+    number of experts that received a token (int32 scalar).
+
+    ``exact``: ``x`` is float32 and neither it nor the activation
+    between the two products is rounded: each row goes in as the
+    bfloat16 terms that sum to it (`ops/bf16_split.py`), the terms of a
+    row side by side in its expert's group, so a group has three times
+    its rows and a decode step's tiles still hold every group once."""
     if impl is None:
         impl = "gmm" if jax.default_backend() == "tpu" else "ragged_dot"
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"activation must be one of {sorted(ACTIVATIONS)}; "
+                         f"got {activation!r}")
     return _routed_experts(x, experts, weights, valid, w_in, w_out,
                            impl=impl, out_dtype=jnp.dtype(out_dtype or
-                                                          x.dtype))
+                                                          x.dtype),
+                           activation=activation, exact=exact)
 
 
 # the two grouped matmuls are the kernels named ``gmm`` in a device trace
 # (the profiler's events carry the kernel's name and no module path): the
 # benchmark finds the experts' device time by that name
-@partial(jax.jit, static_argnames=("impl", "out_dtype"))
+@partial(jax.jit, static_argnames=("impl", "out_dtype", "activation",
+                                   "exact"))
 def _routed_experts(x, experts, weights, valid, w_in, w_out, *, impl,
-                    out_dtype):
+                    out_dtype, activation, exact):
     T, d = x.shape
     k = experts.shape[1]
     E, f = w_out.shape[0], w_out.shape[1]
@@ -226,12 +272,38 @@ def _routed_experts(x, experts, weights, valid, w_in, w_out, *, impl,
     sizes = jnp.sum(flat[:, None] == jnp.arange(E)[None, :], axis=0,
                     dtype=jnp.int32)
     rows = x[jnp.minimum(order // k, T - 1)]                     # [Mp, d]
+
+    def product(lhs, rhs):
+        if not exact:
+            return grouped_matmul(lhs, rhs, sizes, impl=impl,
+                                  out_dtype=jnp.float32, row_tile=tm)
+        # `EXACT_ROWS` sorted rows a pass, each pass's groups clipped to
+        # its rows: the terms' products are three times the rows wide
+        n, rows_a_pass = bf16_split.PARTS, min(Mp, EXACT_ROWS)
+        passes = -(-Mp // rows_a_pass)
+        lhs = jnp.pad(lhs, ((0, passes * rows_a_pass - Mp), (0, 0)))
+        ends = jnp.cumsum(sizes)
+
+        def one(i):
+            lo = i * rows_a_pass
+            part = jnp.clip(ends, lo, lo + rows_a_pass) \
+                - jnp.clip(ends - sizes, lo, lo + rows_a_pass)
+            terms = jnp.moveaxis(bf16_split.bf16_parts(
+                lax.dynamic_slice_in_dim(lhs, lo, rows_a_pass), n), 0, 1)
+            out = grouped_matmul(
+                terms.reshape(rows_a_pass * n, -1), rhs, part * n,
+                impl=impl, out_dtype=jnp.float32,
+                row_tile=tm).reshape(rows_a_pass, n, -1)
+            return reduce(lambda acc, j: acc + out[:, j],
+                          range(n - 2, -1, -1), out[:, n - 1])
+        return lax.map(one, jnp.arange(passes)).reshape(
+            passes * rows_a_pass, -1)[:Mp]
+
     # float32 between the two products: the gate's product is rounded
-    # once, as the operand of the second matmul
-    h = grouped_matmul(rows, w_in, sizes, impl=impl, out_dtype=jnp.float32)
-    act = (jax.nn.relu(h[:, :f]) * h[:, f:]).astype(x.dtype)
-    out = grouped_matmul(act, w_out, sizes, impl=impl,
-                         out_dtype=jnp.float32)                  # [Mp, d]
+    # once, as the operand of the second matmul (not at all when exact)
+    h = product(rows, w_in)
+    act = (ACTIVATIONS[activation](h[:, :f]) * h[:, f:]).astype(x.dtype)
+    out = product(act, w_out)                                    # [Mp, d]
     back = jnp.argsort(order)[:M]                # pair -> its sorted row
     pairs = out[back].reshape(T, k, d)
     # `where`, not a product: rows no group owns are never written

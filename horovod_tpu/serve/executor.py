@@ -603,9 +603,12 @@ class ShardedExecutor:
             # emitting position; the rest of the bucket is padding) and
             # the token positions it computes, padding and masked rows
             # included: tokens / token_slots is the step's useful share
-            attrs["tokens"] = int(np.sum(
-                (np.asarray(last_idx) + 1)[np.asarray(mask, bool)]))
+            on = np.asarray(mask, bool)
+            attrs["tokens"] = int(np.sum((np.asarray(last_idx) + 1)[on]))
             attrs["token_slots"] = B * T
+            # prompt tokens the cache already held (a radix hit's
+            # prefix): a row starts at its first fresh position
+            attrs["cached_tokens"] = int(np.sum(np.asarray(positions)[on]))
         # the step and its three legs land in the process's span ring
         # (docs/tracing.md): which of them the device waits for is what
         # the serve cell's idle metrics read

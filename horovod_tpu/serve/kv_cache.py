@@ -105,6 +105,33 @@ def masked_attention(q: jax.Array, keys: jax.Array, vals: jax.Array,
 
 # -- paged (block) storage ---------------------------------------------------
 
+def _paged_slots(NB: int, BS: int, T: int, positions: jax.Array,
+                 update_mask: jax.Array, block_tables: jax.Array):
+    """Flat pool slot ``block * BS + offset`` of each of the ``T`` new
+    tokens of each row ([B * T]); a write that must not land gets the
+    out-of-range slot ``NB * BS`` (see `write_kv_paged`)."""
+    nblk = block_tables.shape[1]
+    abs_pos = positions[:, None] + jnp.arange(T, dtype=positions.dtype)[None]
+    blk_idx = abs_pos // BS                                   # [B, T]
+    off = abs_pos % BS
+    safe_idx = jnp.clip(blk_idx, 0, nblk - 1)
+    blocks = jnp.take_along_axis(block_tables, safe_idx, axis=1)  # [B, T]
+    valid = (update_mask[:, None] & (blk_idx < nblk) & (blocks >= 0))
+    flat = blocks * BS + off
+    # invalid writes get an out-of-range index and mode="drop" discards
+    # them at the scatter (deterministic on every backend)
+    return jnp.where(valid, flat, NB * BS).reshape(-1)
+
+
+def _scatter_slots(pool: jax.Array, new: jax.Array, flat: jax.Array):
+    """``new`` [B, T, ...] written at the flat slots of ``pool`` [NB, BS,
+    ...]; out-of-range slots dropped."""
+    NB, BS = pool.shape[0], pool.shape[1]
+    out = pool.reshape(NB * BS, *pool.shape[2:]).at[flat].set(
+        new.reshape(-1, *new.shape[2:]).astype(pool.dtype), mode="drop")
+    return out.reshape(pool.shape)
+
+
 def write_kv_paged(pool_k: jax.Array, pool_v: jax.Array, k_new: jax.Array,
                    v_new: jax.Array, positions: jax.Array,
                    update_mask: jax.Array, block_tables: jax.Array):
@@ -120,27 +147,20 @@ def write_kv_paged(pool_k: jax.Array, pool_v: jax.Array, k_new: jax.Array,
     what keeps bucket-padding garbage out of other sequences' blocks.
     Returns the updated (pool_k, pool_v).
     """
-    NB, BS = pool_k.shape[0], pool_k.shape[1]
-    B, T = k_new.shape[0], k_new.shape[1]
-    nblk = block_tables.shape[1]
-    abs_pos = positions[:, None] + jnp.arange(T, dtype=positions.dtype)[None]
-    blk_idx = abs_pos // BS                                   # [B, T]
-    off = abs_pos % BS
-    safe_idx = jnp.clip(blk_idx, 0, nblk - 1)
-    blocks = jnp.take_along_axis(block_tables, safe_idx, axis=1)  # [B, T]
-    valid = (update_mask[:, None] & (blk_idx < nblk) & (blocks >= 0))
-    flat = blocks * BS + off
-    # invalid writes get an out-of-range index and mode="drop" discards
-    # them at the scatter (deterministic on every backend)
-    flat = jnp.where(valid, flat, NB * BS).reshape(-1)
+    flat = _paged_slots(pool_k.shape[0], pool_k.shape[1], k_new.shape[1],
+                        positions, update_mask, block_tables)
+    return _scatter_slots(pool_k, k_new, flat), \
+        _scatter_slots(pool_v, v_new, flat)
 
-    def scatter(pool, new):
-        out = pool.reshape(NB * BS, *pool.shape[2:]).at[flat].set(
-            new.reshape(B * T, *new.shape[2:]).astype(pool.dtype),
-            mode="drop")
-        return out.reshape(pool.shape)
 
-    return scatter(pool_k, k_new), scatter(pool_v, v_new)
+def write_paged(pool: jax.Array, new: jax.Array, positions: jax.Array,
+                update_mask: jax.Array, block_tables: jax.Array):
+    """`write_kv_paged` for ONE pool of any per-token shape: ``pool``
+    [num_blocks, block_size, ...], ``new`` [B, T, ...] (a latent pool's
+    one vector a token, models/mla_lm.py)."""
+    flat = _paged_slots(pool.shape[0], pool.shape[1], new.shape[1],
+                        positions, update_mask, block_tables)
+    return _scatter_slots(pool, new, flat)
 
 
 def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,

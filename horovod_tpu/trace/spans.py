@@ -116,10 +116,12 @@ LEGS = ("queue", "prefill", "migrate", "decode")
 
 #: the local ring holds this many times ``HOROVOD_TRACE_RING`` spans
 #: (the knob bounds what a worker holds for a router that never
-#: collects; it scales both): 32,768 at the default, 10 MB when full; a
+#: collects; it scales both): 65,536 at the default, 20 MB when full; a
 #: traced run of the benchmark's serve cells holds 10,000-24,000 spans
-#: when its readers run (PERF.md section 6)
-RING_FACTOR = 8
+#: when its readers run, the latent-attention cell's decode of answers
+#: to 2,048 tokens about 30,000-40,000, and its readers need the ring
+#: unwrapped since the import (PERF.md sections 6 and 7)
+RING_FACTOR = 16
 
 #: attributes that stay in the process: `to_wire` leaves them out, so a
 #: reply frame never grows by a stamp per token

@@ -699,3 +699,55 @@ def test_decode_walk_compiles_at_the_serve_cells_shapes(v5e, cell):
     entries = pallas_paged.window_entries(window, 1, BS, nblk)
     assert 4 * walk <= pallas_paged._vmem_limit_bytes(
         H // KV, KV, D, BS, entries, 2)
+
+
+# -- latent attention at the JoyAI-LLM-Flash cell's shapes --------------------
+# (16 rows, 32 heads, the 576-value latent held at 640 lanes, 1,536 pool
+# blocks of 64, tables of 192 entries; the prefill's published form at
+# the largest bucket over the whole table). The model runs both in
+# float32 (`models/mla_lm.py`); the kernels take bfloat16 too.
+
+@pytest.mark.parametrize("dtype", ["bf16", "f32"])
+@pytest.mark.parametrize("T", [1, 4])
+def test_latent_decode_compiles_and_reads_the_pool_once(v5e, T, dtype):
+    """The absorbed walk: ONE `_latent_attention_call` with the pool as
+    its operand and no copy or pad of it (the block is the key and the
+    value), within the compiler's default scoped VMEM; a verify's `T`
+    queries a row share the walk."""
+    from horovod_tpu.ops import pallas_latent
+    dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
+    sh = SingleDeviceSharding(v5e[0])
+    pool = _sds((1536, 64, 640), dt, sh)
+    compiled = _aot_compile(jax.jit(functools.partial(
+        pallas_latent._latent_attention_call, scale=192 ** -0.5,
+        value_lanes=512, interpret=False)), (
+            _sds((16, T, 32, 640), dt, sh), pool,
+            _sds((16, 192), jnp.int32, sh), _sds((16,), jnp.int32, sh)))
+    text = compiled.as_text()
+    (call,) = [line for line in text.splitlines() if re.search(
+        r"%_latent_attention_call[.\d]* = .*custom-call", line)]
+    assert f"{dtype}[1536,64,640]" in call.replace(" ", "")
+    assert not re.search(dtype + r"\[1536,64,640\]\{[^ ]* (copy|pad)\(",
+                         text)
+    assert _scoped_vmem(call) == ""
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "f32"])
+def test_flash_prefill_takes_a_scale_and_narrower_values(v5e, dtype):
+    """Keys of 192 (128 + 64) and values of 128 over a 12,288-key table,
+    the largest bucket's queries: the forward compiles, asks for the
+    VMEM its two widths need, and writes values' width."""
+    from horovod_tpu.ops.pallas_attention import flash_prefill
+    dt = {"bf16": jnp.bfloat16, "f32": jnp.float32}[dtype]
+    sh = SingleDeviceSharding(v5e[0])
+    compiled = _aot_compile(jax.jit(functools.partial(
+        flash_prefill, interpret=False, scale=192 ** -0.5)), (
+            _sds((1, 32, 10368, 192), dt, sh),
+            _sds((1, 32, 12288, 192), dt, sh),
+            _sds((1, 32, 12288, 128), dt, sh),
+            _sds((1,), jnp.int32, sh)))
+    (call,) = [line for line in compiled.as_text().splitlines()
+               if re.search(r"%flash_prefill[.\d]* = .*custom-call", line)]
+    assert call.replace(" ", "").startswith("%flash_prefill") and \
+        f"{dtype}[1,32,10752,128]" in call.replace(" ", "")
+    assert _scoped_vmem(call) != ""
