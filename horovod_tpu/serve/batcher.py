@@ -589,7 +589,7 @@ class ContinuousBatcher:
             self._drain_parked_release()
             self._install_migrated()
             self._retire()
-        with rec.span("sched_admit"):
+        with rec.span("sched_admit") as span:
             # KV tier (serve/kvtier/): install router-pulled runs, then
             # promote ladder-held prefixes of waiting prompts BEFORE
             # the admission wave matches — a promoted block is
@@ -602,7 +602,7 @@ class ContinuousBatcher:
                     for p in self.queue.peek_prompts(
                             self.executor.max_batch):
                         self.kvtier.promote_for(p)
-            admitted = self._admit()
+            admitted = self._admit(span)
         if admitted:
             with rec.span("sched_prefill"):
                 self._prefill(admitted)
@@ -1079,14 +1079,19 @@ class ContinuousBatcher:
         if plan["partial"] is not None:
             self.prefix.release([plan["partial"][0]])
 
-    def _admit(self) -> List[_Active]:
+    def _admit(self, span) -> List[_Active]:
+        """One admission wave; on ``span`` (its ``sched_admit``), with
+        the prefix cache on, ``planned`` (requests planned) and
+        ``admitted``: a refused head is their difference."""
         free_rows = self.kv.num_rows - self.kv.live()
         if free_rows <= 0:
             return []
         admitted: List[_Active] = []
-        # ONE evictable-tree walk per admission wave (the live hook is
-        # O(cached blocks) and fits() runs under the queue lock); the
-        # wave's own acceptances are charged against the snapshot:
+        plans_made = 0
+        # ONE evictable snapshot per admission wave (the prefix cache
+        # keeps the count exact as it changes, and the wave's own
+        # matches move it); the wave's own acceptances are charged
+        # against the snapshot:
         # `planned` for reservations that land at alloc_row, `pinned`
         # for matched prefix blocks whose new reference may have made
         # a previously-evictable run un-evictable. Each candidate is
@@ -1114,8 +1119,10 @@ class ContinuousBatcher:
 
         # corrupted-and-reset sequences re-enter ahead of the queue
         # (they already waited their turn once)
+        blocked = False
         while self._reprefill and len(admitted) < free_rows:
             plan = self._plan(self._reprefill[0])
+            plans_made += 1
             if not self.kv.can_admit(
                     plan["new_blocks"] + planned,
                     max(ev0 - pinned - pins_of(plan), 0)):
@@ -1124,7 +1131,8 @@ class ContinuousBatcher:
                 # requests past a blocked reprefill would let them eat
                 # the blocks it is waiting for (priority inversion —
                 # it could starve to its deadline while parked here)
-                return admitted
+                blocked = True
+                break
             # no `planned` charge here: admit_one's alloc_row reserves
             # immediately, so reserved_total already carries it
             pinned += pins_of(plan)
@@ -1133,8 +1141,9 @@ class ContinuousBatcher:
         plans: Dict[int, dict] = {}
 
         def fits(req: ServeRequest) -> bool:
-            nonlocal planned, pinned
+            nonlocal planned, pinned, plans_made
             plan = self._plan(req)
+            plans_made += 1
             if self.kv.can_admit(plan["new_blocks"] + planned,
                                  max(ev0 - pinned - pins_of(plan), 0)):
                 plans[req.rid] = plan
@@ -1144,9 +1153,12 @@ class ContinuousBatcher:
             self._release_plan(plan)
             return False
 
-        for req in self.queue.pop_fitting(free_rows - len(admitted),
-                                          fits):
-            admit_one(req, plans[req.rid])
+        if not blocked:
+            for req in self.queue.pop_fitting(free_rows - len(admitted),
+                                              fits):
+                admit_one(req, plans[req.rid])
+        if self.prefix is not None:
+            span.set(planned=plans_made, admitted=len(admitted))
         return admitted
 
     def _bucket_for(self, length: int) -> int:

@@ -327,6 +327,10 @@ class BlockPool:
         self.peak_in_use = 0
         #: block -> (filled positions, [running crc32 per cache leaf])
         self._crc: Dict[int, Tuple[int, List[int]]] = {}
+        #: ``on_shared(blk, +1)`` when a block's refcount rises from 1 to
+        #: 2, ``(blk, -1)`` when it falls back: the radix prefix cache
+        #: (serve/prefix.py) keeps its evictable count from these
+        self.on_shared: Optional[Callable[[int, int], None]] = None
 
     # -- allocation ----------------------------------------------------------
     def alloc(self) -> Optional[int]:
@@ -345,16 +349,22 @@ class BlockPool:
         return blk
 
     def incref(self, blk: int) -> None:
-        if self.refcount[blk] < 1:
+        rc = self.refcount[blk]
+        if rc < 1:
             raise ValueError(f"block {blk} is not live")
-        self.refcount[blk] += 1
+        self.refcount[blk] = rc + 1
+        if rc == 1 and self.on_shared is not None:
+            self.on_shared(blk, 1)
 
     def decref(self, blk: int) -> bool:
         """Drop one reference; returns True when the block was freed."""
-        if self.refcount[blk] < 1:
+        rc = self.refcount[blk]
+        if rc < 1:
             raise ValueError(f"block {blk} is not live")
-        self.refcount[blk] -= 1
-        if self.refcount[blk] == 0:
+        self.refcount[blk] = rc - 1
+        if rc == 2 and self.on_shared is not None:
+            self.on_shared(blk, -1)
+        if rc == 1:
             self._free.append(blk)
             self.frees += 1
             self._crc.pop(blk, None)
@@ -466,10 +476,9 @@ class PagedKVCache:
 
     def available_blocks(self, evictable: Optional[int] = None) -> int:
         """Free + evictable - reserved. Pass ``evictable`` to reuse a
-        snapshot across an admission wave — the live hook walks the
-        whole radix tree, and one walk per wave (not per candidate,
-        under the queue lock) is plenty; the batcher charges the wave's
-        own pins against the snapshot, which only ever under-admits."""
+        snapshot across an admission wave: the wave's own matches pin
+        blocks as it plans, and the batcher charges those pins against
+        the snapshot, which only ever under-admits."""
         if evictable is None:
             evictable = (self.evictable()
                          if self.evictable is not None else 0)

@@ -23,14 +23,23 @@ Three policies the serving contract needs:
   non-owner — a refcount > 1 block is read-only by construction.
 * **LRU eviction of refcount-zero runs.** The tree holds one refcount
   per node; a node whose block's ONLY reference is the tree itself
-  (pool refcount == 1) is evictable, leaves first, least-recently
-  matched first. `evictable_blocks()` feeds the paged admission gate,
-  so cached-but-unreferenced runs count as free capacity.
+  (pool refcount == 1), and all of whose descendants' likewise, is
+  evictable, leaves first, least-recently matched first.
+  `evictable_blocks()` feeds the paged admission gate, so
+  cached-but-unreferenced runs count as free capacity.
 * **Version fencing.** Cached K/V is only valid for the params that
   computed it: the batcher flushes this cache whenever
   ``swap_params`` adopts a new version (and the fleet router flushes a
   recovering replica before re-admission) — stale-weight KV can never
   serve a new model version (docs/serving.md).
+
+The eviction bookkeeping is kept as the tree and the refcounts change,
+never recomputed by a walk: the pool reports each tree block whose
+refcount crosses 1 <-> 2 (``BlockPool.on_shared``), each node counts
+the shared blocks in and below it, and the evictable leaves wait in a
+heap keyed by ``last_used``. A match, a release and an eviction cost
+the blocks they touch, whatever the size of the tree; the evictable
+count is exact at every call.
 
 Single-threaded by design: every method runs on the batcher's
 scheduling thread, the same one-writer discipline as the block
@@ -38,9 +47,12 @@ allocator.
 """
 from __future__ import annotations
 
+import heapq
 import logging
 import zlib
 from typing import Callable, Dict, List, Optional, Tuple
+
+import numpy as np
 
 from ..obs import metrics as obs_metrics
 from .kv_cache import BlockPool
@@ -48,16 +60,35 @@ from .kv_cache import BlockPool
 logger = logging.getLogger("horovod_tpu")
 
 
+def _key(prompt, lo: int, hi: int) -> Tuple[int, ...]:
+    """``prompt[lo:hi]`` as a tuple of Python ints: a tree key."""
+    seg = prompt[lo:hi]
+    if isinstance(seg, np.ndarray):
+        return tuple(seg.tolist())
+    return tuple(map(int, seg))
+
+
 class _Node:
-    __slots__ = ("tokens", "block", "children", "parent", "last_used")
+    __slots__ = ("tokens", "block", "children", "lead", "parent",
+                 "last_used", "holds", "queued")
 
     def __init__(self, tokens: Tuple[int, ...], block: int,
                  parent: Optional["_Node"]):
         self.tokens = tokens
         self.block = block
         self.children: Dict[Tuple[int, ...], "_Node"] = {}
+        #: the same children by their segment's first token, in
+        #: insertion order: the only ones a partial match can hit
+        self.lead: Dict[int, List["_Node"]] = {}
         self.parent = parent
         self.last_used = 0
+        #: 1 while this node's block is shared (refcount > 1), plus
+        #: one per child whose own ``holds`` is nonzero: the subtree
+        #: holds a referenced block exactly when this is nonzero
+        self.holds = 0
+        #: ``last_used`` the node's current eviction-heap entry was
+        #: pushed under (0: none)
+        self.queued = 0
 
 
 class RadixPrefixCache:
@@ -68,8 +99,19 @@ class RadixPrefixCache:
         self.pool = pool
         self.block_size = pool.block_size
         self._children: Dict[Tuple[int, ...], _Node] = {}   # root level
+        self._lead: Dict[int, List[_Node]] = {}
         self._nodes = 0
         self._tick = 0
+        #: pool index -> the node that owns it (a block is in the tree
+        #: at most once: insert only adds a sequence's own blocks at
+        #: their own token path)
+        self._by_block: Dict[int, _Node] = {}
+        #: nodes whose ``holds`` is nonzero: evictable = nodes - held
+        self._held = 0
+        #: min-heap of (last_used, node) over the evictable leaves;
+        #: entries whose tick is not the node's ``queued`` are stale
+        self._lru: List[Tuple[int, _Node]] = []
+        pool.on_shared = self._on_shared
         #: eviction hook (the KV tier's demotion trigger,
         #: serve/kvtier/): called with a structured event dict — run id,
         #: block index, block count (depth of the run), token length and
@@ -123,6 +165,41 @@ class RadixPrefixCache:
     def _touch(self, node: _Node) -> None:
         self._tick += 1
         node.last_used = self._tick
+        self._queue(node)
+
+    # -- incremental bookkeeping ---------------------------------------------
+    def _queue(self, node: _Node) -> None:
+        """Give an evictable leaf its heap entry under its current
+        ``last_used`` (no-op for any other node, or when it has one)."""
+        if node.holds == 0 and not node.children \
+                and node.queued != node.last_used:
+            node.queued = node.last_used
+            heapq.heappush(self._lru, (node.last_used, node))
+            if len(self._lru) > 2 * self._nodes + 64:
+                # stale entries (re-touched or re-held nodes) outgrew
+                # the live ones: drop them, amortised O(1) a push
+                self._lru = [e for e in self._lru
+                             if e[1].queued == e[0]]
+                heapq.heapify(self._lru)
+
+    def _hold(self, node: _Node, d: int) -> None:
+        """A hold (+1) or its release (-1) at ``node``: the node's own
+        block became shared or unshared, or a child's subtree became
+        held or clear. Walks up only while a count crosses zero."""
+        while node is not None:
+            node.holds += d
+            if node.holds != (1 if d > 0 else 0):
+                return
+            self._held += d
+            if d < 0:
+                self._queue(node)
+            node = node.parent
+
+    def _on_shared(self, blk: int, d: int) -> None:
+        """``BlockPool.on_shared``: ``blk``'s refcount crossed 1 <-> 2."""
+        node = self._by_block.get(blk)
+        if node is not None:
+            self._hold(node, d)
 
     # -- capacity in TOKENS (the fleet index / autoscale definition) ---------
     def resident_tokens(self) -> int:
@@ -134,8 +211,7 @@ class RadixPrefixCache:
 
     def evictable_tokens(self) -> int:
         """Tokens releasable on demand (the token-granular view of
-        :meth:`evictable_blocks` — same subtree walk, same refcount
-        rule)."""
+        :meth:`evictable_blocks`, same refcount rule)."""
         return self.evictable_blocks() * self.block_size
 
     def run_tokens(self, node: _Node) -> Tuple[int, ...]:
@@ -172,26 +248,25 @@ class RadixPrefixCache:
         bs = self.block_size
         cap = len(prompt) - 1
         full: List[int] = []
-        children = self._children
+        children, lead = self._children, self._lead
         pos = 0
-        node = None
         while pos + bs <= cap:
-            seg = tuple(int(t) for t in prompt[pos:pos + bs])
-            child = children.get(seg)
+            child = children.get(_key(prompt, pos, pos + bs))
             if child is None:
                 break
             self.pool.incref(child.block)
             self._touch(child)
             full.append(child.block)
-            node = child
-            children = child.children
+            children, lead = child.children, child.lead
             pos += bs
-        # partial (copy-on-write) match inside the next block
+        # partial (copy-on-write) match inside the next block: only a
+        # child whose segment starts with the next token can match any
+        # of it, and none can match more than one block
         partial: Optional[Tuple[int, int]] = None
-        want = [int(t) for t in prompt[pos:cap]]
-        if want:
+        if pos < cap:
+            want = _key(prompt, pos, min(cap, pos + bs))
             best, best_j = None, 0
-            for child in children.values():
+            for child in lead.get(want[0], ()):
                 j = 0
                 for a, b in zip(child.tokens, want):
                     if a != b:
@@ -234,20 +309,33 @@ class RadixPrefixCache:
         created = 0
         pos = 0
         while pos + bs <= len(prompt) and (pos // bs) < len(seq_blocks):
-            seg = tuple(int(t) for t in prompt[pos:pos + bs])
+            seg = _key(prompt, pos, pos + bs)
             child = children.get(seg)
             if child is None:
-                blk = seq_blocks[pos // bs]
-                self.pool.incref(blk)
-                child = _Node(seg, blk, parent)
-                children[seg] = child
-                self._nodes += 1
+                child = self._add(parent, seg, seq_blocks[pos // bs])
                 created += 1
             self._touch(child)
             parent = child
             children = child.children
             pos += bs
         return created
+
+    def _add(self, parent: Optional[_Node], seg: Tuple[int, ...],
+             block: int) -> _Node:
+        """A new leaf under ``parent`` (None: the root) holding
+        ``block``, with the tree's own refcount on it. The block's
+        other holder (the caller) makes it shared at once."""
+        self.pool.incref(block)
+        node = _Node(seg, block, parent)
+        children, lead = (self._children, self._lead) if parent is None \
+            else (parent.children, parent.lead)
+        children[seg] = node
+        lead.setdefault(seg[0], []).append(node)
+        self._by_block[block] = node
+        self._nodes += 1
+        if self.pool.refcount[block] > 1:
+            self._hold(node, 1)
+        return node
 
     def attach(self, tokens, block: int) -> bool:
         """Graft ONE block back onto the tree (the KV tier's promotion
@@ -275,48 +363,27 @@ class RadixPrefixCache:
         seg = toks[-bs:]
         if seg in children:
             return False
-        self.pool.incref(block)
-        node = _Node(seg, block, parent)
-        children[seg] = node
-        self._nodes += 1
-        self._touch(node)
+        self._touch(self._add(parent, seg, block))
         return True
 
     # -- eviction ------------------------------------------------------------
-    def _leaves(self) -> List[_Node]:
-        out: List[_Node] = []
-        stack = list(self._children.values())
-        while stack:
-            n = stack.pop()
-            if n.children:
-                stack.extend(n.children.values())
-            else:
-                out.append(n)
-        return out
-
     def evictable_blocks(self) -> int:
         """Blocks releasable on demand: nodes whose subtree holds no
         externally referenced block (pool refcount > 1 anywhere below
         pins the whole path — leaf-first eviction cannot reach it).
-        Iterative post-order: the tree is a chain of prompt_len /
-        block_size nodes per cached prompt, deep enough to blow the
-        recursion limit on a long system prompt."""
-        count = 0
-        ok: Dict[int, bool] = {}            # id(node) -> subtree clear
-        stack = [(n, False) for n in self._children.values()]
-        while stack:
-            node, visited = stack.pop()
-            if not visited:
-                stack.append((node, True))
-                stack.extend((ch, False)
-                             for ch in node.children.values())
-                continue
-            good = self.pool.refcount[node.block] == 1 and all(
-                ok[id(ch)] for ch in node.children.values())
-            ok[id(node)] = good
-            if good:
-                count += 1
-        return count
+        Kept as the tree and the refcounts change: O(1)."""
+        return self._nodes - self._held
+
+    def _lru_leaf(self) -> Optional[_Node]:
+        """The least recently used evictable leaf, off the heap."""
+        while self._lru:
+            tick, node = heapq.heappop(self._lru)
+            if node.queued != tick:
+                continue                      # superseded entry
+            node.queued = 0
+            if node.holds == 0 and not node.children:
+                return node
+        return None
 
     def evict(self, n_blocks: int) -> int:
         """Release at least ``n_blocks`` back to the pool if possible:
@@ -324,11 +391,9 @@ class RadixPrefixCache:
         Returns blocks actually freed."""
         freed = 0
         while freed < n_blocks:
-            cands = [lf for lf in self._leaves()
-                     if self.pool.refcount[lf.block] == 1]
-            if not cands:
+            victim = self._lru_leaf()
+            if victim is None:
                 break
-            victim = min(cands, key=lambda lf: lf.last_used)
             hook = self.on_evict
             if hook is not None:
                 # structured eviction event, emitted BEFORE the decref:
@@ -359,11 +424,20 @@ class RadixPrefixCache:
         return freed
 
     def _remove(self, node: _Node) -> None:
-        siblings = (node.parent.children if node.parent is not None
-                    else self._children)
-        siblings.pop(node.tokens, None)
-        self.pool.decref(node.block)
+        """Drop an evictable leaf; its parent may become one."""
+        parent = node.parent
+        children, lead = (self._children, self._lead) if parent is None \
+            else (parent.children, parent.lead)
+        del children[node.tokens]
+        same = lead[node.tokens[0]]
+        same.remove(node)
+        if not same:
+            del lead[node.tokens[0]]
+        del self._by_block[node.block]
         self._nodes -= 1
+        self.pool.decref(node.block)
+        if parent is not None:
+            self._queue(parent)
 
     def flush(self) -> int:
         """Drop EVERY cached run (weight-swap invalidation): all tree
@@ -372,11 +446,13 @@ class RadixPrefixCache:
         them. Returns nodes dropped."""
         dropped = 0
         stack = list(self._children.values())
+        self._by_block = {}
         while stack:
             n = stack.pop()
             stack.extend(n.children.values())
             self.pool.decref(n.block)
             dropped += 1
-        self._children = {}
-        self._nodes = 0
+        self._children, self._lead = {}, {}
+        self._nodes = self._held = 0
+        self._lru = []
         return dropped
